@@ -1033,6 +1033,8 @@ def kernel_benches(quick: bool = False):
 
 
 def main() -> None:
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, us, derived in kernel_benches(quick="--quick" in sys.argv):
         print(f"{name},{us:.1f},{derived}")

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core.multiplier import NUM_PACKED_PLANES, PACKED_MAX_K
 
 NUM_PLANES = 4  # int8 -> 4 radix-4 digit planes (carry provably dead)
@@ -97,6 +95,7 @@ def ent_matmul(
     grid = (m // block_m, n // block_n, nk)
     return pl.pallas_call(
         functools.partial(_kernel, nk=nk),
+        name="ent_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, t: (i, t)),
@@ -107,7 +106,7 @@ def ent_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -144,16 +143,17 @@ def _packed_kernel(x_ref, p_ref, sx_ref, sw_ref, o_ref, acc_ref, *, nk: int):
         o_ref[...] = (acc * sx_ref[...] * sw_ref[...]).astype(o_ref.dtype)
 
 
-def _packed_fused_kernel(x_ref, p_ref, sx_ref, sw_ref, o_ref, acc_ref,
-                         *, nk: int):
-    """Fused prologue: quantize the float X block in VMEM, then matmul."""
+def _packed_fused_kernel(x_ref, p_ref, sx_ref, inv_ref, sw_ref, o_ref,
+                         acc_ref, *, nk: int):
+    """Fused prologue: quantize the float X block in VMEM, then matmul.
+    ``inv_ref`` holds 1/scale per row, computed outside the kernel by the
+    same op the oracle uses, so the int8 X is bit-identical to it."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    inv = 1.0 / sx_ref[...]                       # [block_m, 1] per-row
-    xq = jnp.clip(jnp.round(x * inv), -127, 127).astype(jnp.int8)
+    xq = jnp.clip(jnp.round(x * inv_ref[...]), -127, 127).astype(jnp.int8)
     acc_ref[...] += _packed_contrib(xq, p_ref)
 
     @pl.when(pl.program_id(2) == nk - 1)
@@ -163,7 +163,7 @@ def _packed_fused_kernel(x_ref, p_ref, sx_ref, sw_ref, o_ref, acc_ref,
 
 
 def _packed_call(kernel_body, x, packed, scale_x, scale_w, *, block_m,
-                 block_n, block_k, out_dtype, interpret):
+                 block_n, block_k, out_dtype, interpret, name, inv_x=None):
     m, k = x.shape
     p, k2, n = packed.shape
     assert p == NUM_PACKED_PLANES and k == k2, (x.shape, packed.shape)
@@ -178,24 +178,27 @@ def _packed_call(kernel_body, x, packed, scale_x, scale_w, *, block_m,
         "pad operands to block multiples", (m, n, k), (block_m, block_n, block_k))
     nk = k // block_k
     grid = (m // block_m, n // block_n, nk)
+    rows = [scale_x] if inv_x is None else [scale_x, inv_x]   # [M, 1] each
+    row_spec = pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0))
     return pl.pallas_call(
         functools.partial(kernel_body, nk=nk),
+        name=name,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, t: (i, t)),
             pl.BlockSpec((NUM_PACKED_PLANES, block_k, block_n),
                          lambda i, j, t: (0, t, j)),
-            pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
+            *[row_spec] * len(rows),
             pl.BlockSpec((1, block_n), lambda i, j, t: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, packed, scale_x, scale_w)
+    )(x, packed, *rows, scale_w)
 
 
 @functools.partial(
@@ -217,7 +220,8 @@ def ent_matmul_packed(
     """Packed 2-plane EN-T matmul: half the plane matmuls of ent_matmul."""
     return _packed_call(_packed_kernel, x, packed, scale_x, scale_w,
                         block_m=block_m, block_n=block_n, block_k=block_k,
-                        out_dtype=out_dtype, interpret=interpret)
+                        out_dtype=out_dtype, interpret=interpret,
+                        name="ent_matmul_packed")
 
 
 @functools.partial(
@@ -243,4 +247,6 @@ def ent_matmul_packed_fused(
     """
     return _packed_call(_packed_fused_kernel, x, packed, scale_x, scale_w,
                         block_m=block_m, block_n=block_n, block_k=block_k,
-                        out_dtype=out_dtype, interpret=interpret)
+                        out_dtype=out_dtype, interpret=interpret,
+                        name="ent_matmul_packed_fused",
+                        inv_x=1.0 / scale_x)

@@ -8,7 +8,9 @@ Three entry points, slowest to fastest serving path:
   quantization from f32/bf16 X (the serving default via quant.qdense_apply)
 
 Block sizes default from the shared shape-keyed table in
-``repro.kernels.tuning``; explicit ``block_*`` kwargs always win.
+``repro.kernels.tuning``; explicit ``block_*`` kwargs always win.  Kernel
+launches pad M to the 8-row sublane tile (``tuning.padded_matmul``), so
+any row count compiles.
 """
 
 from __future__ import annotations
@@ -53,19 +55,15 @@ def _resolve(use_kernel: str) -> str:
     return use_kernel
 
 
-def _blocks(shape, block_kw):
-    return tuning.get_block_config("ent_matmul", shape, block_kw)
-
-
 def ent_quantized_matmul(x, planes, scale_x, scale_w, *,
                          out_dtype=jnp.float32, use_kernel: str = "auto",
                          **block_kw):
     use_kernel = _resolve(use_kernel)
     if use_kernel == "ref":
         return ent_matmul_ref(x, planes, scale_x, scale_w, out_dtype)
-    bk = _blocks((x.shape[0], x.shape[1], planes.shape[-1]), block_kw)
-    return ent_matmul(x, planes, scale_x, scale_w, out_dtype=out_dtype,
-                      interpret=(use_kernel == "interpret"), **bk)
+    return tuning.padded_matmul(
+        ent_matmul, "ent_matmul", x, planes, scale_x, scale_w, block_kw,
+        out_dtype=out_dtype, interpret=(use_kernel == "interpret"))
 
 
 def ent_quantized_matmul_packed(x, packed, scale_x, scale_w, *,
@@ -75,9 +73,9 @@ def ent_quantized_matmul_packed(x, packed, scale_x, scale_w, *,
     use_kernel = _resolve(use_kernel)
     if use_kernel == "ref":
         return ent_packed_matmul_ref(x, packed, scale_x, scale_w, out_dtype)
-    bk = _blocks((x.shape[0], x.shape[1], packed.shape[-1]), block_kw)
-    return ent_matmul_packed(x, packed, scale_x, scale_w, out_dtype=out_dtype,
-                             interpret=(use_kernel == "interpret"), **bk)
+    return tuning.padded_matmul(
+        ent_matmul_packed, "ent_matmul", x, packed, scale_x, scale_w,
+        block_kw, out_dtype=out_dtype, interpret=(use_kernel == "interpret"))
 
 
 def ent_quantized_matmul_fused(x, packed, scale_w, *, out_dtype=jnp.float32,
@@ -93,7 +91,6 @@ def ent_quantized_matmul_fused(x, packed, scale_w, *, out_dtype=jnp.float32,
         return ent_packed_fused_ref(x, packed, scale_w, out_dtype)
     x32 = x.astype(jnp.float32)
     _, sx = quantize_rows(x32)   # the int8 q is unused -> DCE'd under jit
-    bk = _blocks((x.shape[0], x.shape[1], packed.shape[-1]), block_kw)
-    return ent_matmul_packed_fused(
-        x32, packed, sx, scale_w, out_dtype=out_dtype,
-        interpret=(use_kernel == "interpret"), **bk)
+    return tuning.padded_matmul(
+        ent_matmul_packed_fused, "ent_matmul", x32, packed, sx, scale_w,
+        block_kw, out_dtype=out_dtype, interpret=(use_kernel == "interpret"))

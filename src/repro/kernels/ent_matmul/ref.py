@@ -23,11 +23,15 @@ def ent_matmul_int32_ref(x, planes):
 
 
 def quantize_rows(x):
-    """Per-row symmetric int8 activation quant: (q int8, scale f32 [.., 1])."""
+    """Per-row symmetric int8 activation quant: (q int8, scale f32 [.., 1]).
+
+    Rows are scaled by the reciprocal ``1 / scale`` — the exact product
+    the fused kernel's prologue forms from the same reciprocal — so the
+    int8 rows agree bit for bit on every backend."""
     x32 = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(x32 / scale), -127, 127).astype(jnp.int8)
+    q = jnp.clip(jnp.round(x32 * (1.0 / scale)), -127, 127).astype(jnp.int8)
     return q, scale
 
 

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -117,6 +115,7 @@ def flash_attention(
             _kernel, nkv=nkv, block_q=block_q, block_kv=block_kv,
             scale=scale, causal=causal, window=window, q_offset=q_offset,
             has_start=False),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -130,7 +129,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -195,9 +194,10 @@ def flash_attention_masked(
             _kernel, nkv=nkv, block_q=block_q, block_kv=block_kv,
             scale=scale, causal=causal, window=window, q_offset=q_offset,
             has_start=True),
+        name="flash_attention_masked",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

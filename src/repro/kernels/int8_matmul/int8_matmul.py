@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
@@ -69,6 +67,7 @@ def int8_matmul(
     grid = (m // block_m, n // block_n, nk)
     return pl.pallas_call(
         functools.partial(_kernel, nk=nk),
+        name="int8_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, t: (i, t)),
@@ -79,7 +78,7 @@ def int8_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,9 +22,6 @@ def quantized_matmul(x, w, scale_x, scale_w, *, out_dtype=jnp.bfloat16,
         use_kernel = "pallas" if jax.default_backend() == "tpu" else "ref"
     if use_kernel == "ref":
         return int8_matmul_ref(x, w, scale_x, scale_w, out_dtype)
-    bk = tuning.get_block_config(
-        "int8_matmul", (x.shape[0], x.shape[1], w.shape[-1]), block_kw)
-    return int8_matmul(
-        x, w, scale_x, scale_w, out_dtype=out_dtype,
-        interpret=(use_kernel == "interpret"), **bk,
-    )
+    return tuning.padded_matmul(
+        int8_matmul, "int8_matmul", x, w, scale_x, scale_w, block_kw,
+        out_dtype=out_dtype, interpret=(use_kernel == "interpret"))

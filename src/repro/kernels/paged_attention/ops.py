@@ -32,10 +32,10 @@ def paged_attention(q, k_pages, v_pages, block_table, pos, start=None, *,
                     **block_kw):
     """Decode attention straight off the page pool.
 
-    q: [B, Hq, 1, D]; k_pages/v_pages: [P, page, Hkv, D] pools (page 0 =
+    q: [B, Hq, 1, D]; k_pages/v_pages: [P, Hkv, page, D] pools (page 0 =
     reserved null page, masked); block_table: [B, pages_per_slot] int32;
     pos/start: [B] int32 (last valid / first attendable position per
-    slot).  ``k_scales``/``v_scales`` ([P, page, Hkv, 1]) are the
+    slot).  ``k_scales``/``v_scales`` ([P, Hkv, page, 1]) are the
     per-page int8-KV dequant scale pools, folded exactly where the
     gather path folded them (K after the q.k dot, V into the
     probabilities).  Returns [B, Hq, 1, D] float32.

@@ -19,17 +19,20 @@ kernel's autotunable block — family ``"paged_attention"`` in
 * masks column positions against the slot's ``pos`` (causality: pages
   past the write head hold stale/unwritten rows) and ``start``
   (left-pad slots, masked forever),
-* for int8-KV caches, dequantizes IN KERNEL against the per-page scale
-  pools (K after the q.k dot, V folded into the probabilities — the
-  exact fold the serving oracle uses),
+* for int8-KV caches, dequantizes the page tile IN KERNEL against its
+  per-row scale column (K and V rows scaled before the dots — the same
+  product as the oracle's after-the-dot fold, at f32 round-off),
 * and runs the online-softmax flash reduction with f32 running
   max / denominator / accumulator in VMEM scratch, so a fully-masked
   slot (an idle serving slot whose table row is all null) emits exact
   zeros.
 
-GQA: the q heads of kv head ``h`` are the contiguous block
-``h*G .. (h+1)*G - 1``, so one grid step loads a ``[G, D]`` q tile and
-scores it against the page tile.
+Layout: pools are ``[P, Hkv, page, D]``, so one (page, kv head) tile is
+a contiguous ``[page, D]`` slab whose last two dims are what the TPU
+block rule needs (a multiple of 8 / the full page, and the full head
+dim).  GQA: the q heads of kv head ``h`` are the contiguous block
+``h*G .. (h+1)*G - 1``; q arrives as ``[B, Hkv, G, D]`` so one grid step
+loads a ``[G, D]`` q tile and scores it against the page tile.
 """
 
 from __future__ import annotations
@@ -41,13 +44,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 _NEG_INF = -1e30
 
 
 def _kernel(*refs, nkv: int, block_kv: int, tiles_per_page: int,
-            page_size: int, scale: float, has_scale: bool):
+            scale: float, has_scale: bool):
     if has_scale:
         (table_ref, pos_ref, start_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
          o_ref, m_ref, l_ref, acc_ref) = refs
@@ -70,12 +71,13 @@ def _kernel(*refs, nkv: int, block_kv: int, tiles_per_page: int,
 
     @pl.when(pid != 0)
     def _block():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # [bkv, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)              # [bkv, D]
+        q = q_ref[...].astype(jnp.float32) * scale             # [G, D]
+        k = k_ref[...].astype(jnp.float32)                     # [bkv, D]
+        v = v_ref[...].astype(jnp.float32)                     # [bkv, D]
+        if has_scale:   # int8 KV: per-row dequant scales, [bkv, 1]
+            k = k * ks_ref[...].astype(jnp.float32)
+            v = v * vs_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [G, bkv]
-        if has_scale:   # per-page K dequant scale, folded after the dot
-            s = s * ks_ref[0, :, 0, :].astype(jnp.float32).reshape(1, block_kv)
 
         # column c of tile j sits at logical position j*block_kv + c
         # (table order IS position order)
@@ -90,8 +92,6 @@ def _kernel(*refs, nkv: int, block_kv: int, tiles_per_page: int,
         p = jnp.where(mask, p, 0.0)                        # masked rows stay 0
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        if has_scale:   # per-page V dequant scale, folded into the probs
-            p = p * vs_ref[0, :, 0, :].astype(jnp.float32).reshape(1, block_kv)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())))
         m_ref[...] = m_new
@@ -99,7 +99,7 @@ def _kernel(*refs, nkv: int, block_kv: int, tiles_per_page: int,
     @pl.when(j == nkv - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -108,12 +108,12 @@ def _kernel(*refs, nkv: int, block_kv: int, tiles_per_page: int,
 )
 def paged_attention_kernel(
     q: jax.Array,            # [B, Hq, 1, D]
-    k_pages: jax.Array,      # [P, page, Hkv, D] page pool (page 0 = null)
-    v_pages: jax.Array,      # [P, page, Hkv, D]
+    k_pages: jax.Array,      # [P, Hkv, page, D] page pool (page 0 = null)
+    v_pages: jax.Array,      # [P, Hkv, page, D]
     block_table: jax.Array,  # [B, pages_per_slot] int32 page ids
     pos: jax.Array,          # [B] int32: last valid position per slot
     start: jax.Array,        # [B] int32: first attendable position
-    k_scales: jax.Array | None = None,   # [P, page, Hkv, 1] per-page scales
+    k_scales: jax.Array | None = None,   # [P, Hkv, page, 1] per-row scales
     v_scales: jax.Array | None = None,
     *,
     page_size: int,
@@ -123,7 +123,7 @@ def paged_attention_kernel(
 ) -> jax.Array:
     b, hq, sq, d = q.shape
     assert sq == 1, "paged_attention is a decode (Sq=1) kernel"
-    _, page, hkv, _ = k_pages.shape
+    _, hkv, page, _ = k_pages.shape
     assert page == page_size, (page, page_size)
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
@@ -141,17 +141,18 @@ def paged_attention_kernel(
     # axis maps to tile (j % tiles_per_page) of page table[b, j // tpp]
     def kv_idx(bi, hi, ji, table_ref, pos_ref, start_ref):
         del pos_ref, start_ref
-        return (table_ref[bi, ji // tiles_per_page], ji % tiles_per_page,
-                hi, 0)
+        return (table_ref[bi, ji // tiles_per_page], hi,
+                ji % tiles_per_page, 0)
 
-    kv_spec = pl.BlockSpec((1, block_kv, 1, d), kv_idx)
-    scale_spec = pl.BlockSpec((1, block_kv, 1, 1), kv_idx)
-    q_spec = pl.BlockSpec(
-        (1, group, 1, d),
-        lambda bi, hi, ji, *refs: (bi, hi, 0, 0))
+    def q_idx(bi, hi, ji, *refs):
+        return (bi, hi, 0, 0)
+
+    kv_spec = pl.BlockSpec((None, None, block_kv, d), kv_idx)
+    scale_spec = pl.BlockSpec((None, None, block_kv, 1), kv_idx)
+    q_spec = pl.BlockSpec((None, None, group, d), q_idx)
 
     in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [q, k_pages, v_pages]
+    operands = [q.reshape(b, hkv, group, d), k_pages, v_pages]
     if has_scale:
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
@@ -160,24 +161,24 @@ def paged_attention_kernel(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, group, 1, d),
-                               lambda bi, hi, ji, *refs: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, group, d), q_idx),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _kernel, nkv=nkv, block_kv=block_kv,
-            tiles_per_page=tiles_per_page, page_size=page_size, scale=scale,
-            has_scale=has_scale),
+            tiles_per_page=tiles_per_page, scale=scale, has_scale=has_scale),
+        name="paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_table.astype(jnp.int32), pos.astype(jnp.int32),
       start.astype(jnp.int32), *operands)
+    return out.reshape(b, hq, sq, d)

@@ -40,15 +40,17 @@ import jax.numpy as jnp
 
 
 def _take_pages(pool, table):
-    """[P, page, H, *] pool + [B, n] ids -> [B, H, n*page, *] operand
-    (the reshape is free; the head transpose fuses into the read)."""
-    g = pool[table]                              # [B, n, page, H, *]
-    g = g.reshape((g.shape[0], -1) + pool.shape[2:])
+    """[P, H, page, *] pool + [B, n] ids -> [B, H, n*page, *] operand.
+    Built as position-ordered rows [B, n*page, H, *], then head-major —
+    the same ops as ``PagedCache``'s gathered view, so both reads feed
+    the contraction identically (the head transpose fuses into it)."""
+    g = pool[table].swapaxes(2, 3)               # [B, n, page, H, *]
+    g = g.reshape((g.shape[0], -1) + g.shape[3:])
     return g.transpose(0, 2, 1, 3)               # [B, H, C, *]
 
 
 def _scale_cols(pool, table):
-    """[P, page, H, 1] scale pool -> [B, H, C] f32 fold operand."""
+    """[P, H, page, 1] scale pool -> [B, H, C] f32 fold operand."""
     return _take_pages(pool, table)[..., 0].astype(jnp.float32)
 
 
@@ -56,7 +58,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, pos, start=None,
                         *, page_size: int, k_scales=None, v_scales=None,
                         scale=None, block_pages: int | None = None,
                         score_mode: str = "auto"):
-    """q: [B, Hq, 1, D]; pools: [P, page, Hkv, D]; table: [B, nP] int32;
+    """q: [B, Hq, 1, D]; pools: [P, Hkv, page, D]; table: [B, nP] int32;
     pos/start: [B] int32.  Returns [B, Hq, 1, D] float32.
 
     ``score_mode`` picks how K is read (both bit-exact):
@@ -75,7 +77,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, pos, start=None,
     """
     b, hq, sq, d = q.shape
     assert sq == 1, "paged_attention is a decode (Sq=1) op"
-    _, page, hkv, _ = k_pages.shape
+    _, hkv, page, _ = k_pages.shape
     assert page == page_size, (page, page_size)
     group = hq // hkv
     npages = block_table.shape[-1]
@@ -97,7 +99,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, pos, start=None,
         # selection from the [B, Hkv, G, 1, ncols] scores — the K pages
         # are read once, in pool order, with no gather at all (each
         # selected score is the same q . k dot, so this is bit-exact)
-        kcols = k_pages.reshape(-1, hkv, d)
+        kcols = k_pages.swapaxes(1, 2).reshape(-1, hkv, d)   # pool order
         if kcols.dtype == jnp.int8:
             kcols = kcols.astype(q.dtype)
         s_all = jnp.einsum("bhgqd,khd->bhgqk", qg, kcols,

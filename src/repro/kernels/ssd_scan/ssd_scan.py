@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *, nchunks: int):
     ic = pl.program_id(2)
@@ -100,7 +98,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,8 +1,8 @@
 """Shape-keyed block-size selection shared by every Pallas kernel family.
 
-One table serves the three kernel families (``int8_matmul``,
-``ent_matmul`` — 4-plane, packed and fused variants — and
-``flash_attention``): callers that don't pass explicit block sizes get
+One table serves the kernel families (``int8_matmul``, ``ent_matmul`` —
+4-plane, packed and fused variants — ``flash_attention`` and
+``paged_attention``): callers that don't pass explicit block sizes get
 them from here instead of from per-call-site constants.
 
 Resolution order for a (family, shape) query:
@@ -14,6 +14,11 @@ Resolution order for a (family, shape) query:
 3. divisibility-aware heuristic defaults (largest power-of-two block
    that divides the dim, capped at the MXU-friendly sizes the seed
    kernels shipped with).
+
+Entries are keyed by the ``device_kind`` of the first JAX device, so a
+sweep on the CPU or in interpret mode never supplies a chip's block
+sizes.  A cached entry is used only if its blocks divide the launch's
+dims and obey the TPU block rule (see :func:`_valid`).
 
 ``autotune`` measures a candidate sweep with a caller-provided bench
 closure and persists the winner, so expensive searches run once per
@@ -36,6 +41,8 @@ __all__ = [
     "record",
     "clear",
     "cache_path",
+    "pad_rows",
+    "padded_matmul",
 ]
 
 MATMUL_FAMILIES = ("int8_matmul", "ent_matmul")
@@ -63,8 +70,15 @@ def _bucket(dim: int) -> int:
     return b
 
 
+def _device_kind() -> str:
+    import jax   # deferred: importing this module must not touch devices
+
+    return jax.devices()[0].device_kind
+
+
 def _key(family: str, shape) -> str:
-    return f"{family}:" + "x".join(str(_bucket(int(d))) for d in shape)
+    return (f"{_device_kind()}/{family}:"
+            + "x".join(str(_bucket(int(d))) for d in shape))
 
 
 def _load() -> None:
@@ -134,8 +148,15 @@ def _heuristic(family: str, shape) -> dict:
     raise KeyError(f"unknown kernel family: {family}")
 
 
+# TPU block rule: a block's last two dims must be multiples of the
+# (sublane, lane) tile — 8 and 128 — or equal the array's dims
+_ALIGN = {"block_m": 8, "block_n": 128, "block_k": 128,
+          "block_q": 8, "block_kv": 8}
+
+
 def _valid(family: str, shape, cfg: dict) -> bool:
-    """Does the config divide the ACTUAL dims after the kernels' min-clamp?
+    """Does the config divide the ACTUAL dims after the kernels' min-clamp,
+    and is each clamped block aligned to its tile or the full dim?
     (Shapes are bucketed in the table, so a tuned entry from elsewhere in
     the bucket may not divide this launch's dims.)"""
     if family in MATMUL_FAMILIES:
@@ -144,8 +165,39 @@ def _valid(family: str, shape, cfg: dict) -> bool:
         dims = {"block_kv": shape[0]}   # must divide the page
     else:
         dims = {"block_q": shape[0], "block_kv": shape[1]}
-    return all(int(dims[k]) % min(int(cfg[k]), int(dims[k])) == 0
-               for k in dims if k in cfg)
+    for k, dim in dims.items():
+        if k not in cfg:
+            continue
+        dim = int(dim)
+        blk = min(int(cfg[k]), dim)
+        if dim % blk or (blk != dim and blk % _ALIGN[k]):
+            return False
+    return True
+
+
+def pad_rows(m: int) -> int:
+    """Rows a matmul kernel launches with: ``m`` rounded up to the 8-row
+    sublane tile, so every ``block_m`` the table picks is aligned."""
+    return -(-int(m) // 8) * 8
+
+
+def padded_matmul(kernel, family: str, x, w, scale_x, scale_w,
+                  block_kw: dict, **kw):
+    """Launch ``kernel(x, w, scale_x, scale_w, **blocks, **kw)`` with the
+    rows of ``x``/``scale_x`` zero-padded to :func:`pad_rows` (scale rows
+    padded with 1, so a fused in-kernel quant never divides by zero) and
+    the result sliced back to ``m`` rows.  Decode (M = slots) and
+    speculative-verify (M = slots * (K+1)) shapes need no alignment of
+    their own."""
+    import jax.numpy as jnp
+
+    m = x.shape[0]
+    mp = pad_rows(m)
+    if mp != m:
+        x = jnp.pad(x, ((0, mp - m), (0, 0)))
+        scale_x = jnp.pad(scale_x, ((0, mp - m), (0, 0)), constant_values=1)
+    bk = get_block_config(family, (mp, x.shape[1], w.shape[-1]), block_kw)
+    return kernel(x, w, scale_x, scale_w, **bk, **kw)[:m]
 
 
 def get_block_config(family: str, shape, overrides: dict | None = None) -> dict:

@@ -20,6 +20,9 @@ is missing from the image.  This module centralises that dance:
 * :func:`enable_x64` — flip ``jax_enable_x64``; safe at any time.
 * :func:`add_env_args` / :func:`apply_env_args` — argparse glue shared
   by ``launch/serve.py`` and ``launch/train.py``.
+* :func:`enable_compile_cache` — JAX's persistent compilation cache,
+  called by every entry point before its first compile (never at
+  import).
 
 Everything degrades to a no-op on missing files or an already-
 initialised backend — launchers must behave identically on dev boxes,
@@ -137,6 +140,28 @@ def apply(env: dict[str, str]) -> dict[str, str]:
         os.environ[k] = v
         changed[k] = v
     return changed
+
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache: JAX reads it
+    itself and nothing is set here.  Otherwise the cache is the fixed
+    ``<checkout>/.jax_cache`` — fixed because the path is part of what a
+    later process must find again.  A 36-layer model compiles once per
+    prefill bucket and chunk window, so a warm cache saves minutes."""
+    import jax  # deferred, see enable_x64
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # .. argparse glue shared by the launchers ..

@@ -206,6 +206,7 @@ def main():
     envmod.add_env_args(ap)
     args = ap.parse_args()
     envmod.apply_env_args(args)
+    envmod.enable_compile_cache()
     if args.fault_plan and not args.http:
         ap.error("--fault-plan needs --http: only the fault-tolerant "
                  "scheduler can recover from injected faults")

@@ -24,6 +24,7 @@ from repro import sharding as shd
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import ARCH_IDS, get_config, get_optim, reduced_config
 from repro.launch import env as envmod
+from repro.launch.mesh import make_mesh
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import Prefetcher, SyntheticSource, TokenStream
 from repro.models.transformer import build_model
@@ -35,6 +36,36 @@ def parse_mesh(spec: str):
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("pod", "data", "model")[-len(dims):] if len(dims) == 3 else ("data", "model")
     return dims, axes
+
+
+def build_train(cfg, mesh, ocfg, tcfg, *, seed: int = 0):
+    """Sharded training state and step on ``mesh``: params initialised
+    from ``seed`` straight into their FSDP x TP shardings, AdamW state
+    sharded like them, and the jitted step (params/opt donated).  A 1x1
+    mesh gives the same step on one device.  Returns (params, opt,
+    step_fn, batch_sharding)."""
+    da = shd.data_axes(mesh)
+    use_dist = mesh.size > 1
+    model = build_model(
+        cfg,
+        act_sharding=P(da, "model", None) if use_dist else None,
+        dist=(mesh, da) if use_dist else None)
+    key = jax.random.PRNGKey(seed)
+    psh = shd.params_shardings(jax.eval_shape(model.init, key), mesh)
+    with mesh:
+        params = jax.jit(model.init, out_shardings=psh)(key)
+        opt_sh = jax.tree.map(
+            lambda _: NamedSharding(mesh, P()),
+            jax.eval_shape(lambda p: init_opt_state(tcfg, p), params))
+        opt_sh["adam"]["m"] = opt_sh["adam"]["v"] = psh
+        opt = jax.jit(lambda p: init_opt_state(tcfg, p),
+                      out_shardings=opt_sh)(params)
+        step_fn = jax.jit(
+            make_train_step(
+                model, ocfg, tcfg, data_axes=da if use_dist else None,
+                grad_shardings=psh if use_dist else None),
+            donate_argnums=(0, 1))
+    return params, opt, step_fn, NamedSharding(mesh, P(da, None))
 
 
 def main():
@@ -53,6 +84,7 @@ def main():
     envmod.add_env_args(ap)
     args = ap.parse_args()
     envmod.apply_env_args(args)
+    envmod.enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -64,30 +96,12 @@ def main():
                        microbatch=args.microbatch, remat=args.remat)
 
     dims, axes = parse_mesh(args.mesh)
-    mesh = jax.make_mesh(dims, axes)
-    da = shd.data_axes(mesh)
-    use_dist = mesh.size > 1
-    model = build_model(
-        cfg,
-        act_sharding=P(da, "model", None) if use_dist else None,
-        dist=(mesh, da) if use_dist else None)
+    mesh = make_mesh(dims, axes)
 
     print(f"arch={cfg.name} params={cfg.param_count()/1e9:.2f}B "
           f"mesh={dims} remat={args.remat}")
+    params, opt, step_fn, bspec = build_train(cfg, mesh, ocfg, tcfg)
     with mesh:
-        params = jax.jit(
-            model.init,
-            out_shardings=shd.params_shardings(
-                jax.eval_shape(model.init, jax.random.PRNGKey(0)), mesh),
-        )(jax.random.PRNGKey(0))
-        opt = init_opt_state(tcfg, params)
-        step_fn = jax.jit(
-            make_train_step(
-                model, ocfg, tcfg, data_axes=da if use_dist else None,
-                grad_shardings=shd.params_shardings(params, mesh)
-                if use_dist else None),
-            donate_argnums=(0, 1))
-
         start = 0
         ck = Checkpointer(args.ckpt) if args.ckpt else None
         if ck is not None:
@@ -103,7 +117,6 @@ def main():
         pf = Prefetcher(stream, depth=2)
         monitor = HealthMonitor()
         straggler = StragglerPolicy()
-        bspec = NamedSharding(mesh, P(da, None))
         times = {}
         try:
             for s in range(start, args.steps):

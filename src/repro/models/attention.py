@@ -60,15 +60,35 @@ def _project(cfg: ModelConfig, p, x, positions):
     return q, k, v
 
 
-def apply(cfg: ModelConfig, p, x, positions=None):
-    """Full-sequence (train / prefill) forward.  x: [B, S, D]."""
+def _per_shard(fn, dist, b: int, hq: int, hkv: int):
+    """Run ``fn(q, k, v)`` shard by shard over ``dist = (mesh, data_axes)``:
+    batch over the data axes, heads over ``model`` where both head counts
+    divide it (whole GQA groups stay together), else replicated.  A
+    Pallas kernel cannot be partitioned automatically, so the sharded
+    train step hands each device its own block of the attention."""
+    from repro.sharding import _axis_size
+    mesh, da = dist
+    msize = _axis_size(mesh, "model") if "model" in mesh.axis_names else 1
+    heads = "model" if msize > 1 and hq % msize == hkv % msize == 0 else None
+    batch = da if b % _axis_size(mesh, da) == 0 else None
+    spec = jax.sharding.PartitionSpec(batch, heads, None, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)
+
+
+def apply(cfg: ModelConfig, p, x, positions=None, dist=None):
+    """Full-sequence (train / prefill) forward.  x: [B, S, D].  ``dist``
+    (mesh, data_axes) shards the attention core per device."""
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     q, k, v = _project(cfg, p, x, positions)
-    out = attn_ops.attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-        causal=True, window=cfg.sliding_window)
+    attn = lambda q, k, v: attn_ops.attention(
+        q, k, v, causal=True, window=cfg.sliding_window)
+    if dist is not None:
+        attn = _per_shard(attn, dist, b, cfg.num_heads, cfg.num_kv_heads)
+    out = attn(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+               v.transpose(0, 2, 1, 3))
     out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.head_dim)
     return L.dense_apply(p["wo"], out, L.cdtype(cfg))
 

@@ -69,9 +69,9 @@ class PagedView(NamedTuple):
     ``(k, v, k_s, v_s, valid)`` operands; masking (pos / start / null
     page) happens inside the kernel from the same [B] vectors."""
 
-    k: jax.Array                  # [P, page, H, hd] pool
+    k: jax.Array                  # [P, H, page, hd] pool
     v: jax.Array
-    k_s: jax.Array | None         # [P, page, H, 1] per-page scales
+    k_s: jax.Array | None         # [P, H, page, 1] per-row scales
     v_s: jax.Array | None
     block_table: jax.Array        # [B, pages_per_slot] int32
     page_size: int
@@ -408,8 +408,10 @@ class RingCache(KVCache):
 class PagedCache(KVCache):
     """Fixed-size pages + per-slot block tables over a shared pool.
 
-    ``k``/``v``: ``[P, page, H, hd]`` page pools (page 0 reserved as the
-    null page); ``k_s``/``v_s``: per-page scale pools for int8 KV;
+    ``k``/``v``: ``[P, H, page, hd]`` page pools (page 0 reserved as the
+    null page) — head-major, so one (page, head) tile is a contiguous
+    ``[page, hd]`` slab, the block shape the TPU kernel streams;
+    ``k_s``/``v_s``: ``[P, H, page, 1]`` scale pools for int8 KV;
     ``block_table``: ``[B, pages_per_slot]`` int32 page ids (0 =
     unmapped).  Decode reads are IN PLACE: ``token_view`` hands the pool
     + table to the paged-attention kernel as a :class:`PagedView`
@@ -432,9 +434,8 @@ class PagedCache(KVCache):
         return self.block_table.shape[-1] * self.page_size
 
     def _gather(self, c):
-        """[P, page, ...] pool -> [B, W, ...] position-ordered view."""
-        g = c[self.block_table]
-        return g.reshape((g.shape[0], -1) + c.shape[2:])
+        """[P, H, page, *] pool -> [B, W, H, *] position-ordered view."""
+        return _rows(c, self.block_table)
 
     def write_token(self, k, v, pos, per_seq: bool):
         del per_seq  # the page scatter is per-sequence by construction
@@ -442,7 +443,8 @@ class PagedCache(KVCache):
         pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
         pp, off = pos_b // self.page_size, pos_b % self.page_size
         pid = jnp.take_along_axis(self.block_table, pp[:, None], axis=1)[:, 0]
-        new, *_ = self._write(lambda c, n: c.at[pid, off].set(n[:, 0]), k, v)
+        new, *_ = self._write(
+            lambda c, n: c.at[pid, :, off].set(n[:, 0]), k, v)
         return new
 
     def write_tokens(self, k, v, pos):
@@ -452,7 +454,7 @@ class PagedCache(KVCache):
         off = cols % self.page_size                           # [B, S]
         pid = jnp.take_along_axis(self.block_table,
                                   cols // self.page_size, axis=1)
-        new, *_ = self._write(lambda c, n: c.at[pid, off].set(n), k, v)
+        new, *_ = self._write(lambda c, n: c.at[pid, :, off].set(n), k, v)
         return new
 
     def verify_view(self, pos_b, start_b, s: int):
@@ -507,7 +509,7 @@ class PagedCache(KVCache):
         off = jnp.asarray(cols % self.page_size, jnp.int32)
         pid = self.block_table[:, cols // self.page_size]     # [B, S]
         off_b = jnp.broadcast_to(off[None, :], pid.shape)
-        return self._write(lambda c, n: c.at[pid, off_b].set(n), k, v)
+        return self._write(lambda c, n: c.at[pid, :, off_b].set(n), k, v)
 
     def context(self, pos0: int):
         if pos0 == 0:
@@ -515,8 +517,7 @@ class PagedCache(KVCache):
         # gather only the pages covering [0, pos0): chunked prefill cost
         # stays O(pos0), not O(pool width)
         bt = self.block_table[:, :-(-pos0 // self.page_size)]
-        sl = lambda c: None if c is None else (
-            c[bt].reshape((bt.shape[0], -1) + c.shape[2:])[:, :pos0])
+        sl = lambda c: None if c is None else _rows(c, bt)[:, :pos0]
         return sl(self.k), sl(self.v), sl(self.k_s), sl(self.v_s), pos0
 
     # .. engine slot management: indices move, rows don't ..
@@ -548,8 +549,8 @@ class PagedCache(KVCache):
         engine copies a still-shared page to a fresh one and remaps the
         writing slot's table BEFORE the write lands, so the other
         holders' bytes never change.  ``src``/``dst``: [n] int32 page
-        ids.  Works on both per-layer pools ([P, page, H, hd]) and the
-        engine's group-stacked leaves ([G, P, page, H, hd]) — the page
+        ids.  Works on both per-layer pools ([P, H, page, hd]) and the
+        engine's group-stacked leaves ([G, P, H, page, hd]) — the page
         axis is indexed from the right."""
         cp = lambda c: None if c is None else (
             c.at[..., dst, :, :, :].set(c[..., src, :, :, :]))
@@ -568,6 +569,13 @@ class SSMCache(CacheSlots):
     ssd: jax.Array
 
 
+def _rows(pool, table):
+    """[P, H, page, *] pool + [B, n] page ids -> [B, n*page, H, *] rows in
+    table (= position) order — the row backends' operand layout."""
+    g = pool[table].swapaxes(2, 3)              # [B, n, page, H, *]
+    return g.reshape((g.shape[0], -1) + g.shape[3:])
+
+
 def paged_init(batch: int, max_len: int, kv_heads: int, head_dim: int,
                dtype, *, quantized: bool = False,
                page_size: int = DEFAULT_PAGE_SIZE, pages: int | None = None,
@@ -582,7 +590,7 @@ def paged_init(batch: int, max_len: int, kv_heads: int, head_dim: int,
     if mapped and npages < batch * pps:
         raise ValueError(f"identity mapping needs {batch * pps} pages, "
                          f"pool has {npages}")
-    shape = (npages + 1, page_size, kv_heads, head_dim)  # +1: null page 0
+    shape = (npages + 1, kv_heads, page_size, head_dim)  # +1: null page 0
     if mapped:
         table = 1 + np.arange(batch * pps, dtype=np.int32).reshape(batch, pps)
     else:

@@ -224,13 +224,12 @@ def apply_sharded(cfg: ModelConfig, p, x, mesh, data_axes, model_axis="model"):
     P = jax.sharding.PartitionSpec
     w_spec = P(model_axis, None, None)
     x_spec = P(da, None, None) if da else P(None, None, None)
-    from repro.sharding import shard_map_compat
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(x_spec, w_spec, w_spec, w_spec, w_spec, P(None, None)),
         out_specs=(x_spec, P()),
-        check=False,
+        check_vma=False,
     )(x,
       wig if wig is not None else jnp.zeros((m, 1, 1), dt),
       wiu if wiu is not None else jnp.zeros((m, 1, 1), dt),

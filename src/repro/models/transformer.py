@@ -59,7 +59,7 @@ def _layer_apply(cfg: ModelConfig, spec, p, x, dist=None):
     aux = jnp.zeros((), jnp.float32)
     if mixer == "attn":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
-        x = x + attention.apply(cfg, p["mixer"], h)
+        x = x + attention.apply(cfg, p["mixer"], h, dist=dist)
     elif mixer == "ssm":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
         x = x + ssm.apply(cfg, p["mixer"], h)

@@ -3,8 +3,12 @@
 ``quantize_params`` walks a float param tree and replaces every matmul
 kernel (minus skip patterns) with a quantized record:
 
-    {"q": int8 [I, O], "scale": f32 [1, O],          # per-out-channel
-     "planes_packed": int8 [2, I, O]}                # packed EN-T planes
+    {"planes_packed": int8 [2, I, O], "scale": f32 [1, O]}   # EN-T
+    {"q": int8 [I, O], "scale": f32 [1, O]}                  # plain int8
+
+(per-out-channel scales).  An EN-T record carries the planes only: they
+decode exactly to the int8 weight (``dequantize_weight``), so a second
+int8 copy would be bytes nothing reads.
 
 The packed planes are produced ONCE here by the hoisted edge encoder
 (repro.core.multiplier.ent_packed_planes) — the paper's computation reuse
@@ -17,21 +21,24 @@ float activations go straight into the FUSED packed matmul
 (repro.kernels.ent_matmul.ops.ent_quantized_matmul_fused), which performs
 the per-row int8 activation quantization inside the kernel — no separate
 ``quantize_acts`` pass, no f32->int8 HBM round trip.  ``layers.dense_apply``
-dispatches here when it sees a "q" key, so the whole model zoo serves
+dispatches here when it sees a quantized record, so the whole model zoo serves
 quantized without code changes.  Legacy records carrying 4-plane
 ``planes`` (old checkpoints) still work via the unpacked path.
 """
 
 from __future__ import annotations
 
+import functools
 import re
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import QuantConfig
-from repro.core.multiplier import ent_packed_planes
+from repro.core.multiplier import (ent_packed_planes, packed_to_weight,
+                                   planes_to_weight)
 from repro.kernels.ent_matmul import ops as ent_ops
+from repro.kernels.ent_matmul.ref import quantize_rows
 from repro.kernels.int8_matmul import ops as int8_ops
 
 __all__ = ["quantize_weight", "quantize_params", "quantize_acts",
@@ -39,7 +46,8 @@ __all__ = ["quantize_weight", "quantize_params", "quantize_acts",
 
 
 def quantize_weight(w, *, ent_encode: bool = True, per_channel: bool = True):
-    """Symmetric int8 quantization of a [I, O] kernel (+ packed EN-T planes)."""
+    """Symmetric int8 quantization of a [I, O] kernel: packed EN-T planes
+    (``ent_encode``) or the plain int8 weight, plus per-channel scales."""
     w32 = w.astype(jnp.float32)
     if per_channel:
         amax = jnp.max(jnp.abs(w32), axis=0, keepdims=True)     # [1, O]
@@ -47,14 +55,21 @@ def quantize_weight(w, *, ent_encode: bool = True, per_channel: bool = True):
         amax = jnp.max(jnp.abs(w32)).reshape(1, 1)
     scale = jnp.maximum(amax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(w32 / scale), -127, 127).astype(jnp.int8)
-    rec = {"q": q, "scale": scale.astype(jnp.float32)}
     if ent_encode:
-        rec["planes_packed"] = ent_packed_planes(q)
-    return rec
+        return {"planes_packed": ent_packed_planes(q),
+                "scale": scale.astype(jnp.float32)}
+    return {"q": q, "scale": scale.astype(jnp.float32)}
 
 
 def dequantize_weight(rec):
-    return rec["q"].astype(jnp.float32) * rec["scale"]
+    """Float weight of a record (stacked records keep their lead axes)."""
+    if "planes_packed" in rec:
+        w = packed_to_weight(jnp.moveaxis(rec["planes_packed"], -3, 0))
+    elif "planes" in rec:
+        w = planes_to_weight(jnp.moveaxis(rec["planes"], -3, 0))
+    else:
+        w = rec["q"]
+    return w.astype(jnp.float32) * rec["scale"]
 
 
 def quantize_acts(x):
@@ -62,12 +77,9 @@ def quantize_acts(x):
 
     x: [..., K] float -> (q int8, scale f32 [..., 1]).  Kept for the plain
     int8 path and external callers — the EN-T serving path quantizes
-    activations INSIDE the fused packed kernel instead."""
-    x32 = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(x32 / scale), -127, 127).astype(jnp.int8)
-    return q, scale
+    activations INSIDE the fused packed kernel instead, with the same
+    rounding (``ent_matmul.ref.quantize_rows``)."""
+    return quantize_rows(x)
 
 
 def qdense_apply(rec, x, out_dtype=jnp.bfloat16, use_kernel: str = "auto"):
@@ -106,23 +118,28 @@ def quantize_params(params, qcfg: QuantConfig):
     Returns a new tree where {"kernel": w[, "bias": b]} records become
     quantized records; everything else passes through unchanged.
     MoE expert stacks ([..., E, I, O]) and scanned stacks ([G, I, O]) are
-    quantized along their trailing [I, O] with vmapped encoders.
+    quantized along their trailing [I, O], ONE slice at a time
+    (``lax.map`` under ``jit``): the encoder's int32 digit temporaries
+    exist for one [I, O] slice, never for a whole stack — a full-width
+    [36, 2048, 11008] MLP stack would otherwise need ~13 GB of them.
     """
-    import functools
+    encode = functools.partial(quantize_weight, ent_encode=qcfg.ent_encode,
+                               per_channel=qcfg.per_channel)
+
+    @jax.jit
+    def encode_stack(kern):
+        lead = kern.shape[:-2]
+        flat = kern.reshape((-1,) + kern.shape[-2:])
+        rec = jax.lax.map(encode, flat)
+        return jax.tree.map(lambda a: a.reshape(lead + a.shape[1:]), rec)
 
     def walk(node, path):
         if isinstance(node, dict):
             if "kernel" in node and not _should_skip(path, qcfg):
                 kern = node["kernel"]
                 if kern.ndim >= 2:
-                    fn = functools.partial(
-                        quantize_weight, ent_encode=qcfg.ent_encode,
-                        per_channel=qcfg.per_channel)
-                    for _ in range(kern.ndim - 2):
-                        fn = jax.vmap(fn, in_axes=0)
-                    rec = fn(kern)
-                    # vmap of dicts keeps leading axes on each leaf; fix
-                    # scale shape contract for stacked kernels
+                    rec = (jax.jit(encode)(kern) if kern.ndim == 2
+                           else encode_stack(kern))
                     if "bias" in node:
                         rec["bias"] = node["bias"]
                     return rec

@@ -78,12 +78,11 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, num_micro: int,
             axis)
         return out_buf
 
-    from repro.sharding import shard_map_compat
-    y = shard_map_compat(
+    y = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(stage_params, xm)
     return y.reshape((b,) + x.shape[1:])

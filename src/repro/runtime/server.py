@@ -58,6 +58,7 @@ import asyncio
 import contextlib
 import json
 import threading
+import time
 
 from repro.runtime.faults import InjectedFault, fault_point
 from repro.runtime.scheduler import PipelinedScheduler
@@ -153,6 +154,11 @@ class ServingServer:
         while not self._stop_flag:
             if sched.busy:
                 sched.tick()
+                # a tick holds the scheduler lock throughout; yield so the
+                # HTTP loop's submit/cancel/metrics calls waiting on it get
+                # in, rather than this thread retaking it at once until
+                # the batch drains
+                time.sleep(0)
                 if not (sched.engine._active or sched._prefill):
                     # busy but capacity-blocked (parked streams under a
                     # drain/shrink): nap instead of hot-spinning empty

@@ -27,20 +27,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """jax.shard_map across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
 def _axis_size(mesh: Mesh, axis) -> int:
     if axis is None:
         return 1

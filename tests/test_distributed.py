@@ -31,12 +31,13 @@ def _run(code: str):
 def test_shard_map_moe_matches_reference():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.configs.base import ModelConfig, MoEConfig
     from repro.models import moe
 
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(name="t", family="moe", num_layers=2, d_model=32,
                       num_heads=4, num_kv_heads=4, d_ff=64, vocab_size=128,
                       moe=MoEConfig(num_experts=4, top_k=2,
@@ -64,6 +65,7 @@ def test_shard_map_moe_matches_reference():
 def test_sharded_train_step_matches_single_device():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import sharding as shd
     from repro.configs import get_config, reduced_config
@@ -72,7 +74,7 @@ def test_sharded_train_step_matches_single_device():
     from repro.runtime.train_loop import init_opt_state, make_train_step
 
     cfg = reduced_config(get_config("qwen2.5-3b"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ocfg = OptimConfig(lr=1e-3, warmup_steps=0, total_steps=10)
     tcfg = TrainConfig(seq_len=32, global_batch=4)
     batch = {"tokens": jnp.ones((4, 32), jnp.int32),
@@ -105,12 +107,13 @@ def test_sharded_train_step_matches_single_device():
 def test_decode_step_parity_on_mesh():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro import sharding as shd
     from repro.configs import get_config, reduced_config
     from repro.models.transformer import build_model
 
     cfg = reduced_config(get_config("mixtral-8x7b"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     m = build_model(cfg)
     p = m.init(jax.random.PRNGKey(0))
     cache = m.init_cache(4, 8)

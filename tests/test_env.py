@@ -99,3 +99,46 @@ class TestArgparseGlue:
         monkeypatch.setattr(envmod, "find_tcmalloc", lambda: None)
         with pytest.warns(UserWarning, match="libtcmalloc"):
             envmod.apply_env_args(args)
+
+
+class TestCompileCache:
+    @pytest.fixture
+    def restore_cache_dir(self):
+        import jax
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_env_dir_wins_and_nothing_is_set(self, monkeypatch, tmp_path,
+                                             restore_cache_dir):
+        import jax
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert envmod.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_checkout_dir(self, monkeypatch,
+                                           restore_cache_dir):
+        import jax
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert envmod.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        # idempotent: a second call names the same directory
+        assert envmod.enable_compile_cache() == want
+
+    def test_not_called_at_import(self):
+        import subprocess
+        import sys
+        code = ("import jax; from repro.launch import env, serve, train; "
+                "print(jax.config.jax_compilation_cache_dir)")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        e = {k: v for k, v in os.environ.items()
+             if k not in ("JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS")}
+        e["PYTHONPATH"] = os.path.join(root, "src")
+        e["JAX_PLATFORMS"] = "cpu"
+        out = subprocess.run([sys.executable, "-c", code], env=e,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip() == "None"

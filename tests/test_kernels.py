@@ -162,6 +162,24 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=1e-4)
 
+    @pytest.mark.parametrize("window", [None, 32])
+    def test_kernel_path_differentiable(self, window):
+        """Training differentiates through the kernel path (its backward
+        is the oracle's): grads match the oracle's own."""
+        import jax
+        from repro.kernels.flash_attention import ops as attn_ops
+        q, k, v = self._data(1, 4, 2, 128, 128, 32)
+
+        def loss(use_kernel):
+            return lambda q, k, v: jnp.sum(jnp.sin(attn_ops.attention(
+                q, k, v, window=window, use_kernel=use_kernel)))
+
+        got = jax.grad(loss("interpret"), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss("ref"), argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4, rtol=1e-3)
+
 
 class TestMaskedFlashAttention:
     """The ragged serving-prefill kernel vs the blocked jnp oracle."""

@@ -34,7 +34,7 @@ class TestKVQuantPrimitives:
                                  kind="paged", page_size=8)
         assert isinstance(c, kv_cache.PagedCache) and c.quantized
         assert c.k.dtype == jnp.int8
-        assert c.k_s.shape == c.k.shape[:-1] + (1,)   # [P, page, H, 1]
+        assert c.k_s.shape == c.k.shape[:-1] + (1,)   # [P, H, page, 1]
         assert c.block_table.shape == (2, 2)
 
 

@@ -129,6 +129,31 @@ class TestFusedQuantPath:
         want = ent_packed_fused_ref(xf, packed, sw)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
+    @pytest.mark.parametrize("m", [1, 3, 12, 20])
+    def test_unaligned_rows_padded_match_oracle(self, m):
+        """Row counts off the 8-row tile (decode slots, verify bursts)
+        launch padded to it and slice back: exact vs the oracle."""
+        from repro.kernels.int8_matmul.ops import quantized_matmul
+        from repro.quant.quantize import quantize_acts
+        xf = jnp.asarray(RNG.normal(size=(m, 256)).astype(np.float32))
+        w = jnp.asarray(RNG.integers(-127, 128, (256, 128), dtype=np.int8))
+        sw = jnp.asarray(RNG.random((1, 128), dtype=np.float32) * 0.1 + 1e-3)
+        packed = mult.ent_packed_planes(w)
+        got = ent_ops.ent_quantized_matmul_fused(
+            xf, packed, sw, use_kernel="interpret")
+        assert got.shape == (m, 128)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(ent_packed_fused_ref(xf, packed, sw)))
+        xq, sx = quantize_acts(xf)
+        for op, wop in ((ent_ops.ent_quantized_matmul_packed, packed),
+                        (quantized_matmul, w)):
+            got = op(xq, wop, sx, sw, out_dtype=jnp.float32,
+                     use_kernel="interpret")
+            want = op(xq, wop, sx, sw, out_dtype=jnp.float32,
+                      use_kernel="ref")
+            assert got.shape == (m, 128)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
     def test_fused_equals_separate_quantize_then_matmul(self):
         """Fusing the act-quant into the kernel changes WHERE the int8 is
         made, not its value: identical to quantize_acts + packed matmul."""
@@ -201,7 +226,7 @@ class TestQuantRecordIntegration:
         assert rec["planes_packed"].shape == (2, 64, 32)
         np.testing.assert_array_equal(
             np.asarray(mult.packed_to_weight(rec["planes_packed"])),
-            np.asarray(rec["q"], np.int32))
+            np.asarray(quantize_weight(w, ent_encode=False)["q"], np.int32))
 
     def test_qdense_packed_equals_plain_int8(self):
         """Packed EN-T serving path == plain int8 path, bitwise (the

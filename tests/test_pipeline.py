@@ -25,9 +25,10 @@ def test_pipeline_matches_sequential():
     env["PYTHONPATH"] = os.path.join(_REPO, "src")
     code = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.runtime.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     S, D = 4, 16
 
     def stage_fn(params, x):          # one MLP stage

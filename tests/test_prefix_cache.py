@@ -242,7 +242,7 @@ class TestSuffixPrefill:
 
 
 class TestInterleavingProperty:
-    @given(st.data())
+    @given(data=st.data())
     @settings(max_examples=3, deadline=None, derandomize=True)
     def test_random_interleavings_stay_identical_and_leak_free(self, data,
                                                                tiny):

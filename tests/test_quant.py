@@ -24,17 +24,23 @@ class TestWeightQuant:
         assert float(jnp.max(err - per_col_scale[None, :] / 2)) <= 1e-6
 
     def test_packed_planes_reconstruct_q(self):
-        """The packed EN-T planes must decode to exactly the int8 weights."""
+        """The packed EN-T planes must decode to exactly the int8 weights
+        (the packed record carries no int8 copy of its own)."""
         rng = np.random.default_rng(1)
         w = jnp.asarray(rng.normal(size=(32, 48)).astype(np.float32))
         rec = quantize_weight(w, ent_encode=True)
+        assert set(rec) == {"planes_packed", "scale"}
         assert rec["planes_packed"].shape == (2, 32, 48)
         assert rec["planes_packed"].dtype == jnp.int8
         weights = jnp.asarray([1, 16], jnp.int32)
         recon = jnp.sum(rec["planes_packed"].astype(jnp.int32)
                         * weights[:, None, None], axis=0)
+        q = quantize_weight(w, ent_encode=False)["q"]
         np.testing.assert_array_equal(np.asarray(recon),
-                                      np.asarray(rec["q"], np.int32))
+                                      np.asarray(q, np.int32))
+        np.testing.assert_array_equal(
+            np.asarray(dequantize_weight(rec)),
+            np.asarray(q.astype(jnp.float32) * rec["scale"]))
 
     def test_qdense_matches_float_within_quant_error(self):
         rng = np.random.default_rng(2)
@@ -68,15 +74,23 @@ class TestQuantizeParams:
         assert "kernel" in qparams["lm_head"]          # skipped: stays float
         assert "embedding" in qparams["embed"]
         g0 = qparams["groups"][0]
-        assert "q" in g0["mixer"]["wq"] and "planes_packed" in g0["mixer"]["wq"]
+        assert "planes_packed" in g0["mixer"]["wq"]
+        assert "q" not in g0["mixer"]["wq"]            # planes only
         assert "scale" in g0["ffn_norm"]               # norms untouched
 
     def test_stacked_kernels_quantized_per_group(self, setup):
         _, _, params, qparams = setup
         wq = qparams["groups"][0]["mixer"]["wq"]
-        g = params["groups"][0]["mixer"]["wq"]["kernel"].shape[0]
-        assert wq["q"].shape[0] == g                  # [G, I, O] int8
-        assert wq["planes_packed"].shape[:2] == (g, 2)  # vmapped packed planes
+        kern = params["groups"][0]["mixer"]["wq"]["kernel"]
+        g = kern.shape[0]
+        assert wq["planes_packed"].shape == (g, 2) + kern.shape[1:]
+        assert wq["scale"].shape == (g, 1, kern.shape[-1])
+        # slice-at-a-time encoding == encoding each [I, O] slice alone
+        for i in range(g):
+            one = jax.jit(quantize_weight)(kern[i])
+            for leaf in ("planes_packed", "scale"):
+                np.testing.assert_array_equal(np.asarray(wq[leaf][i]),
+                                              np.asarray(one[leaf]))
 
     def test_quantized_model_serves_close_to_float(self, setup):
         cfg, model, params, qparams = setup

@@ -66,6 +66,14 @@ def _prompt(cfg, n, seed=0):
         1, cfg.vocab_size, n).tolist()
 
 
+# The tiny model decodes ~1000 tokens/s on a fast host, so a 480-token
+# stream can finish before the server sees the client go away, and the
+# cancellation these tests assert never happens.  Each decode dispatch
+# sleeps 10 ms under this fault: a stream then lasts seconds on any host.
+PACE_DECODE = Fault("decode.dispatch", times=1 << 30, kind="hang",
+                    seconds=0.01)
+
+
 def test_healthz(served):
     status, body = _get_json(served, "/healthz")
     assert (status, body) == (200, {"ok": True, "state": "ready"})
@@ -130,19 +138,20 @@ def test_disconnect_cancels_and_frees(served):
     pages return to the pool and the leak probe stays clean."""
     cfg, eng, sched, *_ = served
     before = sched.metrics.cancelled_total
-    c, r = _post(served, {"tokens": _prompt(cfg, 8, seed=2),
-                          "max_new_tokens": 480})
-    assert r.status == 200
-    r.read(40)                   # a couple of events, then walk away
-    r.close()                    # closes the socket fd (FIN/RST)
-    c.close()
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
-        _, m = _get_json(served, "/metrics")
-        if (m["requests"]["cancelled"] > before
-                and m["queue"]["active_slots"] == 0):
-            break
-        time.sleep(0.2)
+    with FaultPlan([PACE_DECODE]):
+        c, r = _post(served, {"tokens": _prompt(cfg, 8, seed=2),
+                              "max_new_tokens": 480})
+        assert r.status == 200
+        r.read(40)               # a couple of events, then walk away
+        r.close()                # closes the socket fd (FIN/RST)
+        c.close()
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline:
+            _, m = _get_json(served, "/metrics")
+            if (m["requests"]["cancelled"] > before
+                    and m["queue"]["active_slots"] == 0):
+                break
+            time.sleep(0.2)
     assert m["requests"]["cancelled"] == before + 1
     assert m["leaks_clean"] is True
     assert m["queue"]["active_slots"] == 0
@@ -216,7 +225,7 @@ def test_injected_write_fault_cancels_stream(served):
     the leak probe stays clean."""
     cfg, eng, sched, *_ = served
     before = sched.metrics.cancelled_total
-    with FaultPlan([Fault("server.write", at=3)]):
+    with FaultPlan([Fault("server.write", at=3), PACE_DECODE]):
         c, r = _post(served, {"tokens": _prompt(cfg, 8, seed=5),
                               "max_new_tokens": 480})
         assert r.status == 200
@@ -286,11 +295,11 @@ def test_slow_client_bounded_queue_disconnects(tiny_model):
     sched = PipelinedScheduler(eng, prefill_chunk=8)
     srv = ServingServer(sched, max_stream_queue=1)
     host, port = srv.start()
-    # EVERY write hangs: the writer drains ~5 events/s while the engine
-    # produces hundreds — the bounded queue must overflow long before
-    # the 480-token request finishes, however fast or slow the machine
+    # EVERY write hangs: the writer drains ~5 events/s while the paced
+    # engine produces up to 100 — the bounded queue must overflow long
+    # before the 400-token request finishes
     plan = FaultPlan([Fault("server.write", times=9999, kind="hang",
-                            seconds=0.2)])
+                            seconds=0.2), PACE_DECODE])
     try:
         with plan:
             c = http.client.HTTPConnection(host, port, timeout=600)
@@ -312,5 +321,6 @@ def test_slow_client_bounded_queue_disconnects(tiny_model):
     finally:
         srv.stop()
     assert sched.metrics.cancelled_total == 1
-    assert plan.fired and plan.fired[0].kind == "hang"
+    assert any(f.site == "server.write" and f.kind == "hang"
+               for f in plan.fired)
     eng.check_leaks()

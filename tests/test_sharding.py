@@ -12,21 +12,14 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro import sharding as shd
 
 
-def _abstract_mesh(sizes, names):
-    try:  # newer jax: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh(sizes, names)
-    except TypeError:  # jax<=0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 @pytest.fixture(scope="module")
 def mesh():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def pod_mesh():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestParamRules:

@@ -1,0 +1,101 @@
+"""Compile-only checks of the main-path Pallas kernels for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: these
+tests compile each serving kernel at Qwen2.5-3B's real widths for a
+described ``v5e:2x2`` topology and assert the compiled program holds the
+Mosaic kernel (``tpu_custom_call``).  They catch what interpret-mode
+parity never sees — block shapes that break the TPU tiling rule, VMEM
+overflows — at no chip cost.  Nothing runs, so they say nothing about
+results or speed.
+
+The topology is described inside a module fixture (never at import): a
+process that describes it holds the TPU library until it exits, so only
+the worker that runs this file may touch it.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.ent_matmul import ops as ent_ops
+from repro.kernels.flash_attention import ops as attn_ops
+from repro.kernels.paged_attention import ops as paged_ops
+
+# Qwen2.5-3B: d_model 2048, 16 q / 2 kv heads of 128, d_ff 11008
+D, HQ, HKV, HD, FF = 2048, 16, 2, 128, 11008
+PROJ = [(D, D), (D, HKV * HD), (D, FF), (FF, D)]   # q/o, k/v, up/gate, down
+SLOTS, PAGE, MAX_LEN = 8, 16, 1024
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off meanwhile
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, kernel, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    assert kernel in text, f"kernel {kernel!r} not in the program"
+
+
+@pytest.mark.parametrize("m", [3, 8, 512])
+@pytest.mark.parametrize("k,n", PROJ)
+def test_ent_fused_matmul(one_chip, m, k, n):
+    """Fused packed-plane EN-T matmul: decode (M = slots), prefill
+    (M = 512) and an M off the 8-row tile."""
+    _compile(lambda x, p, sw: ent_ops.ent_quantized_matmul_fused(
+        x, p, sw, use_kernel="pallas"), one_chip, "ent_matmul_packed_fused",
+        ((m, k), jnp.bfloat16), ((2, k, n), jnp.int8), ((1, n), jnp.float32))
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
+def test_paged_attention(one_chip, kv_dtype):
+    """In-place paged decode over the [P, Hkv, page, D] pool, bf16 KV and
+    int8 KV with per-row scale pools."""
+    pps = MAX_LEN // PAGE
+    pool = (SLOTS * pps + 1, HKV, PAGE, HD)
+    shapes = [((SLOTS, HQ, 1, HD), jnp.bfloat16), (pool, kv_dtype),
+              (pool, kv_dtype), ((SLOTS, pps), jnp.int32),
+              ((SLOTS,), jnp.int32), ((SLOTS,), jnp.int32)]
+    if kv_dtype == jnp.int8:
+        shapes += [(pool[:-1] + (1,), jnp.bfloat16)] * 2
+
+    def fn(q, k, v, table, pos, start, *scales):
+        ks, vs = scales if scales else (None, None)
+        return paged_ops.paged_attention(
+            q, k, v, table, pos, start, page_size=PAGE, k_scales=ks,
+            v_scales=vs, use_kernel="pallas")
+
+    _compile(fn, one_chip, "paged_attention", *shapes)
+
+
+@pytest.mark.parametrize("sq,skv", [(512, 512), (256, 512)])
+def test_flash_masked(one_chip, sq, skv):
+    """Ragged masked flash prefill: a whole 512-token prompt, and a
+    256-token suffix over a 256-token cached prefix."""
+    def fn(q, k, v, start):
+        return attn_ops.masked_attention(q, k, v, start=start,
+                                         q_offset=skv - sq,
+                                         use_kernel="pallas")
+
+    _compile(fn, one_chip, "flash_attention_masked",
+             ((1, HQ, sq, HD), jnp.bfloat16),
+             ((1, HKV, skv, HD), jnp.bfloat16),
+             ((1, HKV, skv, HD), jnp.bfloat16), ((1,), jnp.int32))

@@ -55,7 +55,7 @@ class TestTableAndCache:
                       {"block_m": 128, "block_n": 128, "block_k": 256})
         with open(tuning.cache_path()) as f:
             data = json.load(f)
-        assert "int8_matmul:128x512x512" in data
+        assert tuning._key("int8_matmul", (128, 512, 512)) in data
         tuning.clear()
         tuning._LOADED = False  # force reload from disk
         cfg = tuning.get_block_config("int8_matmul", (128, 512, 512))
@@ -68,6 +68,39 @@ class TestTableAndCache:
         assert cfg["block_m"] == 128  # None override ignored -> heuristic
 
 
+class TestDeviceKeying:
+    def test_key_names_device_kind(self):
+        import jax
+        kind = jax.devices()[0].device_kind
+        assert tuning._key("ent_matmul", (8, 2048, 2048)).startswith(
+            f"{kind}/ent_matmul:")
+
+    def test_other_device_entry_not_served(self, monkeypatch):
+        """An entry swept on one device kind (e.g. a CPU / interpret-mode
+        sweep) is never handed to a launch on another."""
+        tuning.record("ent_matmul", (256, 1024, 1024),
+                      {"block_m": 64, "block_n": 256, "block_k": 1024})
+        monkeypatch.setattr(tuning, "_device_kind", lambda: "TPU v5 lite")
+        cfg = tuning.get_block_config("ent_matmul", (256, 1024, 1024))
+        assert cfg == tuning._heuristic("ent_matmul", (256, 1024, 1024))
+
+    def test_unaligned_entry_rejected(self):
+        """A cached block that divides the dims but breaks the TPU block
+        rule (not a multiple of the 8x128 tile, not the full dim) is not
+        served."""
+        tuning.record("ent_matmul", (16, 1024, 1024),
+                      {"block_m": 4, "block_n": 64, "block_k": 1024})
+        cfg = tuning.get_block_config("ent_matmul", (16, 1024, 1024))
+        assert cfg["block_m"] % 8 == 0 and cfg["block_n"] % 128 == 0
+
+    @pytest.mark.parametrize("m", [1, 3, 8, 12, 40, 130])
+    def test_pad_rows_aligned(self, m):
+        mp = tuning.pad_rows(m)
+        assert mp >= m and mp % 8 == 0 and mp - m < 8
+        cfg = tuning.get_block_config("ent_matmul", (mp, 2048, 256))
+        assert tuning._valid("ent_matmul", (mp, 2048, 256), cfg)
+
+
 class TestCacheRobustness:
     """The persistent cache must survive concurrent writers and corrupt
     files: _save is write-temp + atomic rename, _load tolerates garbage."""
@@ -77,13 +110,14 @@ class TestCacheRobustness:
         path = tuning.cache_path()
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
-            f.write('{"int8_matmul:128x512x512": {"block_m"')  # truncated
+            f.write('{"cpu/int8_matmul:128x512x512": {"block_m"')  # cut
         tuning._LOADED = False
         cfg = tuning.get_block_config("ent_matmul", (256, 1024, 1024))
         assert cfg["block_m"] == 128  # heuristic fallback, no raise
         tuning.record("ent_matmul", (256, 1024, 1024), {"block_m": 64})
         with open(path) as f:
-            assert json.load(f)["ent_matmul:256x1024x1024"] == {"block_m": 64}
+            key = tuning._key("ent_matmul", (256, 1024, 1024))
+            assert json.load(f)[key] == {"block_m": 64}
 
     def test_non_dict_payload_tolerated(self):
         import os
@@ -102,8 +136,9 @@ class TestCacheRobustness:
         path = tuning.cache_path()
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
-            json.dump({"ent_matmul:128x512x512": "bogus",
-                       "int8_matmul:128x512x512": {"block_k": 256}}, f)
+            json.dump({tuning._key("ent_matmul", (128, 512, 512)): "bogus",
+                       tuning._key("int8_matmul", (128, 512, 512)):
+                           {"block_k": 256}}, f)
         tuning._LOADED = False
         tuning._TABLE.clear()
         assert tuning.get_block_config(
