@@ -31,6 +31,7 @@ from repro.configs.base import QuantConfig
 from repro.launch import env as envmod
 from repro.models.transformer import build_model
 from repro.quant.quantize import quantize_params
+from repro.runtime.metrics import SPANS
 from repro.runtime.serve_loop import ServeEngine, generate
 
 
@@ -221,7 +222,9 @@ def main():
     params = model.init(jax.random.PRNGKey(0))
     if args.quantize:
         t0 = time.perf_counter()
-        params = quantize_params(params, QuantConfig(enabled=True))
+        with SPANS.span("quant.encode", n=cfg.num_layers):
+            params = jax.block_until_ready(
+                quantize_params(params, QuantConfig(enabled=True)))
         print(f"EN-T encode (once): {time.perf_counter()-t0:.2f}s")
 
     rng = np.random.default_rng(0)

@@ -58,14 +58,16 @@ def _layer_apply(cfg: ModelConfig, spec, p, x, dist=None):
     mixer, ffn = spec
     aux = jnp.zeros((), jnp.float32)
     if mixer == "attn":
-        h = L.norm_apply(cfg, p["mixer_norm"], x)
-        x = x + attention.apply(cfg, p["mixer"], h, dist=dist)
+        with jax.named_scope("attn"):
+            h = L.norm_apply(cfg, p["mixer_norm"], x)
+            x = x + attention.apply(cfg, p["mixer"], h, dist=dist)
     elif mixer == "ssm":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
         x = x + ssm.apply(cfg, p["mixer"], h)
     if ffn == "dense":
-        h = L.norm_apply(cfg, p["ffn_norm"], x)
-        x = x + L.mlp_apply(cfg, p["ffn"], h)
+        with jax.named_scope("mlp"):
+            h = L.norm_apply(cfg, p["ffn_norm"], x)
+            x = x + L.mlp_apply(cfg, p["ffn"], h)
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, aux = _moe_apply(cfg, p["ffn"], h, dist)
@@ -77,18 +79,20 @@ def _layer_decode(cfg: ModelConfig, spec, p, x, cache, pos, dist=None,
                   start=None, token_mask=None):
     mixer, ffn = spec
     if mixer == "attn":
-        h = L.norm_apply(cfg, p["mixer_norm"], x)
-        y, cache = attention.decode_step(cfg, p["mixer"], h, cache, pos,
-                                         start=start)
-        x = x + y
+        with jax.named_scope("attn"):
+            h = L.norm_apply(cfg, p["mixer_norm"], x)
+            y, cache = attention.decode_step(cfg, p["mixer"], h, cache, pos,
+                                             start=start)
+            x = x + y
     elif mixer == "ssm":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
         y, cache = ssm.decode_step(cfg, p["mixer"], h, cache, pos,
                                    token_mask=token_mask)
         x = x + y
     if ffn == "dense":
-        h = L.norm_apply(cfg, p["ffn_norm"], x)
-        x = x + L.mlp_apply(cfg, p["ffn"], h)
+        with jax.named_scope("mlp"):
+            h = L.norm_apply(cfg, p["ffn_norm"], x)
+            x = x + L.mlp_apply(cfg, p["ffn"], h)
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, _ = _moe_apply(cfg, p["ffn"], h, dist)
@@ -103,17 +107,19 @@ def _layer_verify(cfg: ModelConfig, spec, p, x, cache, pos, dist=None,
     mixer, ffn = spec
     states = None
     if mixer == "attn":
-        h = L.norm_apply(cfg, p["mixer_norm"], x)
-        y, cache = attention.verify_step(cfg, p["mixer"], h, cache, pos,
-                                         start=start)
-        x = x + y
+        with jax.named_scope("attn"):
+            h = L.norm_apply(cfg, p["mixer_norm"], x)
+            y, cache = attention.verify_step(cfg, p["mixer"], h, cache, pos,
+                                             start=start)
+            x = x + y
     elif mixer == "ssm":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
         y, cache, states = ssm.verify_step(cfg, p["mixer"], h, cache)
         x = x + y
     if ffn == "dense":
-        h = L.norm_apply(cfg, p["ffn_norm"], x)
-        x = x + L.mlp_apply(cfg, p["ffn"], h)
+        with jax.named_scope("mlp"):
+            h = L.norm_apply(cfg, p["ffn_norm"], x)
+            x = x + L.mlp_apply(cfg, p["ffn"], h)
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, _ = _moe_apply(cfg, p["ffn"], h, dist)
@@ -130,11 +136,12 @@ def _layer_prefill(cfg: ModelConfig, spec, p, x, cache, start=None,
     layer's recurrent state cannot be read around)."""
     mixer, ffn = spec
     if mixer == "attn":
-        h = L.norm_apply(cfg, p["mixer_norm"], x)
-        y, cache = attention.prefill_step(cfg, p["mixer"], h, cache,
-                                          start=start, pos0=pos0,
-                                          write=write)
-        x = x + y
+        with jax.named_scope("attn"):
+            h = L.norm_apply(cfg, p["mixer_norm"], x)
+            y, cache = attention.prefill_step(cfg, p["mixer"], h, cache,
+                                              start=start, pos0=pos0,
+                                              write=write)
+            x = x + y
     elif mixer == "ssm":
         if not write:
             raise ValueError(
@@ -144,8 +151,9 @@ def _layer_prefill(cfg: ModelConfig, spec, p, x, cache, start=None,
         y, cache = ssm.prefill_step(cfg, p["mixer"], h, cache, mask=pad_mask)
         x = x + y
     if ffn == "dense":
-        h = L.norm_apply(cfg, p["ffn_norm"], x)
-        x = x + L.mlp_apply(cfg, p["ffn"], h)
+        with jax.named_scope("mlp"):
+            h = L.norm_apply(cfg, p["ffn_norm"], x)
+            x = x + L.mlp_apply(cfg, p["ffn"], h)
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, _ = _moe_apply(cfg, p["ffn"], h, dist)
@@ -334,7 +342,8 @@ class Model:
             out["loss"] = ce + out["aux_loss"]
             out["ce_loss"] = ce
             return out
-        logits = L.lm_head_apply(cfg, head, params["embed"], x)
+        with jax.named_scope("head"):
+            logits = L.lm_head_apply(cfg, head, params["embed"], x)
         if logits.ndim == 3:
             # keep [B@data, S@model, V] sharded through the CE backward
             logits = self._constrain(logits)
@@ -425,7 +434,9 @@ class Model:
             group_body, (x, cache["layers"]),
             (params["groups"], jnp.arange(cfg.num_groups)))
         x = L.norm_apply(cfg, params["final_norm"], x)
-        logits = L.lm_head_apply(cfg, params.get("lm_head"), params["embed"], x)
+        with jax.named_scope("head"):
+            logits = L.lm_head_apply(cfg, params.get("lm_head"),
+                                     params["embed"], x)
         new_cache = dict(cache)
         new_cache["layers"] = new_layer_caches
         new_cache["pos"] = pos + 1
@@ -475,7 +486,9 @@ class Model:
             group_body, (x, cache["layers"]),
             (params["groups"], jnp.arange(cfg.num_groups)))
         x = L.norm_apply(cfg, params["final_norm"], x)
-        logits = L.lm_head_apply(cfg, params.get("lm_head"), params["embed"], x)
+        with jax.named_scope("head"):
+            logits = L.lm_head_apply(cfg, params.get("lm_head"),
+                                     params["embed"], x)
         new_cache = dict(cache)
         new_cache["layers"] = new_layer_caches
         new_cache["pos"] = pos + tokens.shape[1]

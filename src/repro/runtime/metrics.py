@@ -16,8 +16,14 @@ numbers the synchronous bench loops never saw:
   inter-token latency histograms, queue-depth gauge/high-water mark,
   shed and cancellation counters, and completed-request accounting.
   ``snapshot()`` is what ``GET /metrics`` serializes.
+* :class:`SpanLog` — timed spans of the serving path (scheduler tick,
+  device sync, admission, KV-table dispatches, SSE writes, ...) in a
+  preallocated ring, on the ``time.monotonic`` clock.  Each span also
+  opens a ``jax.profiler.TraceAnnotation``, so a profiled run shows it
+  on the host plane beside the device ops.  ``SPANS`` is the one
+  process-wide log, as the profiler is process-wide.
 
-Both are thread-safe (one lock per object): the engine thread records
+All are thread-safe (one lock per object): the engine thread records
 while the asyncio thread snapshots.  All record/percentile work is
 plain numpy on the host — nothing here ever touches the device, so
 metering cannot perturb the dispatch stream it measures.
@@ -25,10 +31,13 @@ metering cannot perturb the dispatch stream it measures.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
+from typing import NamedTuple
 
+import jax
 import numpy as np
 
 
@@ -88,9 +97,175 @@ class LatencyHistogram:
             }
 
 
+class Span(NamedTuple):
+    """One finished span: times are ``time.monotonic`` seconds; ``parent``
+    is the id of the innermost span open on the recording thread (-1:
+    none); ``uid`` the request (-1: none); ``n`` an integer payload."""
+    name: str
+    t0: float
+    t1: float
+    id: int
+    parent: int
+    uid: int
+    n: int
+
+
+SUMMARY_S = 60.0    # the span summary of /metrics covers this many seconds
+_SPAN_DTYPE = np.dtype([("name", np.int32), ("t0", np.float64),
+                        ("t1", np.float64), ("id", np.int64),
+                        ("parent", np.int64), ("uid", np.int64),
+                        ("n", np.int64)])
+
+
+class _Open:
+    """A span in progress (``SpanLog.span``).  ``uid`` and ``n`` may be
+    set inside the block, once they are known."""
+    __slots__ = ("log", "name", "uid", "n", "id", "parent", "t0", "_ann")
+
+    def __init__(self, log, name, uid, n):
+        self.log, self.name, self.uid, self.n = log, name, uid, n
+
+    def __enter__(self):
+        stack = self.log._stack()
+        self.id = next(self.log._ids)
+        self.parent = stack[-1] if stack else -1
+        stack.append(self.id)
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic()
+        self._ann.__exit__(*exc)
+        self.log._stack().pop()
+        self.log._write(self.name, self.t0, t1, self.id, self.parent,
+                        self.uid, self.n)
+
+
+class _Off:
+    """The span of a log that records nothing."""
+    __slots__ = ("uid", "n")
+    id = -1
+
+    def __init__(self):
+        self.uid, self.n = -1, 0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+class SpanLog:
+    """Bounded ring of finished spans (numpy columns, preallocated).
+
+    ``span(name, uid, n)`` times a block of code on the calling thread;
+    spans nest through a thread-local stack (a span's ``parent`` is the
+    innermost span open on its thread).  ``record`` adds a span whose
+    start was taken elsewhere, such as on another thread.  The ring keeps
+    the last ``capacity`` spans; ``lost_t1`` is the latest end among the
+    spans it has overwritten (-inf while none), so a reader can tell a
+    window it no longer holds whole.  ``capacity=0`` records nothing.
+    Spans are never rolled back: a tick the fault-tolerant scheduler retries
+    keeps the spans of its failed attempt.
+    """
+
+    def __init__(self, capacity: int = 1 << 17):
+        if capacity < 0:
+            raise ValueError(f"capacity must be >= 0, got {capacity}")
+        self.capacity = capacity
+        self._codes: dict[str, int] = {}
+        self._names: list[str] = []
+        self._buf = np.zeros((capacity,), _SPAN_DTYPE)
+        self._written = 0                                   # lifetime
+        self.lost_t1 = -np.inf      # latest end of an overwritten span
+        self._ids = itertools.count()
+        self._lock = threading.Lock()
+        self._local = threading.local()
+
+    def span(self, name: str, uid: int = -1, n: int = 0):
+        """Context manager timing its block as span ``name``; also a
+        ``jax.profiler.TraceAnnotation`` of that name."""
+        if not self.capacity:
+            return _Off()
+        return _Open(self, name, uid, n)
+
+    def record(self, name: str, t0: float, t1: float, uid: int = -1,
+               n: int = 0) -> None:
+        """Add a finished span that started at ``t0`` (monotonic seconds)."""
+        if not self.capacity:
+            return
+        stack = self._stack()
+        self._write(name, t0, t1, next(self._ids),
+                    stack[-1] if stack else -1, uid, n)
+
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def _write(self, name, t0, t1, sid, parent, uid, n) -> None:
+        with self._lock:
+            code = self._codes.get(name)
+            if code is None:
+                code = self._codes[name] = len(self._names)
+                self._names.append(name)
+            at = self._written % self.capacity
+            if self._written >= self.capacity:
+                self.lost_t1 = max(self.lost_t1, float(self._buf[at]["t1"]))
+            self._buf[at] = (code, t0, t1, sid, parent, uid, n)
+            self._written += 1
+
+    def _rows(self, name, lo, hi) -> np.ndarray:
+        """Copy of the records, oldest first, of the spans ``name``
+        (None: all) that end in [lo, hi).  Call with the lock held."""
+        held = min(self._written, self.capacity)
+        rows = self._buf[np.arange(self._written - held, self._written)
+                         % max(self.capacity, 1)]
+        keep = (rows["t1"] >= lo) & (rows["t1"] < hi)
+        if name is not None:
+            keep &= rows["name"] == self._codes.get(name, -1)
+        return rows[keep]
+
+    def select(self, name: str | None = None, lo: float = -np.inf,
+               hi: float = np.inf) -> list[Span]:
+        """The spans named ``name`` (None: every span) that end in
+        [lo, hi), oldest first."""
+        with self._lock:
+            rows = self._rows(name, lo, hi)
+            names = list(self._names)
+        return [Span(names[r[0]], *r.tolist()[1:]) for r in rows]
+
+    def summary(self) -> dict:
+        """{name: {count, p50_ms, p99_ms}} of the spans that ended in the
+        last ``SUMMARY_S`` seconds: the ``spans`` section of ``/metrics``."""
+        with self._lock:
+            rows = self._rows(None, time.monotonic() - SUMMARY_S, np.inf)
+            names = list(self._names)
+        codes, dur = rows["name"], rows["t1"] - rows["t0"]
+        out = {}
+        for code in np.unique(codes):
+            d = dur[codes == code] * 1e3
+            out[names[code]] = {
+                "count": int(d.size),
+                "p50_ms": round(float(np.percentile(d, 50)), 3),
+                "p99_ms": round(float(np.percentile(d, 99)), 3)}
+        return out
+
+
+# The process-wide span log: the scheduler, engine, server and quantizer
+# write here unless handed another (``ServingMetrics(spans=...)``).
+SPANS = SpanLog()
+
+
 @dataclass
 class _ReqTimes:
     submit_t: float
+    admit_t: float | None = None
     first_token_t: float | None = None
     last_token_t: float | None = None
     tokens: int = 0
@@ -107,16 +282,22 @@ class ServingMetrics:
                         \\->  cancelled(uid)  (client went away)
 
     The first ``token`` records TTFT (against ``submitted``); each
-    subsequent one records the inter-token gap.  ``queue_depth`` is a
-    gauge the scheduler sets each tick; ``spec`` carries the engine's
-    acceptance-weighted speculative stats through to ``snapshot()``.
+    subsequent one records the inter-token gap.  ``admitted`` records the
+    queue wait (submit to admission start); the rest of TTFT, admission
+    start to first token, is span ``req.prefill`` of ``spans`` (default:
+    the process-wide ``SPANS``).
+    ``queue_depth`` is a gauge the scheduler sets each tick; ``spec``
+    carries the engine's acceptance-weighted speculative stats through
+    to ``snapshot()``.
     """
 
-    def __init__(self, *, window: int = 8192, clock=time.monotonic):
+    def __init__(self, *, window: int = 8192, clock=time.monotonic,
+                 spans: SpanLog | None = None):
+        self.spans = SPANS if spans is None else spans
         self.ttft = LatencyHistogram(window)
         self.itl = LatencyHistogram(window)
         self.queue_wait = LatencyHistogram(window)
-        self._clock = clock
+        self.clock = clock
         self._lock = threading.Lock()
         self._live: dict[int, _ReqTimes] = {}
         self.queue_depth = 0
@@ -143,7 +324,7 @@ class ServingMetrics:
 
     # .. lifecycle ..
     def submitted(self, uid: int, now: float | None = None) -> None:
-        now = self._clock() if now is None else now
+        now = self.clock() if now is None else now
         with self._lock:
             self._live[uid] = _ReqTimes(submit_t=now)
             self.submitted_total += 1
@@ -151,15 +332,21 @@ class ServingMetrics:
                 self._t0 = now
 
     def admitted(self, uid: int, now: float | None = None) -> None:
-        """Request left the queue for a slot (records queue wait)."""
-        now = self._clock() if now is None else now
+        """Request left the queue for a slot at ``now``, the start of its
+        admission (records queue wait)."""
+        now = self.clock() if now is None else now
         with self._lock:
             rt = self._live.get(uid)
-        if rt is not None:
-            self.queue_wait.record(now - rt.submit_t)
+            if rt is not None:
+                rt.admit_t = now
+        if rt is None:
+            return
+        self.queue_wait.record(now - rt.submit_t)
+        if rt.first_token_t is not None:   # atomic admission: token first
+            self.spans.record("req.prefill", now, rt.first_token_t, uid)
 
     def token(self, uid: int, now: float | None = None) -> None:
-        now = self._clock() if now is None else now
+        now = self.clock() if now is None else now
         with self._lock:
             rt = self._live.get(uid)
             if rt is None:         # cancelled mid-flight: token raced out
@@ -172,14 +359,16 @@ class ServingMetrics:
             rt.tokens += 1
             self.tokens_total += 1
             self._t_last = now
-            submit_t = rt.submit_t
+            submit_t, admit_t = rt.submit_t, rt.admit_t
         if first:
             self.ttft.record(now - submit_t)
+            if admit_t is not None:
+                self.spans.record("req.prefill", admit_t, now, uid)
         elif prev is not None:
             self.itl.record(now - prev)
 
     def finished(self, uid: int, now: float | None = None) -> None:
-        now = self._clock() if now is None else now
+        now = self.clock() if now is None else now
         with self._lock:
             self._live.pop(uid, None)
             self.finished_total += 1
@@ -288,6 +477,7 @@ class ServingMetrics:
         out["ttft"] = self.ttft.snapshot()
         out["inter_token"] = self.itl.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()
+        out["spans"] = self.spans.summary()
         if spec_stats:
             drafted = spec_stats.get("drafted", 0)
             out["spec_decode"] = dict(

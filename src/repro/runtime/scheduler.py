@@ -57,7 +57,11 @@ with a 429-style ``None``, never a stall), **priorities** (lower value
 admits first; FIFO within a priority), and **per-request deadlines**
 (a request still queued past its deadline is shed, not started).  All
 request lifecycle events feed a :class:`~repro.runtime.metrics.
-ServingMetrics` (TTFT, inter-token latency, queue depth, shed counts).
+ServingMetrics` (TTFT, inter-token latency, queue depth, shed counts),
+and every tick records spans into that metrics' span log (the engine's
+too): ``sched.tick`` around ``sched.decode``, ``sched.chunk``,
+``sched.admit``, ``sched.sync`` (the blocking device->host copies
+alone) and ``sched.emit``.
 
 Thread safety: one re-entrant lock guards every public method, so an
 HTTP thread may ``submit``/``cancel`` while the engine thread runs
@@ -117,7 +121,8 @@ import numpy as np
 
 from repro.runtime.faults import fault_point
 from repro.runtime.metrics import ServingMetrics
-from repro.runtime.serve_loop import Request, ServeEngine, _SlotState
+from repro.runtime.serve_loop import (Request, ServeEngine, _SlotState,
+                                      host_copy)
 
 QUEUED, PREFILL, ACTIVE = "queued", "prefill", "active"
 DONE, SHED, CANCELLED = "done", "shed", "cancelled"
@@ -169,6 +174,7 @@ class _Entry:
     tok_dev: object                  # [slots] int32 on device (or None)
     active: list                     # [(slot, uid)] snapshot at dispatch
     admits: list = field(default_factory=list)   # [(slot, uid, tok[1] dev)]
+    span: int = -1                   # id of the sched.decode span
 
 
 @dataclass
@@ -232,6 +238,7 @@ class PipelinedScheduler:
         self.max_queue = max_queue
         self.chunk = max(1, prefill_chunk or engine.prefill_chunk or 32)
         self.metrics = metrics if metrics is not None else ServingMetrics()
+        self.spans = engine.spans = self.metrics.spans
         self._clock = clock
         # dispatch-ahead ticks write past the host pos mirror: widen
         # every reservation by the pipeline depth (the speculative
@@ -449,45 +456,59 @@ class PipelinedScheduler:
     # .. decode stream ..
     def _feed(self):
         if self._tok_dev is None:
-            self._tok_dev = jnp.asarray(self.engine._next_tok)
+            self._tok_dev = host_copy(self.engine._next_tok)
         return self._tok_dev
 
     def _dispatch_decode(self) -> _Entry:
         eng = self.engine
-        fault_point("decode.dispatch")
-        # a dispatch-ahead tick writes up to len(pipeline) positions
-        # past the host mirror: make that whole span write-safe first
-        eng._map_tick_pages(len(self._pipeline))
-        toks = self._feed()
-        pmask = jnp.asarray(self._park_mask)
-        ppos = jnp.asarray(self._park_pos)
-        fault_point("sampler")
-        if eng._temp.any() or eng._truncates:
-            tok, eng._keys, eng.cache = self._sampled_tick(
-                eng.params, eng.cache, toks, pmask, ppos,
-                jnp.asarray(eng._temp), eng._keys)
-        else:
-            tok, eng.cache = self._greedy_tick(
-                eng.params, eng.cache, toks, pmask, ppos)
-        self._tok_dev = tok
-        return _Entry(tok, [(s, st.req.uid) for s, st in
-                            eng._active.items()])
+        with self.spans.span("sched.decode", n=len(eng._active)) as sp:
+            fault_point("decode.dispatch")
+            # a dispatch-ahead tick writes up to len(pipeline) positions
+            # past the host mirror: make that whole span write-safe first
+            eng._map_tick_pages(len(self._pipeline))
+            toks = self._feed()
+            pmask = host_copy(self._park_mask)
+            ppos = host_copy(self._park_pos)
+            fault_point("sampler")
+            if eng._temp.any() or eng._truncates:
+                tok, eng._keys, eng.cache = self._sampled_tick(
+                    eng.params, eng.cache, toks, pmask, ppos,
+                    host_copy(eng._temp), eng._keys)
+            else:
+                tok, eng.cache = self._greedy_tick(
+                    eng.params, eng.cache, toks, pmask, ppos)
+            self._tok_dev = tok
+            return _Entry(tok, [(s, st.req.uid) for s, st in
+                                eng._active.items()], span=sp.id)
 
     def _process_entry(self, entry: _Entry) -> None:
         eng = self.engine
-        toks = None if entry.tok_dev is None else np.asarray(entry.tok_dev)
-        for slot, uid in entry.active:
-            st = eng._active.get(slot)
-            if st is None or st.req.uid != uid:
-                continue        # finished/cancelled after this dispatch:
+        # sched.sync holds only the blocking device->host copies (its
+        # payload: the id of the sched.decode span whose tokens it waits
+        # for, -1 for an admission's first token); emission, and the
+        # releases it triggers, is sched.emit.  The decode tokens are
+        # emitted before an admission's first token is synced: that one
+        # waits for the admission's final chunk, dispatched after them.
+        with self.spans.span("sched.sync", n=entry.span):
+            toks = (None if entry.tok_dev is None
+                    else np.asarray(entry.tok_dev))
+        with self.spans.span("sched.emit") as sp:
+            for slot, uid in entry.active:
+                st = eng._active.get(slot)
+                if st is None or st.req.uid != uid:
+                    continue    # finished/cancelled after this dispatch:
                                 # the in-flight token is discarded
-            eng._pos[slot] += 1
-            eng._emit(slot, int(toks[slot]))
+                eng._pos[slot] += 1
+                eng._emit(slot, int(toks[slot]))
+                sp.n += 1
         for slot, uid, tdev in entry.admits:
             st = eng._active.get(slot)
             if st is None or st.req.uid != uid:
                 continue        # cancelled between admission and here
-            eng._emit(slot, int(np.asarray(tdev)[0]))
+            with self.spans.span("sched.sync", n=-1):
+                tok = int(np.asarray(tdev)[0])
+            with self.spans.span("sched.emit", n=1):
+                eng._emit(slot, tok)
 
     # .. prefill stream ..
     def _pop_ready(self, now: float) -> Request | None:
@@ -541,11 +562,13 @@ class PipelinedScheduler:
             if self._chunked:
                 ok = self._start_admission(slot, req)
             else:
-                ok = eng._admit_one(slot, req)
+                t_admit = self.metrics.clock()
+                with self.spans.span("sched.admit", uid=req.uid):
+                    ok = eng._admit_one(slot, req)
                 if ok:
                     eng._free.remove(slot)
                     self._status[req.uid] = ACTIVE
-                    self.metrics.admitted(req.uid)
+                    self.metrics.admitted(req.uid, now=t_admit)
                     if slot in eng._active:   # not done at token one
                         self._tok_dev = self._feed().at[slot].set(
                             jnp.int32(int(eng._next_tok[slot])))
@@ -564,25 +587,26 @@ class PipelinedScheduler:
         cached prompt — one peek dispatch) or park the slot and hand the
         suffix to the chunk stream."""
         eng = self.engine
-        fault_point("prefill.dispatch", uid=req.uid)
-        pos0 = eng._map_prefix(slot, req)
-        if pos0 is None:
-            return False
-        eng._free.remove(slot)
-        self.metrics.admitted(req.uid)
-        n = len(req.tokens)
-        if pos0 >= n:
-            # fully cached: a read-only peek of the last token's logits
-            view = eng._view(eng.cache["layers"], slot)
-            toks = jnp.asarray([[req.tokens[-1]]], jnp.int32)
-            logits = eng._peek(eng.params, toks, view, n - 1)
-            self._complete_admission(slot, req, logits)
-        else:
-            self._prefill = _Prefill(slot, req, pos0, n)
-            self._status[req.uid] = PREFILL
-            self._park_mask[slot] = True
-            self._park_pos[slot] = pos0
-        return True
+        with self.spans.span("sched.admit", uid=req.uid):
+            fault_point("prefill.dispatch", uid=req.uid)
+            pos0 = eng._map_prefix(slot, req)
+            if pos0 is None:
+                return False
+            eng._free.remove(slot)
+            self.metrics.admitted(req.uid)
+            n = len(req.tokens)
+            if pos0 >= n:
+                # fully cached: a read-only peek of the last token's logits
+                view = eng._view(eng.cache["layers"], slot)
+                toks = jnp.asarray([[req.tokens[-1]]], jnp.int32)
+                logits = eng._peek(eng.params, toks, view, n - 1)
+                self._complete_admission(slot, req, logits)
+            else:
+                self._prefill = _Prefill(slot, req, pos0, n)
+                self._status[req.uid] = PREFILL
+                self._park_mask[slot] = True
+                self._park_pos[slot] = pos0
+            return True
 
     def _advance_chunk(self) -> None:
         """Dispatch ONE grid-aligned prefill chunk for the admission in
@@ -593,26 +617,27 @@ class PipelinedScheduler:
         pf = self._prefill
         assert pf is not None
         eng, req, slot, lo = self.engine, pf.req, pf.slot, pf.lo
-        fault_point("prefill.dispatch", uid=req.uid)
         cap = eng._pps * eng.page_size
         hi = min((lo // self.chunk + 1) * self.chunk, cap)
         real_hi = min(hi, pf.n)
-        toks = jnp.asarray(
-            [req.tokens[lo:real_hi]
-             + [eng.pad_id] * (hi - real_hi)], jnp.int32)
-        slot_t = jnp.int32(slot)
-        if real_hi < pf.n:
-            eng.cache["layers"] = self._chunk_mid(
-                eng.params, toks, eng.cache["layers"], slot_t, lo)
-            pf.lo = hi
-            self._park_pos[slot] = hi         # frontier moved
-        else:
-            logits, eng.cache["layers"] = self._chunk_last(
-                eng.params, toks, eng.cache["layers"], slot_t, lo,
-                jnp.asarray(pf.n - 1 - lo, jnp.int32))
-            self._prefill = None
-            self._park_mask[slot] = False
-            self._complete_admission(slot, req, logits)
+        with self.spans.span("sched.chunk", uid=req.uid, n=real_hi - lo):
+            fault_point("prefill.dispatch", uid=req.uid)
+            toks = jnp.asarray(
+                [req.tokens[lo:real_hi]
+                 + [eng.pad_id] * (hi - real_hi)], jnp.int32)
+            slot_t = jnp.int32(slot)
+            if real_hi < pf.n:
+                eng.cache["layers"] = self._chunk_mid(
+                    eng.params, toks, eng.cache["layers"], slot_t, lo)
+                pf.lo = hi
+                self._park_pos[slot] = hi     # frontier moved
+            else:
+                logits, eng.cache["layers"] = self._chunk_last(
+                    eng.params, toks, eng.cache["layers"], slot_t, lo,
+                    jnp.asarray(pf.n - 1 - lo, jnp.int32))
+                self._prefill = None
+                self._park_mask[slot] = False
+                self._complete_admission(slot, req, logits)
 
     def _complete_admission(self, slot: int, req: Request, logits) -> None:
         """Activate the slot and sample the first token WITHOUT a host
@@ -650,7 +675,8 @@ class PipelinedScheduler:
         in-flight depth.  In fault-tolerant mode the whole tick runs
         inside a snapshot/rollback envelope (see the class docstring).
         Returns True while there is (or will be) work."""
-        with self._lock:
+        with self._lock, self.spans.span("sched.tick",
+                                         n=len(self.engine._active)):
             if not self._ft:
                 return self._tick_inner()
             return self._tick_ft()

@@ -59,6 +59,7 @@ from repro.models import kv_cache
 from repro.models.transformer import Model
 from repro.runtime import sampling
 from repro.runtime.faults import InjectedFault, fault_point
+from repro.runtime.metrics import SPANS
 from repro.runtime.page_allocator import PageAllocator
 from repro.runtime.prefix_cache import PrefixCache
 
@@ -161,8 +162,10 @@ def generate(model: Model, params, prompt_tokens, steps: int, *,
             for t in range(s0):
                 logits, cache = step(params, cache, prompt_tokens[:, t])
         else:
-            sstep = jax.jit(lambda p, c, t, m: model.decode_step(
-                p, c, tokens=t, token_mask=m))
+            def masked_step(p, c, t, m):
+                return model.decode_step(p, c, tokens=t, token_mask=m)
+
+            sstep = jax.jit(masked_step)
             for t in range(s0):
                 logits, cache = sstep(params, cache, prompt_tokens[:, t],
                                       mask[:, t])
@@ -196,6 +199,50 @@ def generate(model: Model, params, prompt_tokens, steps: int, *,
 
 
 # --- continuous-batching engine ----------------------------------------------
+
+# Backend-dispatched slot management over the per-layer caches
+# (kv_cache.CacheSlots): the SAME jitted helpers drive dense row splices,
+# ring splices and paged zero-copy pool adoption.  Named functions, so
+# each is its own module (``jit_kv_set_tables``, ...) in a device trace.
+
+def kv_view(layers, slot):
+    return tuple(c.prefill_view(slot) if hasattr(c, "prefill_view") else c
+                 for c in layers)
+
+
+def kv_admit_slot(full, one, slot):
+    return tuple(f.admit(o, slot) if hasattr(f, "admit") else f
+                 for f, o in zip(full, one))
+
+
+def kv_release_slot(layers, slot):
+    return tuple(c.free_slot(slot) if hasattr(c, "free_slot") else c
+                 for c in layers)
+
+
+def kv_set_tables(layers, table):
+    return tuple(c.with_table(table) if isinstance(c, kv_cache.PagedCache)
+                 else c for c in layers)
+
+
+def kv_copy_pages(layers, src, dst):
+    """Device half of copy-on-write: duplicate whole pages src -> dst
+    across every paged layer pool in one dispatch."""
+    return tuple(c.copy_pages(src, dst) if isinstance(c, kv_cache.PagedCache)
+                 else c for c in layers)
+
+
+def host_copy(mirror: np.ndarray) -> jax.Array:
+    """A device array of a host mirror that the engine mutates in place.
+    On the CPU backend ``jnp.asarray`` (and ``jnp.array``, whose copy is
+    dispatched) may read the numpy buffer only once the device queue
+    reaches it, after later host writes: copy on the host first."""
+    return jnp.asarray(mirror.copy())
+
+
+def argmax_tokens(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
 
 def _bucket(n: int, lo: int) -> int:
     """Round a prompt length up to a power of two (>= lo) so prefill jits
@@ -457,22 +504,13 @@ class ServeEngine:
         self.cache = cache
         self._decode = make_serve_step(model)
 
-        # backend-dispatched slot management (kv_cache.CacheSlots): the
-        # SAME three jitted helpers drive dense row splices, ring splices
-        # and paged zero-copy pool adoption — no layer-type or backend
-        # special cases in the tick loop
-        self._view = jax.jit(lambda layers, slot: tuple(
-            c.prefill_view(slot) if hasattr(c, "prefill_view") else c
-            for c in layers))
-        self._admit_slot = jax.jit(lambda full, one, slot: tuple(
-            f.admit(o, slot) if hasattr(f, "admit") else f
-            for f, o in zip(full, one)))
-        self._release = jax.jit(lambda layers, slot: tuple(
-            c.free_slot(slot) if hasattr(c, "free_slot") else c
-            for c in layers))
-        self._set_tables = jax.jit(lambda layers, table: tuple(
-            c.with_table(table) if isinstance(c, kv_cache.PagedCache) else c
-            for c in layers))
+        # where the engine's spans go (a scheduler hands it its own log)
+        self.spans = SPANS
+        # no layer-type or backend special cases in the tick loop
+        self._view = jax.jit(kv_view)
+        self._admit_slot = jax.jit(kv_admit_slot)
+        self._release = jax.jit(kv_release_slot)
+        self._set_tables = jax.jit(kv_set_tables)
 
         def _prefill_into(params, toks, mask, layers):
             c = {"layers": layers, "pos": jnp.zeros((), jnp.int32)}
@@ -526,15 +564,10 @@ class ServeEngine:
             return out["logits"][:, 0]
 
         self._peek = jax.jit(_peek_last, static_argnums=(3,))
-        # device half of copy-on-write: duplicate whole pages src -> dst
-        # across every paged layer pool in one dispatch
-        self._copy_pages = jax.jit(lambda layers, src, dst: tuple(
-            c.copy_pages(src, dst) if isinstance(c, kv_cache.PagedCache)
-            else c for c in layers))
+        self._copy_pages = jax.jit(kv_copy_pages)
         self._sampler = sampling.make_sampler(top_k, top_p, pad_id)
         self._truncates = top_k is not None or top_p is not None
-        self._argmax = jax.jit(
-            lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32))
+        self._argmax = jax.jit(argmax_tokens)
         self._seed_key = jax.random.PRNGKey(seed)
         self._keys = sampling.init_keys(self._seed_key, slots)
         self._temp = np.zeros((slots,), np.float32)
@@ -623,20 +656,22 @@ class ServeEngine:
             return drafts, burst, dc, snaps
 
         self._draft = jax.jit(_draft_fn, static_argnums=(3,))
-        self._verify = jax.jit(
-            lambda p, c, t: model.verify_step(p, c, tokens=t))
+
+        def verify_burst(p, c, t):
+            return model.verify_step(p, c, tokens=t)
+
+        def draft_select(layers, snaps, sel):
+            return draft_model.select_ssm_states(
+                layers, jax.tree.map(lambda x: jnp.moveaxis(x, 0, 2), snaps),
+                sel)
+
+        self._verify = jax.jit(verify_burst)
         self._verifier = sampling.make_spec_verifier(top_k, top_p, spec_mode)
         self._gverify = jax.jit(sampling.greedy_verify)
         self._t_has_ssm = any(m == "ssm" for m, _ in model.cfg.group)
         self._d_has_ssm = any(m == "ssm" for m, _ in draft_model.cfg.group)
-        self._t_select = jax.jit(
-            lambda layers, states, sel: model.select_ssm_states(
-                layers, states, sel))
-        self._d_select = jax.jit(
-            lambda layers, snaps, sel: draft_model.select_ssm_states(
-                layers,
-                jax.tree.map(lambda x: jnp.moveaxis(x, 0, 2), snaps),
-                sel))
+        self._t_select = jax.jit(model.select_ssm_states)
+        self._d_select = jax.jit(draft_select)
 
     # .. request intake ..
     def submit(self, tokens, *, max_new_tokens: int = 32,
@@ -676,7 +711,7 @@ class ServeEngine:
         request leaves no trace but its freed capacity.  Tolerates a
         slot that is mid-admission (reserved pages but no ``_active``
         entry yet — the async scheduler cancels mid-prefill)."""
-        self._active.pop(slot, None)
+        st = self._active.pop(slot, None)
         if slot not in self._free:
             self._free.append(slot)
         self.cache["pos"] = self.cache["pos"].at[slot].set(0)
@@ -696,8 +731,10 @@ class ServeEngine:
                 self._alloc.release(pid)
             self._slot_reserved.pop(slot, None)
             self._table[slot] = 0
-            self.cache["layers"] = self._release(
-                self.cache["layers"], slot)
+            with self.spans.span("kv.release",
+                                 uid=-1 if st is None else st.req.uid):
+                self.cache["layers"] = self._release(
+                    self.cache["layers"], slot)
 
     def cancel(self, uid: int) -> bool:
         """Abort ``uid`` wherever it is — queued (dropped) or active
@@ -841,9 +878,15 @@ class ServeEngine:
         self._slot_pages[slot] = pids
         self._table[slot] = 0
         self._table[slot, :need] = pids
-        self.cache["layers"] = self._set_tables(
-            self.cache["layers"], jnp.asarray(self._table))
+        self._push_tables()
         return True
+
+    def _push_tables(self) -> None:
+        """Hand the host block table to every paged layer: one dispatch
+        over the whole pool (span ``kv.set_tables``)."""
+        with self.spans.span("kv.set_tables"):
+            self.cache["layers"] = self._set_tables(
+                self.cache["layers"], host_copy(self._table))
 
     def _map_prefix(self, slot: int, req: Request) -> int | None:
         """Prefix-cached page mapping for ``req``: walk the radix cache,
@@ -878,8 +921,7 @@ class ServeEngine:
         fresh = self._take_pages(prompt_pages - len(spids))
         self._slot_pages[slot].extend(fresh)
         self._table[slot, len(spids):prompt_pages] = fresh
-        self.cache["layers"] = self._set_tables(
-            self.cache["layers"], jnp.asarray(self._table))
+        self._push_tables()
         return matched
 
     def _admit_one(self, slot: int, req: Request) -> bool:
@@ -1018,8 +1060,7 @@ class ServeEngine:
             if self._prefix is not None:
                 dirty |= self._cow(slot, p, hi)
         if dirty:
-            self.cache["layers"] = self._set_tables(
-                self.cache["layers"], jnp.asarray(self._table))
+            self._push_tables()
 
     # .. driving ..
     def step(self) -> bool:
@@ -1037,7 +1078,7 @@ class ServeEngine:
         fault_point("decode.dispatch")
         self._map_tick_pages()
         logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self._next_tok))
+            self.params, self.cache, host_copy(self._next_tok))
         self._pos += 1     # decode_step advances every slot's pos
         if self._spec:
             # spec temporarily degraded to plain decode: keep the
@@ -1047,7 +1088,7 @@ class ServeEngine:
         fault_point("sampler")
         if self._temp.any() or self._truncates:
             toks, self._keys = self._sampler(
-                logits, self._keys, jnp.asarray(self._temp))
+                logits, self._keys, host_copy(self._temp))
         else:              # all-greedy tick: skip the [B, V] Gumbel draw
             toks = self._argmax(logits)
         toks = np.asarray(toks)          # the ONE device->host transfer
@@ -1076,13 +1117,13 @@ class ServeEngine:
         self._map_tick_pages(tick_k)
 
         drafts, burst, dc, snaps = self._draft(
-            self.draft_params, self._dcache, jnp.asarray(self._next_tok),
+            self.draft_params, self._dcache, host_copy(self._next_tok),
             tick_k)
         vlogits, vcache, states = self._verify(self.params, self.cache,
                                                burst)
         if self._temp.any() or self._truncates:
             toks, n_acc, self._keys = self._verifier(
-                vlogits, drafts, self._keys, jnp.asarray(self._temp))
+                vlogits, drafts, self._keys, host_copy(self._temp))
         else:              # all-greedy tick: argmax matching, keys idle
             toks, n_acc = self._gverify(vlogits, drafts)
 
@@ -1242,8 +1283,7 @@ class ServeEngine:
         self.cache["pos"] = self.cache["pos"].at[slot].set(0)
         self.cache["start"] = self.cache["start"].at[slot].set(0)
         self._table[slot] = 0
-        self.cache["layers"] = self._set_tables(
-            self.cache["layers"], jnp.asarray(self._table))
+        self._push_tables()
         return uid
 
     def resume_parked(self, uid: int) -> int:
@@ -1262,8 +1302,7 @@ class ServeEngine:
         self._slot_shared[slot] = rec.shared
         self._slot_reserved[slot] = rec.reserved
         self._table[slot] = rec.table_row
-        self.cache["layers"] = self._set_tables(
-            self.cache["layers"], jnp.asarray(self._table))
+        self._push_tables()
         self.cache["pos"] = self.cache["pos"].at[slot].set(rec.pos)
         self.cache["start"] = self.cache["start"].at[slot].set(rec.start)
         self._pos[slot] = rec.pos

@@ -18,8 +18,12 @@ dependency beyond ``asyncio``:
   sheds, it never stalls); a malformed/oversized request answers 400.
 * ``GET /metrics`` — the scheduler's JSON metrics snapshot (TTFT /
   inter-token p50/p99, queue depth, shed counts, fault/retry/degrade
-  counters, page + prefix-cache + spec-decode counters) plus an
-  allocator ``leaks_clean`` probe.
+  counters, page + prefix-cache + spec-decode counters, and the
+  ``spans`` summary of the serving path's span log over the last 60 s)
+  plus an allocator ``leaks_clean`` probe.  The server adds two spans:
+  ``http.submit`` (request parsed to uid, scheduler-lock wait included)
+  and ``http.write`` (per streamed token: engine emission to its SSE
+  event written).
 * ``GET /healthz`` — READINESS, not just liveness: answers
   ``{"ok": bool, "state": ...}`` where state is "starting" (engine
   thread not yet spinning), "ready", "degraded" (serving, but the
@@ -59,6 +63,8 @@ import contextlib
 import json
 import threading
 import time
+
+import jax
 
 from repro.runtime.faults import InjectedFault, fault_point
 from repro.runtime.scheduler import PipelinedScheduler
@@ -267,10 +273,13 @@ class ServingServer:
         uid_box: list[int] = []
 
         def on_token(tok: int, done: bool) -> None:
-            # engine thread -> asyncio loop: the only crossing point
+            # engine thread -> asyncio loop: the only crossing point; the
+            # emission time rides along for the token's http.write span
+            emitted = time.monotonic()
+
             def _put():
                 try:
-                    q.put_nowait((tok, done))
+                    q.put_nowait((tok, done, emitted))
                 except asyncio.QueueFull:
                     # slow-client policy: the socket's flow control and
                     # our bounded queue are both full — cancel the
@@ -284,14 +293,18 @@ class ServingServer:
             loop.call_soon_threadsafe(_put)
 
         try:
-            uid = self.scheduler.submit(
-                tokens,
-                max_new_tokens=int(req.get("max_new_tokens", 32)),
-                temperature=float(req.get("temperature", 0.0)),
-                priority=int(req.get("priority", 0)),
-                deadline=(None if req.get("deadline") is None
-                          else float(req["deadline"])),
-                on_token=on_token)
+            # http.submit: parsed request to uid, the scheduler lock's
+            # wait included
+            with self.scheduler.spans.span("http.submit") as sp:
+                uid = self.scheduler.submit(
+                    tokens,
+                    max_new_tokens=int(req.get("max_new_tokens", 32)),
+                    temperature=float(req.get("temperature", 0.0)),
+                    priority=int(req.get("priority", 0)),
+                    deadline=(None if req.get("deadline") is None
+                              else float(req["deadline"])),
+                    on_token=on_token)
+                sp.uid = -1 if uid is None else uid
         except ValueError as e:            # capacity validation
             await self._respond(writer, 400, {"error": str(e)})
             return
@@ -331,7 +344,7 @@ class ServingServer:
                     getter.cancel()
                     self.scheduler.cancel(uid)
                     return "disconnect", None
-                tok, fin = getter.result()
+                tok, fin, _ = getter.result()
                 if tok is None and fin:    # quarantine failure sentinel
                     return "failed", None
                 toks.append(tok)
@@ -360,7 +373,7 @@ class ServingServer:
                     getter.cancel()
                     self.scheduler.cancel(uid)
                     return
-                tok, fin = getter.result()
+                tok, fin, emitted = getter.result()
                 if tok is None and fin:    # quarantine failure sentinel
                     err = {"error": "failed", "uid": uid,
                            "detail": self.scheduler.errors.get(uid)}
@@ -370,9 +383,14 @@ class ServingServer:
                     return
                 ev = {"index": len(toks), "token": tok}
                 toks.append(tok)
-                fault_point("server.write", uid=uid)
-                writer.write(f"data: {json.dumps(ev)}\n\n".encode())
-                await writer.drain()
+                # http.write: the engine's emission to this token's event
+                # written; the annotation marks the write on the host plane
+                with jax.profiler.TraceAnnotation("http.write"):
+                    fault_point("server.write", uid=uid)
+                    writer.write(f"data: {json.dumps(ev)}\n\n".encode())
+                    await writer.drain()
+                self.scheduler.spans.record("http.write", emitted,
+                                            time.monotonic(), uid)
                 if fin:
                     fin_ev = {"done": True, "uid": uid, "tokens": toks}
                     fault_point("server.write", uid=uid)

@@ -132,6 +132,35 @@ def test_metrics_endpoint_shape_and_leak_probe(served):
     assert "pages" in m and "prefix_cache" in m
 
 
+def test_http_spans_per_streamed_token(served):
+    """One ``http.write`` per streamed token (engine emission to its SSE
+    event written) and one ``http.submit``, each with the request's uid;
+    ``/metrics`` summarizes them with the rest of the serving path."""
+    cfg, eng, sched, *_ = served
+    spans = sched.spans
+    t_start = time.monotonic()
+    c, r = _post(served, {"tokens": _prompt(cfg, 9, seed=3),
+                          "max_new_tokens": 5})
+    events = [json.loads(ln[6:]) for ln in r.read().decode().splitlines()
+              if ln.startswith("data: ")]
+    c.close()
+    uid = events[-1]["uid"]
+    t_end = time.monotonic()
+    writes = [s for s in spans.select("http.write", t_start, t_end)
+              if s.uid == uid]
+    assert len(writes) == len(events) - 1 == 5
+    assert all(t_start <= s.t0 <= s.t1 <= t_end for s in writes)
+    submits = [s for s in spans.select("http.submit", t_start, t_end)
+               if s.uid == uid]
+    assert len(submits) == 1 and submits[0].t1 <= writes[0].t0
+    status, m = _get_json(served, "/metrics")
+    assert status == 200
+    for name in ("http.write", "http.submit", "sched.tick", "sched.sync",
+                 "req.prefill"):
+        assert m["spans"][name]["count"] >= 1, name
+        assert m["spans"][name]["p99_ms"] >= m["spans"][name]["p50_ms"]
+
+
 def test_disconnect_cancels_and_frees(served):
     """Close the client socket mid-stream on a long completion: the EOF
     watcher must cancel the request through the scheduler — slot and
