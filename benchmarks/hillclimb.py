@@ -181,8 +181,8 @@ def tune_kernels(quick: bool = False) -> dict:
                                cands, iters=iters, warmup=warmup)
         results[f"flash_attention:{sq}x{skv}x{d}"] = best
 
-    # paged decode attention: on TPU the kernel's within-page kv tile
-    # (block_kv) is what matters; on CPU the serving path is the jnp
+    # paged decode attention: on TPU the kernel's whole-page kv block
+    # (pages_per_block) is what matters; on CPU the serving path is the jnp
     # oracle, so the sweep measures its pages-per-block streaming
     # granularity (block_pages) instead — both knobs live in the one
     # "paged_attention" table entry
@@ -197,7 +197,7 @@ def tune_kernels(quick: bool = False) -> dict:
     for page, d in pg_shapes:
         b, hkv, hq = 4, 2, 8
         pps = -(-max_len // page)
-        pool_shape = (b * pps + 1, page, hkv, d)
+        pool_shape = (b * pps + 1, hkv, page, d)
         kp = jnp.asarray(rng.normal(size=pool_shape).astype(np.float32))
         vp = jnp.asarray(rng.normal(size=pool_shape).astype(np.float32))
         table = jnp.asarray(
@@ -206,7 +206,7 @@ def tune_kernels(quick: bool = False) -> dict:
         pos = jnp.full((b,), max_len - 1, jnp.int32)
         start = jnp.zeros((b,), jnp.int32)
         # sweep only the knob this host's backend responds to — the
-        # kernel ignores block_pages and the oracle ignores block_kv
+        # kernel ignores block_pages and the oracle pages_per_block
         cands = tuning.paged_attention_candidates(
             page, knob="oracle" if interpret else "kernel")
         if quick:
@@ -227,7 +227,7 @@ def tune_kernels(quick: bool = False) -> dict:
             else:
                 out = paged_attention_kernel(
                     q, kp, vp, table, pos, start, page_size=page,
-                    block_kv=cfg["block_kv"])
+                    pages_per_block=cfg["pages_per_block"])
             jax.block_until_ready(out)
 
         best = tuning.autotune("paged_attention", (page, d), bench_paged,

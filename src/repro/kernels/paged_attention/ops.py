@@ -10,9 +10,9 @@ oracle elsewhere (bit-identical to the dense backend's decode — see
 
 Block sizes come from the shared shape-keyed table in
 ``kernels.tuning`` (family ``"paged_attention"``, keyed on
-``(page_size, head_dim)``): ``block_kv`` is the kernel's within-page kv
-tile, ``block_pages`` the oracle's K-streaming granularity — both swept
-by ``hillclimb --tune-kernels``.
+``(page_size, head_dim)``): ``pages_per_block`` is the kernel's kv
+block in whole pages, ``block_pages`` the oracle's K-streaming
+granularity — both swept by ``hillclimb --tune-kernels``.
 """
 
 from __future__ import annotations
@@ -50,7 +50,7 @@ def paged_attention(q, k_pages, v_pages, block_table, pos, start=None, *,
         return paged_attention_kernel(
             q, k_pages, v_pages, block_table, pos, start,
             k_scales, v_scales, page_size=page_size, scale=scale,
-            block_kv=bk.get("block_kv"),
+            pages_per_block=int(bk["pages_per_block"]),
             interpret=(use_kernel == "interpret"))
     return paged_attention_ref(
         q, k_pages, v_pages, block_table, pos, start=start,

@@ -136,15 +136,15 @@ def _heuristic(family: str, shape) -> dict:
         sq, skv, d = (int(x) for x in shape)
         return {"block_q": _fit(sq, 128), "block_kv": _fit(skv, 128)}
     if family in PAGED_FAMILIES:
-        # (page_size, head_dim): block_kv tiles WITHIN one page (the
-        # kernel streams page by page through the block table, so the kv
-        # tile can never span pages); block_pages is the jnp oracle's
-        # K-streaming granularity — 64 pages per block, i.e. one
-        # assembled read at the 1024-token serving width (on CPU an
-        # indexed page read cannot fuse into the GEMM, so coarse blocks
-        # win; the sweep refines per host)
+        # (page_size, head_dim): pages_per_block is the kernel's kv block
+        # in whole pages (one async copy per page, every kv head at
+        # once) — enough pages for >= 128 tokens a block, 8 at page 16;
+        # block_pages is the jnp oracle's K-streaming granularity — 64
+        # pages per block, i.e. one assembled read at the 1024-token
+        # serving width (on CPU an indexed page read cannot fuse into the
+        # GEMM, so coarse blocks win; the sweep refines per host)
         page, d = (int(x) for x in shape)
-        return {"block_kv": _fit(page, 128), "block_pages": 64}
+        return {"pages_per_block": -(-128 // page), "block_pages": 64}
     raise KeyError(f"unknown kernel family: {family}")
 
 
@@ -162,7 +162,11 @@ def _valid(family: str, shape, cfg: dict) -> bool:
     if family in MATMUL_FAMILIES:
         dims = {"block_m": shape[0], "block_k": shape[1], "block_n": shape[2]}
     elif family in PAGED_FAMILIES:
-        dims = {"block_kv": shape[0]}   # must divide the page
+        # blocks of whole pages: any positive count fits; a within-page
+        # block_kv (left in a cache by the per-page kernel) means nothing
+        ppb = cfg.get("pages_per_block")
+        return ("block_kv" not in cfg and isinstance(ppb, int)
+                and not isinstance(ppb, bool) and ppb > 0)
     else:
         dims = {"block_q": shape[0], "block_kv": shape[1]}
     for k, dim in dims.items():
@@ -257,26 +261,28 @@ def paged_attention_candidates(page_size: int,
                                knob: str = "both") -> list[dict]:
     """Sweep for the paged decode attention family.
 
-    The two knobs belong to different backends — ``block_kv`` (within-
-    page kv tile, must divide the page) to the Pallas kernel,
-    ``block_pages`` (pages per score block) to the jnp oracle — so a
-    bench that exercises one backend should sweep only its own knob
+    The two knobs belong to different backends — ``pages_per_block``
+    (whole pages per kv block) to the Pallas kernel, ``block_pages``
+    (pages per score block) to the jnp oracle — so a bench that
+    exercises one backend should sweep only its own knob
     (``knob="kernel"`` / ``"oracle"``): the cross product would time
     duplicates and persist the other knob from noise.  The un-swept
     knob rides along at its heuristic default.
     """
     base = _heuristic("paged_attention", (page_size, 0))
-    bkvs = [min(b, page_size) for b in (8, 16, 32, 64, 128)
-            if page_size % min(b, page_size) == 0]
+    # kernel blocks of 1 page up to 512 tokens, the heuristic's included
+    ppbs = sorted({p for p in (1, 2, 4, 8, 16, 32) if p * page_size <= 512}
+                  | {base["pages_per_block"]})
     bps = (8, 16, 32, 64, 128)
     if knob == "kernel":
-        out = [{"block_kv": b, "block_pages": base["block_pages"]}
-               for b in bkvs]
+        out = [{"pages_per_block": p, "block_pages": base["block_pages"]}
+               for p in ppbs]
     elif knob == "oracle":
-        out = [{"block_kv": base["block_kv"], "block_pages": p}
+        out = [{"pages_per_block": base["pages_per_block"], "block_pages": p}
                for p in bps]
     else:
-        out = [{"block_kv": b, "block_pages": p} for b in bkvs for p in bps]
+        out = [{"pages_per_block": k, "block_pages": p}
+               for k in ppbs for p in bps]
     uniq = {tuple(sorted(c.items())): c for c in out}
     return list(uniq.values())
 

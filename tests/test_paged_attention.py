@@ -8,8 +8,11 @@ Pins this PR's acceptance contract:
   granularity, partial last pages, page-0 null table entries, and
   ragged per-slot lengths (hypothesis sweep over (B, page_size, ctx));
 * the Pallas kernel (interpret mode) matches the oracle at float
-  tolerance over the same grid, including within-page ``block_kv``
-  tiles, and emits exact zeros for fully-masked (all-null) slots;
+  tolerance over the same grid, for any ``pages_per_block`` and both
+  ways it brings pages in (by hand at lane-aligned head dims, by the
+  grid's pipeline otherwise), and emits exact zeros for fully-masked
+  (all-null) slots; its trip count follows each slot's live pages, not
+  the table's width;
 * the model decode path no longer touches ``PagedCache._gather``:
   a ServeEngine decode tick and ``Model.decode_step`` run end to end
   with the gather forcibly disabled, and stay bit-identical to the
@@ -26,7 +29,7 @@ from repro.configs import get_config, reduced_config
 from repro.kernels.flash_attention import ops as attn_ops
 from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.paged_attention.paged_attention import (
-    paged_attention_kernel)
+    live_extent, paged_attention_kernel)
 from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.models import kv_cache
 from repro.models.transformer import build_model
@@ -140,18 +143,20 @@ class TestOracleBitIdentity:
 class TestKernelParity:
     """Pallas kernel (interpret) vs the oracle, float tolerance."""
 
+    @pytest.mark.parametrize("hd", [16, 128])
     @pytest.mark.parametrize("quantized", [False, True])
-    @pytest.mark.parametrize("page,block_kv", [(4, None), (8, 4), (8, 8)])
-    def test_matches_oracle(self, page, block_kv, quantized):
-        b, h, hd, max_len = 2, 2, 16, 16
+    @pytest.mark.parametrize("page,ppb", [(4, None), (8, 1), (8, 2)])
+    def test_matches_oracle(self, page, ppb, quantized, hd):
+        b, h, max_len = 2, 2, 16
         pc, pos = _filled_cache(b, max_len, [13, 7], h, hd, page, quantized)
         start = jnp.asarray([0, 2], jnp.int32)
         q = jnp.asarray(RNG.normal(size=(b, 4, 1, hd)).astype(np.float32))
         want = paged_attention_ref(q, pc.k, pc.v, pc.block_table, pos, start,
                                    page_size=page, **_scales(pc))
+        kw = {} if ppb is None else {"pages_per_block": ppb}
         got = paged_attention_kernel(
             q, pc.k, pc.v, pc.block_table, pos, start, pc.k_s, pc.v_s,
-            page_size=page, block_kv=block_kv, interpret=True)
+            page_size=page, interpret=True, **kw)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-6, rtol=1e-5)
 
@@ -184,6 +189,124 @@ class TestKernelParity:
                                       use_kernel="interpret", **_scales(pc))
         np.testing.assert_allclose(np.asarray(k), np.asarray(a), atol=2e-6,
                                    rtol=1e-5)
+
+
+def _pool_case(tables, pos, start, *, page, hd, h=2, group=2,
+               quantized=False, npool=32, seed=0):
+    """Random pools of ``npool`` pages and q, with the given tables."""
+    rng = np.random.default_rng(seed)
+    shape = (npool, h, page, hd)
+    if quantized:
+        kv = [jnp.asarray(rng.integers(-127, 128, shape).astype(np.int8))
+              for _ in range(2)]
+        sc = [jnp.asarray(rng.uniform(5e-3, 2e-2, shape[:-1] + (1,))
+                          .astype(np.float32)) for _ in range(2)]
+    else:
+        kv = [jnp.asarray(rng.normal(size=shape).astype(np.float32))
+              for _ in range(2)]
+        sc = [None, None]
+    b = len(tables)
+    q = jnp.asarray(rng.normal(size=(b, h * group, 1, hd)).astype(np.float32))
+    return (q, kv[0], kv[1], jnp.asarray(np.asarray(tables, np.int32)),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(start, jnp.int32),
+            sc[0], sc[1])
+
+
+# (tables, pos, start, page, pages_per_block, extra): one slot per row
+LIVE_CASES = {
+    # 7 live pages over 4 blocks of 2, the last ending mid-block and
+    # mid-page; the second slot's pos on a page's first position
+    "multi_block_mid_page": ([[3, 9, 4, 11, 6, 20, 7, 0, 0, 0],
+                              [5, 8, 12, 13, 0, 0, 0, 0, 0, 0]],
+                             [25, 12], [0, 0], 4, 2, {}),
+    # pos on a page's last position, in both slots
+    "pos_on_page_end": ([[2, 5, 7, 0, 0, 0], [9, 0, 0, 0, 0, 0]],
+                        [11, 3], [0, 0], 4, 2, {}),
+    # left-pad start > 0: leading null pages, start mid-page
+    "start_after_null_pages": ([[0, 0, 6, 14, 15, 0], [0, 3, 4, 0, 0, 0]],
+                               [18, 10], [9, 5], 4, 2, {}),
+    # shuffled, descending ids; page 17 shared (read only) by both slots
+    "shuffled_shared_page": ([[30, 2, 17, 9, 1, 0], [17, 25, 4, 0, 0, 0]],
+                             [19, 9], [0, 0], 4, 3, {}),
+    "one_page_per_block": ([[4, 1, 7, 0], [2, 6, 0, 0]], [13, 6], [0, 0],
+                           4, 1, {}),
+    "block_wider_than_table": ([[4, 1, 7, 3], [2, 6, 0, 0]], [15, 5],
+                               [0, 1], 4, 16, {}),
+    "int8_kv_scales": ([[3, 9, 4, 11, 0, 0], [5, 8, 0, 0, 0, 0]], [13, 6],
+                       [0, 2], 4, 2, {"quantized": True}),
+    # an idle slot: all-null row and a stale pos past a used page
+    "all_null_slot": ([[3, 9, 4, 0], [0, 0, 0, 0]], [10, 14], [0, 0], 4, 2,
+                      {}),
+}
+
+
+class TestLiveBlocks:
+    """Kernel (interpret) vs oracle over the slot shapes the live-block
+    loop must get right, by hand (D 128) and by the pipeline (D 16)."""
+
+    @pytest.mark.parametrize("hd", [16, 128])
+    @pytest.mark.parametrize("case", sorted(LIVE_CASES))
+    def test_matches_oracle(self, case, hd):
+        tables, pos, start, page, ppb, extra = LIVE_CASES[case]
+        args = _pool_case(tables, pos, start, page=page, hd=hd, **extra)
+        q, k, v, table, pos, start, ks, vs = args
+        want = paged_attention_ref(q, k, v, table, pos, start,
+                                   page_size=page, k_scales=ks, v_scales=vs)
+        got = paged_attention_kernel(q, k, v, table, pos, start, ks, vs,
+                                     page_size=page, pages_per_block=ppb,
+                                     interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=1e-5)
+        idle = ~np.asarray(table).any(-1)
+        assert np.all(np.asarray(got)[idle] == 0)
+
+    @pytest.mark.parametrize("quantized", [False, True])
+    def test_mha_head_dim_64(self, quantized):
+        """MiniCPM's shape: G = 1 (MHA), head dim 64."""
+        tables, pos, start, page, _, _ = LIVE_CASES["multi_block_mid_page"]
+        args = _pool_case(tables, pos, start, page=page, hd=64, h=4,
+                          group=1, quantized=quantized)
+        q, k, v, table, pos, start, ks, vs = args
+        want = paged_attention_ref(q, k, v, table, pos, start,
+                                   page_size=page, k_scales=ks, v_scales=vs)
+        got = paged_attention_kernel(q, k, v, table, pos, start, ks, vs,
+                                     page_size=page, pages_per_block=2,
+                                     interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=1e-5)
+
+    def test_trip_count_follows_live_pages(self):
+        """The blocks a slot runs come from its live pages (``pos``,
+        ``start``, mapped entries), whatever the table's width."""
+        page, ppb = 4, 2
+        pos = jnp.asarray([25, 12, 14, 18], jnp.int32)
+        start = jnp.asarray([0, 0, 0, 9], jnp.int32)
+        for width in (10, 128):
+            table = np.zeros((4, width), np.int32)
+            table[0, :7] = np.arange(1, 8)        # pages 0-6: blocks 0-3
+            table[1, :4] = np.arange(8, 12)       # pages 0-3: blocks 0-1
+            table[2, 5] = 12                      # a page past pos: dead
+            table[3, 2:5] = np.arange(13, 16)     # start 9: pages 2-4
+            ext = np.asarray(live_extent(jnp.asarray(table), pos, start,
+                                         page_size=page,
+                                         pages_per_block=ppb))
+            np.testing.assert_array_equal(ext[0], [0, 0, 0, 2])   # first
+            np.testing.assert_array_equal(ext[1], [6, 3, -1, 4])  # last
+            np.testing.assert_array_equal(ext[2], [0, 0, 0, 1])   # 1st block
+            np.testing.assert_array_equal(ext[3], [4, 2, 0, 2])   # trips
+
+    @pytest.mark.parametrize("hd", [16, 128])
+    def test_output_independent_of_table_width(self, hd):
+        """Widening the table with null pages (a longer ``max_len``)
+        changes no bit of the result: no extra block runs."""
+        tables, pos, start, page, ppb, _ = LIVE_CASES["multi_block_mid_page"]
+        args = list(_pool_case(tables, pos, start, page=page, hd=hd))
+        narrow = paged_attention_kernel(*args, page_size=page,
+                                        pages_per_block=ppb, interpret=True)
+        args[3] = jnp.pad(args[3], ((0, 0), (0, 54)))
+        wide = paged_attention_kernel(*args, page_size=page,
+                                      pages_per_block=ppb, interpret=True)
+        np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))
 
 
 class TestDecodePathInPlace:

@@ -65,15 +65,25 @@ def test_ent_fused_matmul(one_chip, m, k, n):
         ((m, k), jnp.bfloat16), ((2, k, n), jnp.int8), ((1, n), jnp.float32))
 
 
+# (slots, max_len, q heads, kv heads, head dim): the compile width above,
+# the serving cell's 64 slots at 2048 tokens, and MiniCPM-2B's MHA
+PAGED = {"qwen_8x1024": (SLOTS, MAX_LEN, HQ, HKV, HD),
+         "qwen_64x2048": (64, 2048, HQ, HKV, HD),
+         "minicpm_mha_d64": (SLOTS, MAX_LEN, 36, 36, 64)}
+
+
+@pytest.mark.parametrize("shape", sorted(PAGED))
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
-def test_paged_attention(one_chip, kv_dtype):
+def test_paged_attention(one_chip, kv_dtype, shape):
     """In-place paged decode over the [P, Hkv, page, D] pool, bf16 KV and
-    int8 KV with per-row scale pools."""
-    pps = MAX_LEN // PAGE
-    pool = (SLOTS * pps + 1, HKV, PAGE, HD)
-    shapes = [((SLOTS, HQ, 1, HD), jnp.bfloat16), (pool, kv_dtype),
-              (pool, kv_dtype), ((SLOTS, pps), jnp.int32),
-              ((SLOTS,), jnp.int32), ((SLOTS,), jnp.int32)]
+    int8 KV with per-row scale pools: pages by hand (lane-aligned bf16)
+    and by the grid's pipeline (head dim 64, int8 scales)."""
+    slots, max_len, hq, hkv, hd = PAGED[shape]
+    pps = max_len // PAGE
+    pool = (slots * pps + 1, hkv, PAGE, hd)
+    shapes = [((slots, hq, 1, hd), jnp.bfloat16), (pool, kv_dtype),
+              (pool, kv_dtype), ((slots, pps), jnp.int32),
+              ((slots,), jnp.int32), ((slots,), jnp.int32)]
     if kv_dtype == jnp.int8:
         shapes += [(pool[:-1] + (1,), jnp.bfloat16)] * 2
 

@@ -38,6 +38,54 @@ class TestHeuristics:
             tuning.get_block_config("conv3d", (1, 2, 3))
 
 
+class TestPagedAttention:
+    """The paged decode family: whole-page kv blocks for the kernel, page
+    groups for the oracle."""
+
+    @pytest.mark.parametrize("page,ppb", [(16, 8), (8, 16), (32, 4),
+                                          (128, 1), (3, 43)])
+    def test_heuristic_128_tokens_a_block(self, page, ppb):
+        cfg = tuning.get_block_config("paged_attention", (page, 128))
+        assert cfg == {"pages_per_block": ppb, "block_pages": 64}
+        assert (ppb - 1) * page < 128 <= ppb * page
+
+    @pytest.mark.parametrize("knob", ["kernel", "oracle", "both"])
+    def test_candidates(self, knob):
+        cands = tuning.paged_attention_candidates(16, knob=knob)
+        ppbs = {c["pages_per_block"] for c in cands}
+        bps = {c["block_pages"] for c in cands}
+        assert all(set(c) == {"pages_per_block", "block_pages"}
+                   for c in cands)
+        assert len({tuple(sorted(c.items())) for c in cands}) == len(cands)
+        if knob == "oracle":
+            assert ppbs == {8} and bps == {8, 16, 32, 64, 128}
+        else:   # whole pages, one up to 512 tokens, the heuristic's among
+            assert ppbs == {1, 2, 4, 8, 16, 32}
+            assert bps == ({64} if knob == "kernel"
+                           else {8, 16, 32, 64, 128})
+
+    def test_stale_block_kv_entry_rejected(self):
+        """A within-page ``block_kv`` left in the persistent cache by the
+        per-page kernel is never handed to the block kernel."""
+        tuning.record("paged_attention", (16, 128),
+                      {"block_kv": 16, "block_pages": 32})
+        cfg = tuning.get_block_config("paged_attention", (16, 128))
+        assert cfg == {"pages_per_block": 8, "block_pages": 64}
+
+    @pytest.mark.parametrize("ppb", [0, -2, 2.5, "8", True, None])
+    def test_bad_pages_per_block_rejected(self, ppb):
+        tuning.record("paged_attention", (16, 128),
+                      {"pages_per_block": ppb, "block_pages": 32})
+        assert tuning.get_block_config(
+            "paged_attention", (16, 128))["pages_per_block"] == 8
+
+    def test_tuned_entry_served(self):
+        tuning.record("paged_attention", (16, 128),
+                      {"pages_per_block": 16, "block_pages": 32})
+        assert tuning.get_block_config("paged_attention", (16, 128)) == {
+            "pages_per_block": 16, "block_pages": 32}
+
+
 class TestTableAndCache:
     def test_record_then_lookup_same_bucket(self):
         tuning.record("ent_matmul", (256, 1024, 1024),
