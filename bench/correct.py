@@ -127,7 +127,7 @@ def check(cell, seed: int, records: list, window: tuple,
     if not sample:
         return out
     t0 = time.perf_counter()
-    mod = spec.reader("references", cfg["bench"]["reference"])
+    mod = spec.reader("references", cfg["bench"]["reference"], cell.root)
     ref = mod.Reference(cfg, seed)
     tokens, pos, seg, rows, want = pack(sample, traffic)
     kernel = ref.head()

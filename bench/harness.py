@@ -4,9 +4,11 @@
 (``run.py`` adds those).  In order:
 
 1. set-up: the program's persistent compile cache, every program kept;
-   the model from the configuration; weights drawn from ``--seed`` on the
-   device and encoded as the configuration states; engine -> scheduler ->
-   HTTP server on threads of this process; the compiled decode tick and
+   through the program adapter of the configuration's architecture
+   (``programs/<r>.py``), the model and its weights drawn from ``--seed``
+   on the device; the weights encoded as the configuration states;
+   engine -> scheduler -> HTTP server on threads of this process, checked
+   against the adapter (``system.serve``); the compiled decode tick and
    prefill chunk checked for the configuration's Pallas kernels; a
    warm-up burst over HTTP that compiles every chunk window the cell's
    prompts reach; then the ramp: the cell's own traffic until every
@@ -166,6 +168,7 @@ def ramp(sched, clients: int, t_go: float) -> None:
 class Context:
     """What the readers in ``end_to_end/`` and ``layer_metrics/`` read."""
     cell: spec.Cell
+    program: object                # the cell's adapter, programs/<r>.py
     window: tuple                  # (start, end), time.monotonic()
     records: list                  # the client's, times absolute
     setup_s: float
@@ -253,10 +256,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     compiles = CompileCounter()
     cfg, wl = cell.config, cell.workload
     bench_cfg = cfg["bench"]
-    model = system.build_model(system.model_config(cfg))
-    params = system.encode(system.make_params(model, cfg, seed),
+    prog = system.program(cell)
+    model = system.build_model(prog.model_config(cfg))
+    params = system.encode(prog.make_params(model, cfg, seed),
                            bench_cfg["weights"])
-    eng, sched, srv = system.serve(model, params, wl["engine"], record=trace)
+    eng, sched, srv = system.serve(model, params, wl["engine"], record=trace,
+                                   prefix_cache=prog.PREFIX_CACHE)
     del params
     missing = system.missing_kernels(system.compiled_kernels(eng, sched),
                                      bench_cfg["kernels"])
@@ -304,8 +309,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         if r["sent"] is not None:
             r["sent"] += t0
         records.append(r)
-    ctx = Context(cell=cell, window=window, records=records, setup_s=setup_s,
-                  device_kind=dev.device_kind)
+    ctx = Context(cell=cell, program=prog, window=window, records=records,
+                  setup_s=setup_s, device_kind=dev.device_kind)
     if trace:
         import trace_reduce
         ctx.trace = trace_reduce.reduce_trace(trace_path)
@@ -323,7 +328,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = spec.reader("layer_metrics" if trace else "end_to_end",
-                            m["name"]).read(ctx)
+                            m["name"], cell.root).read(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 

@@ -3,11 +3,12 @@
 Device time: the ``paged_attention`` events of the trace (one per layer
 per decode tick).  Least time: the K and V of every cached position each
 active slot attends (``work.paged_attention``), read at the HBM
-bandwidth, or its operations at the bf16 peak if those bound.  The
-contexts come from the benchmark's record of each decode dispatch in the
-traced span (the engine's host mirror of positions, which may trail the
-device by the pipeline's depth: the count errs low).  Per event, the
-mean over those dispatches."""
+bandwidth, or its operations at the bf16 peak if those bound; the query
+heads from the cell's program adapter, the rest from the call's key
+pages.  The contexts come from the benchmark's record of each decode
+dispatch in the traced span (the engine's host mirror of positions,
+which may trail the device by the pipeline's depth: the count errs
+low).  Per event, the mean over those dispatches."""
 
 LAYER = "kernels"
 MOVES = "itl_p95_ms"
@@ -15,7 +16,7 @@ KERNEL = "paged_attention"
 
 
 def read(ctx):
-    from work import dims, least_time, paged_attention
+    from work import least_time, paged_attention
     calls = ctx.trace.calls(KERNEL) if ctx.trace else []
     a, b = ctx.trace_span or (0, 0)
     ticks = [c for t, c in ctx.decodes if a <= t < b and c]
@@ -24,7 +25,7 @@ def read(ctx):
     # 4-d shapes: the result, the queries, then the key pages [P, Hkv, page, D]
     dtype, (_, hkv, _, hd) = [s for s in calls[0].shapes if len(s[1]) == 4][2]
     item = 1 if dtype == "s8" else 2
-    hq = dims(ctx.cfg)["hq"]
+    hq = ctx.program.query_heads(ctx.cfg)
     p = ctx.peaks
     per_call = sum(least_time(*paged_attention(c, hq, hkv, hd, item),
                               p["bf16"], p["hbm_bytes_per_s"])

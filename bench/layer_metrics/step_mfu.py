@@ -11,5 +11,6 @@ def read(ctx):
     from work import served_work, work_seconds
     if not ctx.trace or not ctx.trace.busy_s or not ctx.decodes:
         return None
-    mm, other = served_work(ctx.cfg, ctx.decodes, ctx.chunks, *ctx.trace_span)
+    mm, other = served_work(ctx.program, ctx.cfg, ctx.decodes, ctx.chunks,
+                            *ctx.trace_span)
     return 100.0 * work_seconds(ctx.cfg, ctx.peaks, mm, other) / ctx.trace.busy_s

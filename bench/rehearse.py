@@ -67,7 +67,7 @@ def rehearse(name: str, dev) -> None:
 def _rehearse(name: str, dev) -> None:
     cell = spec.load_cell(name)
     cfg, eng = cell.config, cell.workload["engine"]
-    model = system.build_model(system.model_config(cfg))
+    model = system.build_model(system.program(cell).model_config(cfg))
     on = jax.sharding.SingleDeviceSharding(dev)
     place = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on), t)

@@ -1,20 +1,38 @@
-"""Find a cell's files by name: workload, configuration, traffic mix, metrics.
+"""Find a cell's files by name: workload, configuration, traffic mix,
+program adapter, reference, metrics.
 
 Nothing here imports JAX, so the load generator can use it too.  Every
 piece of a cell is a file of its own, found by the name that
-``BENCHMARK.json`` gives it:
+``BENCHMARK.json`` or another file gives it:
 
 * ``bench/workloads/<cell>.json``   config and traffic names, clients,
   engine sizes, the check's limit;
-* ``bench/configs/<config>.json``   the model as it is run, its weight
-  format, the reference that checks it, the kernels it must compile;
+* ``bench/configs/<config>.json``   the model as it is run: its published
+  keys (``vocab_size`` among them), and a ``bench`` block with its
+  weight format, the name ``<r>`` of its architecture, the precisions of
+  the reference and of the control, the kernels it must compile;
+* ``bench/programs/<r>.py``         the program adapter of architecture
+  ``<r>`` (the one place where a configuration's own keys meet the
+  program): ``PREFIX_CACHE``, whether the engine must come up with a
+  prefix cache; ``model_config(cfg)``, the program's ``ModelConfig``;
+  ``make_params(model, cfg, seed)``, the reference's weight draws in the
+  program's layout, in one jitted call; ``query_heads(cfg)``, the query
+  heads of a paged attention call; ``token_ops(cfg, ctx, head)`` and
+  ``prefill_ops(cfg, lo, n, head)``, the logical work of a decoded and of
+  prefilled tokens (``work.py`` says how work is counted);
+* ``bench/references/<r>.py``       the plain reference of ``<r>``: a
+  ``Reference(cfg, seed)`` with ``hidden(tokens, pos, seg, mode)`` and
+  ``head()`` (``correct.py``), and the weight draws its adapter hands to
+  the program;
 * ``bench/traffic/<mix>.json``      parameters of the one generator in
   ``traffic.py``;
 * ``bench/end_to_end/<metric>.py`` and ``bench/layer_metrics/<metric>.py``
   the readers, each with a ``read(ctx)`` function.
 
-A later cell, mix or metric is a new file and an entry in
-``BENCHMARK.json``; no file here changes.
+A metric entry without a ``workloads`` list applies to every cell; only
+the kernels' rooflines list theirs, since a kernel is read only where it
+runs.  A later cell, mix, metric or architecture is a new file and an
+entry in ``BENCHMARK.json``; no file here changes.
 """
 
 from __future__ import annotations
@@ -46,6 +64,7 @@ class Cell:
     chips: int
     end_to_end: list      # BENCHMARK.json entries this cell reports
     per_layer: list
+    root: str = ROOT      # the checkout its files were found in
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -70,12 +89,13 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         traffic=_json(os.path.join(bdir, "traffic", f"{wl['traffic']}.json")),
         chips=int(entry["chips"]),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
-        per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
+        per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
+        root=root)
 
 
 def reader(kind: str, name: str, root: str = ROOT):
     """The module ``bench/<kind>/<name>.py``: a metric's reader (its
-    ``read(ctx)``) or a configuration's reference."""
+    ``read(ctx)``), or an architecture's program adapter or reference."""
     path = os.path.join(root, "bench", kind, f"{name}.py")
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)

@@ -1,13 +1,14 @@
 """The system under test, built from a configuration file, and the spans
 and counters the benchmark takes from it.
 
-This is the one module of the benchmark that imports the program
-(``repro``).  It builds the model from the configuration's published
-numbers, hands it the reference's weight draws in the program's own
-parameter layout (one jitted call on the device), encodes them to EN-T
-packed planes where the configuration says so, and stands up
-``ServeEngine`` (paged cache, prefix cache) -> ``PipelinedScheduler`` ->
-``ServingServer``, the path a deployment serves through.
+This module and the program adapters (``programs/<r>.py``, found by
+``program``) are the benchmark's only code that imports the program
+(``repro``); what is particular to an architecture lives in its
+adapter.  Here the weights the adapter made are encoded to EN-T packed
+planes where the configuration says so, and ``ServeEngine`` (paged
+cache, with a prefix cache where the adapter says so) ->
+``PipelinedScheduler`` (chunked admission) -> ``ServingServer`` is stood
+up, the path a deployment serves through.
 
 ``RecordingScheduler`` is the benchmark's wrapper for a traced run: host
 spans (``bench.tick``, ``bench.decode``, ``bench.chunk``, ``bench.sync``,
@@ -25,19 +26,17 @@ import time
 import jax
 import jax.numpy as jnp
 
-from spec import ROOT
+import spec
 
-sys.path.insert(0, os.path.join(ROOT, "src"))
+sys.path.insert(0, os.path.join(spec.ROOT, "src"))
 
-from repro.configs.base import ModelConfig, QuantConfig  # noqa: E402
+from repro.configs.base import QuantConfig  # noqa: E402
 from repro.launch.env import enable_compile_cache as _program_cache  # noqa: E402
 from repro.models.transformer import build_model  # noqa: E402
 from repro.quant.quantize import quantize_params  # noqa: E402
 from repro.runtime.scheduler import PipelinedScheduler  # noqa: E402
 from repro.runtime.serve_loop import ServeEngine  # noqa: E402
 from repro.runtime.server import ServingServer  # noqa: E402
-
-from references import dense  # noqa: E402
 
 
 def enable_compile_cache() -> str:
@@ -51,54 +50,11 @@ def enable_compile_cache() -> str:
     return path
 
 
-def model_config(cfg: dict) -> ModelConfig:
-    """The program's config from a configuration file's published keys."""
-    s = dense.sizes(cfg)
-    return ModelConfig(
-        name=cfg["name"], family="dense", num_layers=s["layers"],
-        d_model=s["d"], num_heads=s["hq"], num_kv_heads=s["hkv"],
-        head_dim=s["hd"], d_ff=s["ff"], vocab_size=s["vocab"],
-        qkv_bias=s["bias"], norm_eps=s["eps"], rope_theta=s["theta"],
-        tie_embeddings=s["tied"], param_dtype="bfloat16",
-        compute_dtype="bfloat16")
-
-
-def make_params(model, cfg: dict, seed: int):
-    """The reference's weight draws for ``seed`` in the program's layout,
-    made on the device in one jitted call."""
-    s = dense.sizes(cfg)
-
-    @jax.jit
-    def make(key):
-        w = jax.vmap(lambda i: dense.layer_weights(cfg, key, i))(
-            jnp.arange(s["layers"]))
-        emb, norm, head = dense.embedding(cfg, key)
-        rec = lambda k, b=None: ({"kernel": w[k]} if b is None
-                                 else {"kernel": w[k], "bias": w[b]})
-        bias = s["bias"]
-        mixer = {"wq": rec("wq", "bq" if bias else None),
-                 "wk": rec("wk", "bk" if bias else None),
-                 "wv": rec("wv", "bv" if bias else None),
-                 "wo": rec("wo")}
-        p = {"embed": {"embedding": emb}, "final_norm": {"scale": norm},
-             "groups": ({"mixer_norm": {"scale": w["attn_norm"]},
-                         "mixer": mixer,
-                         "ffn_norm": {"scale": w["mlp_norm"]},
-                         "ffn": {"wi_gate": rec("wg"), "wi_up": rec("wu"),
-                                 "wo": rec("wd")}},)}
-        if head is not None:
-            p["lm_head"] = {"kernel": head}
-        return p
-
-    key = dense.base_key(seed)
-    want = jax.eval_shape(model.init, key)
-    got = jax.eval_shape(make, key)
-    shapes = lambda t: [(a.shape, a.dtype) for a in jax.tree.leaves(t)]
-    if (jax.tree.structure(want) != jax.tree.structure(got)
-            or shapes(want) != shapes(got)):
-        raise ValueError("the reference's weights do not fit the program's "
-                         "parameter layout")
-    return jax.block_until_ready(make(key))
+def program(cell: spec.Cell):
+    """The program adapter of the cell's architecture:
+    ``programs/<bench.reference>.py`` of the cell's checkout."""
+    return spec.reader("programs", cell.config["bench"]["reference"],
+                       cell.root)
 
 
 def encode(params, weights: str):
@@ -149,14 +105,31 @@ class RecordingScheduler(PipelinedScheduler):
             return super()._process_entry(entry)
 
 
-def serve(model, params, engine_kw: dict, record: bool):
+def serve(model, params, engine_kw: dict, record: bool, prefix_cache: bool):
     """Engine -> scheduler -> HTTP server (not yet started): the path a
-    deployment serves through.  Returns (engine, scheduler, server)."""
+    deployment serves through.  Returns (engine, scheduler, server).
+
+    The engine picks its cache for the model; it must come up paged, with
+    a prefix cache exactly when the adapter says so (``prefix_cache``),
+    and the scheduler must split admissions into chunks: the kernel
+    check, the warm-up and the chunk records know no other prefill
+    program, so an atomic admission would be measured on the wrong one."""
     eng = ServeEngine(model, params, prefix_cache="auto", seed=0, **engine_kw)
-    if eng.cache_kind != "paged" or eng._prefix is None:
-        raise RuntimeError("the engine did not come up paged with a prefix "
-                           "cache")
+    if eng.cache_kind != "paged":
+        raise RuntimeError(f"the engine came up with a {eng.cache_kind!r} "
+                           "cache, not a paged one")
+    has = eng._prefix is not None
+    if has != prefix_cache:
+        raise RuntimeError(
+            f"the engine came up {'with' if has else 'without'} a prefix "
+            f"cache, where the program adapter says it must "
+            f"{'' if prefix_cache else 'not '}have one")
     sched = RecordingScheduler(eng) if record else PipelinedScheduler(eng)
+    if not sched._chunked:
+        raise RuntimeError(
+            "the scheduler admits each request atomically, not in prefill "
+            "chunks: the harness checks, warms up and records only the "
+            "chunked prefill program")
     srv = ServingServer(sched)
     return eng, sched, srv
 

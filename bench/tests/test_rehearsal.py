@@ -7,8 +7,6 @@ the control in the program's place, which must come out not correct at
 the cell's limit while the program comes out correct."""
 
 import functools
-import json
-import os
 import time
 
 import pytest
@@ -17,15 +15,9 @@ import faults
 import harness
 import spec
 import system
+from tiny import ENGINE, MIX, TINY, tiny_config
 
-BENCH = spec.BENCH
 SEED = 2**31 + 77                      # more than 32 signed bits hold
-TINY = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-        "num_attention_heads": 4, "vocab_size": 300}
-MIX = {"prompt": {"dist": "lognormal", "median": 20, "sigma": 0.6,
-                  "min": 4, "max": 40},
-       "output": {"dist": "lognormal", "median": 24, "sigma": 0.3,
-                  "min": 16, "max": 32}}
 # the check's limit at toy size, set between the program's widest gap and
 # the control's narrowest over three seeds on the CPU (EN-T: 0.0065-0.0138
 # against 0.28-0.40).  bf16 against its int8 control reads 0.0011-0.0088
@@ -36,49 +28,11 @@ TOY_LIMIT = {"qwen2.5-3b-ent": 0.05, "minicpm-2b-bf16": 0.03}
 
 
 def tiny_cell(config: str) -> spec.Cell:
-    cfg = json.load(open(os.path.join(BENCH, "configs", f"{config}.json")))
-    mha = cfg["num_key_value_heads"] == cfg["num_attention_heads"]
-    cfg.update(TINY, num_key_value_heads=4 if mha else 2)
-    # interpret-mode kernels compile to no tpu_custom_call
-    cfg["bench"]["kernels"] = {"decode": [], "chunk": []}
     wl = {"config": config, "traffic": "tiny", "clients": 4,
-          "engine": {"slots": 4, "max_len": 80, "page_size": 8,
-                     "prefill_chunk": 16},
-          "correct": {"logit_gap": TOY_LIMIT[config]}}
-    return spec.Cell(name="tiny", workload=wl, config=cfg, traffic=MIX,
-                     chips=1, end_to_end=spec.benchmark()["end_to_end"],
-                     per_layer=[])
-
-
-class _CopyingJnp:
-    """``jax.numpy`` whose ``asarray`` copies a numpy argument first."""
-
-    def __getattr__(self, name):
-        import jax.numpy as jnp
-        return getattr(jnp, name)
-
-    @staticmethod
-    def asarray(x, *a, **k):
-        import jax.numpy as jnp
-        import numpy as np
-        return jnp.asarray(np.array(x) if isinstance(x, np.ndarray) else x,
-                           *a, **k)
-
-
-@pytest.fixture
-def short(monkeypatch):
-    """A ramp of one second at least, no persistent compile cache on the CPU, and host
-    mirrors copied before they reach JAX.  On the CPU backend
-    ``jnp.asarray`` of an aligned numpy array aliases its memory, and the
-    scheduler and engine mutate their host mirrors (park mask and
-    positions, block table) in place while dispatched work may still read
-    them: served tokens then differ from run to run (PERF.md, Open
-    questions).  The copy keeps these tests about the harness."""
-    from repro.runtime import scheduler, serve_loop
-    monkeypatch.setattr(harness, "RAMP_MIN_S", 1.0)
-    monkeypatch.setattr(system, "enable_compile_cache", lambda: None)
-    monkeypatch.setattr(scheduler, "jnp", _CopyingJnp())
-    monkeypatch.setattr(serve_loop, "jnp", _CopyingJnp())
+          "engine": ENGINE, "correct": {"logit_gap": TOY_LIMIT[config]}}
+    return spec.Cell(name="tiny", workload=wl, config=tiny_config(config),
+                     traffic=MIX, chips=1,
+                     end_to_end=spec.benchmark()["end_to_end"], per_layer=[])
 
 
 @pytest.fixture
@@ -141,3 +95,25 @@ def test_control_reads_above_program(short, config):
     assert res["control_gap"] >= 3 * gap
     assert res["control_gap"] > res["checks"]["logit_gap"]["limit"]
     assert not res["control_correct"]
+
+
+@pytest.mark.parametrize("engine_prefix,adapter_prefix,cause", [
+    (False, True, "without a prefix cache"),
+    ("auto", False, "with a prefix cache"),
+    (False, False, "admits each request atomically"),
+])
+def test_serve_checks_the_engine_against_the_adapter(
+        monkeypatch, engine_prefix, adapter_prefix, cause):
+    """An engine whose prefix cache disagrees with the program adapter, or
+    whose scheduler admits atomically (a paged engine without a prefix
+    cache does), is refused before anything is measured."""
+    cfg = tiny_config("qwen2.5-3b-ent")
+    prog = spec.reader("programs", "dense")
+    model = system.build_model(prog.model_config(cfg))
+    params = prog.make_params(model, cfg, SEED)
+    built = system.ServeEngine
+    monkeypatch.setattr(system, "ServeEngine", lambda *a, **k: built(
+        *a, **dict(k, prefix_cache=engine_prefix)))
+    with pytest.raises(RuntimeError, match=cause):
+        system.serve(model, params, ENGINE, record=False,
+                     prefix_cache=adapter_prefix)

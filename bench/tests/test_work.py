@@ -1,5 +1,7 @@
-"""Operation and byte counts against hand counts, and the rooflines'
-ceiling by construction."""
+"""Operation and byte counts against hand counts, the dense adapter's
+counts and the readers built on them against the values the harness gave
+before the counts moved into the adapter, and the rooflines' ceiling by
+construction."""
 
 import json
 import os
@@ -7,12 +9,16 @@ import types
 
 import pytest
 
+import spec
 import work
 from trace_reduce import OpCall, Reduction
 from peaks import PEAKS, peaks
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-QWEN = json.load(open(os.path.join(BENCH, "configs", "qwen2.5-3b-ent.json")))
+BENCH = spec.BENCH
+CONFIGS = {n: json.load(open(os.path.join(BENCH, "configs", f"{n}.json")))
+           for n in ("qwen2.5-3b-ent", "minicpm-2b-bf16")}
+QWEN = CONFIGS["qwen2.5-3b-ent"]
+DENSE = spec.reader("programs", "dense")
 V5E = PEAKS["TPU v5 lite"]
 
 
@@ -39,19 +45,55 @@ def test_decode_tick_hand_count():
     # Qwen2.5-3B: per layer q 2048x2048, k and v 2048x256, o 2048x2048,
     # gate/up 2048x11008, down 11008x2048
     per_layer = 2 * 2048 * 2048 + 2 * 2048 * 256 + 3 * 2048 * 11008
-    assert work.layer_matmul_params(QWEN) == per_layer == 77_070_336
-    mm, other = work.token_ops(QWEN, 1000, head=True)
+    assert DENSE.layer_matmul_params(QWEN) == per_layer == 77_070_336
+    assert DENSE.query_heads(QWEN) == 16
+    mm, other = DENSE.token_ops(QWEN, 1000, head=True)
     assert mm == 2 * per_layer * 36
     assert other == 4 * 1000 * 16 * 128 * 36 + 2 * 2048 * 151936
     # a prefill chunk of 256 tokens at positions [256, 512)
-    mm, other = work.prefill_ops(QWEN, 256, 256, head=False)
+    mm, other = DENSE.prefill_ops(QWEN, 256, 256, head=False)
     assert mm == 2 * per_layer * 36 * 256
     assert other == 4 * (256 * 256 + 256 * 257 / 2) * 16 * 128 * 36
 
 
+# what the harness's work.py and readers gave before the counts moved into
+# the adapter, for qwen2.5-3b-ent and minicpm-2b-bf16 as filed, on a fixed
+# set of decode ticks (time, contexts of the active slots) and prefill
+# chunks (time, start, real tokens, final)
+with open(os.path.join(BENCH, "tests", "data", "work_before.json")) as f:
+    BEFORE = json.load(f)
+DECODES = [tuple(d) for d in BEFORE["decodes"]]
+CHUNKS = [tuple(c) for c in BEFORE["chunks"]]
+
+
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_dense_adapter_counts_as_before(config):
+    cfg, want = CONFIGS[config], BEFORE[config]
+    for ctx, head, *v in want["token_ops"]:
+        assert DENSE.token_ops(cfg, ctx, head) == tuple(v), (ctx, head)
+    for lo, n, head, *v in want["prefill_ops"]:
+        assert DENSE.prefill_ops(cfg, lo, n, head) == tuple(v), (lo, n, head)
+    for lo, hi, *v in want["served_work"]:
+        assert work.served_work(DENSE, cfg, DECODES, CHUNKS, lo,
+                                hi) == tuple(v), (lo, hi)
+
+
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+@pytest.mark.parametrize("metric", ["mfu", "step_mfu", "paged_attn_roofline"])
+def test_readers_through_the_adapter_read_as_before(config, metric):
+    # 72 paged attention calls of 0.1 ms on Qwen's pools, 2 s traced of 3
+    calls = [OpCall("paged_attention", "paged_attention.1", 1e-4,
+                    [("f32", (64, 2, 8, 128)), ("bf16", (64, 16, 1, 128)),
+                     ("bf16", (8192, 2, 16, 128))])] * 72
+    ctx = types.SimpleNamespace(
+        cfg=CONFIGS[config], program=DENSE, peaks=peaks("TPU v5 lite"),
+        trace=Reduction(2.0, 1.5, 1, 0.0, calls), trace_span=(0.4, 2.6),
+        decodes=DECODES, chunks=CHUNKS, window=(0.0, 3.0), window_s=3.0)
+    got = spec.reader("layer_metrics", metric).read(ctx)
+    assert got == BEFORE[config]["readers"][metric]
+
+
 def _roofline(calls):
-    mod = types.SimpleNamespace()
-    import spec
     mod = spec.reader("layer_metrics", "ent_matmul_roofline")
     ctx = types.SimpleNamespace(trace=Reduction(1.0, 1.0, 1, 0.0, calls),
                                 peaks=peaks("TPU v5 lite"))

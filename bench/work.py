@@ -13,20 +13,13 @@ implementation must do:
   to the same yardstick;
 * paged decode attention: the K and V rows of every cached position a
   slot attends, per layer, and ``4 * ctx * Hq * hd`` operations;
-* a model token: ``2 *`` the matmul parameters (the tied head counted
-  once), plus attention over its context.
+* a model token: what the configuration's program adapter
+  (``programs/<r>.py``) counts for its architecture, split into the
+  projections, at the peak of the weight type, and the rest (attention,
+  recurrences, the LM head), at the bf16 peak.
 """
 
 from __future__ import annotations
-
-
-def dims(cfg: dict) -> dict:
-    """The sizes of a dense-transformer config file (its published keys)."""
-    h = cfg["num_attention_heads"]
-    hd = cfg.get("head_dim") or cfg["hidden_size"] // h
-    return {"d": cfg["hidden_size"], "layers": cfg["num_hidden_layers"],
-            "hq": h, "hkv": cfg["num_key_value_heads"], "hd": hd,
-            "ff": cfg["intermediate_size"], "vocab": cfg["vocab_size"]}
 
 
 def least_time(ops: float, nbytes: float, op_peak: float,
@@ -56,50 +49,21 @@ def paged_attention(ctxs, hq: int, hkv: int, hd: int,
     return 4.0 * t * hq * hd, 2.0 * t * hkv * hd * kv_itemsize
 
 
-def layer_matmul_params(cfg: dict) -> int:
-    g = dims(cfg)
-    d, q, kv = g["d"], g["hq"] * g["hd"], g["hkv"] * g["hd"]
-    return d * q + 2 * d * kv + q * d + 3 * d * g["ff"]
-
-
-def token_ops(cfg: dict, ctx: int, head: bool) -> tuple[float, float]:
-    """(matmul ops, bf16-only ops) of one token at context ``ctx``: the
-    layers' projections, and attention plus (when ``head``) the LM head,
-    which run in bf16 whatever the weight format."""
-    g = dims(cfg)
-    mm = 2.0 * layer_matmul_params(cfg) * g["layers"]
-    other = 4.0 * ctx * g["hq"] * g["hd"] * g["layers"]
-    if head:
-        other += 2.0 * g["d"] * g["vocab"]
-    return mm, other
-
-
-def prefill_ops(cfg: dict, lo: int, n: int, head: bool) -> tuple[float, float]:
-    """(matmul ops, bf16 ops) of prefilling positions [lo, lo + n): each
-    attends every position up to itself."""
-    g = dims(cfg)
-    mm = 2.0 * layer_matmul_params(cfg) * g["layers"] * n
-    ctx = n * lo + n * (n + 1) / 2
-    other = 4.0 * ctx * g["hq"] * g["hd"] * g["layers"]
-    if head:
-        other += 2.0 * g["d"] * g["vocab"]
-    return mm, other
-
-
-def served_work(cfg: dict, decodes: list, chunks: list, lo: float,
+def served_work(program, cfg: dict, decodes: list, chunks: list, lo: float,
                 hi: float) -> tuple[float, float]:
     """(matmul ops, bf16 ops) of the decode ticks and prefill chunks
     dispatched in [lo, hi): every active slot's token, every real prompt
-    token (chunk padding is not work)."""
+    token (chunk padding is not work), as the configuration's program
+    adapter ``program`` counts them."""
     mm = other = 0.0
     for t, ctxs in decodes:
         if lo <= t < hi:
             for c in ctxs:
-                a, b = token_ops(cfg, c, head=True)
+                a, b = program.token_ops(cfg, c, head=True)
                 mm, other = mm + a, other + b
     for t, start, n, last in chunks:
         if lo <= t < hi:
-            a, b = prefill_ops(cfg, start, n, head=last)
+            a, b = program.prefill_ops(cfg, start, n, head=last)
             mm, other = mm + a, other + b
     return mm, other
 
