@@ -23,6 +23,7 @@ _MODULES = {
     "jamba-1.5-large": "jamba_1_5_large",
     "musicgen-medium": "musicgen_medium",
     "mamba2-370m": "mamba2_370m",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "llava-next-34b": "llava_next_34b",
 }
 

@@ -52,6 +52,15 @@ class ModelConfig:
     norm_type: str = "rmsnorm"    # rmsnorm | layernorm
     norm_eps: float = 1e-5
     rope_theta: float = 10000.0
+    rope: bool = True             # False: no position embedding (NoPE)
+    # softmax scale of attention (None: head_dim ** -0.5)
+    attn_scale: float | None = None
+    # muP scalars: embeddings times ``embedding_multiplier``, each
+    # layer branch (mixer, FFN) times ``residual_multiplier`` before its
+    # residual add, logits divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     sliding_window: int | None = None
     # serving: prefill prompts in chunks of this many tokens (None = one
     # shot up to the KV ring width, then auto-chunk at the ring width);
@@ -124,6 +133,7 @@ class ModelConfig:
             conv_dim = d_in + 2 * s.ngroups * s.state_dim
             n += d * (2 * d_in + 2 * s.ngroups * s.state_dim + nheads)  # in_proj
             n += conv_dim * s.conv_width                                # conv filt
+            n += conv_dim                                               # conv bias
             n += 3 * nheads                                             # A, dt_bias, D
             n += d_in * d                                               # out_proj
             n += d + d_in                                               # norms

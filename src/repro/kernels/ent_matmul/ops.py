@@ -82,15 +82,22 @@ def ent_quantized_matmul_fused(x, packed, scale_w, *, out_dtype=jnp.float32,
                                use_kernel: str = "auto", **block_kw):
     """Fused path from UNquantized f32/bf16 activations.
 
+    ``packed`` may hold more columns than ``scale_w`` (lane-tiled planes,
+    ``quant.quantize_weight``): the result has ``scale_w``'s width.
     The per-row quant scale is a cheap [M] amax reduction here; the int8
     X itself is produced inside the kernel (never written to HBM).  On
     non-TPU backends the jnp oracle fuses the same way under jit.
     """
     use_kernel = _resolve(use_kernel)
+    n = scale_w.shape[-1]
+    if packed.shape[-1] != n:   # lane-tiled planes: zero columns past n
+        scale_w = jnp.pad(scale_w, ((0, 0), (0, packed.shape[-1] - n)),
+                          constant_values=1)
     if use_kernel == "ref":
-        return ent_packed_fused_ref(x, packed, scale_w, out_dtype)
+        return ent_packed_fused_ref(x, packed, scale_w, out_dtype)[:, :n]
     x32 = x.astype(jnp.float32)
     _, sx = quantize_rows(x32)   # the int8 q is unused -> DCE'd under jit
     return tuning.padded_matmul(
         ent_matmul_packed_fused, "ent_matmul", x32, packed, sx, scale_w,
-        block_kw, out_dtype=out_dtype, interpret=(use_kernel == "interpret"))
+        block_kw, out_dtype=out_dtype,
+        interpret=(use_kernel == "interpret"))[:, :n]

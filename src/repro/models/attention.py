@@ -1,4 +1,5 @@
-"""GQA/MHA attention layer with RoPE, sliding window, and KV cache decode.
+"""GQA/MHA attention layer with RoPE (or none: ``cfg.rope``), sliding window,
+and KV cache decode.
 
 Training/prefill run the flash-attention op (Pallas on TPU, oracle on
 CPU).  Decode maintains a KV cache behind the first-class backend API in
@@ -55,8 +56,9 @@ def _project(cfg: ModelConfig, p, x, positions):
     q = L.dense_apply(p["wq"], x, dt).reshape(b, s, cfg.num_heads, hd)
     k = L.dense_apply(p["wk"], x, dt).reshape(b, s, cfg.num_kv_heads, hd)
     v = L.dense_apply(p["wv"], x, dt).reshape(b, s, cfg.num_kv_heads, hd)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = L.rope(q, positions, cfg.rope_theta)
+        k = L.rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -84,7 +86,8 @@ def apply(cfg: ModelConfig, p, x, positions=None, dist=None):
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     q, k, v = _project(cfg, p, x, positions)
     attn = lambda q, k, v: attn_ops.attention(
-        q, k, v, causal=True, window=cfg.sliding_window)
+        q, k, v, causal=True, window=cfg.sliding_window,
+        scale=cfg.attn_scale)
     if dist is not None:
         attn = _per_shard(attn, dist, b, cfg.num_heads, cfg.num_kv_heads)
     out = attn(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
@@ -207,7 +210,7 @@ def decode_step(cfg: ModelConfig, p, x, cache, pos, start=None):
         out = paged_ops.paged_attention(
             q.transpose(0, 2, 1, 3), view.k, view.v, view.block_table,
             pos_b, start_b, page_size=view.page_size,
-            k_scales=view.k_s, v_scales=view.v_s)
+            k_scales=view.k_s, v_scales=view.v_s, scale=cfg.attn_scale)
         return _finish(cfg, p, out), new
 
     # attention against the whole cache view through the shared masked
@@ -223,7 +226,7 @@ def decode_step(cfg: ModelConfig, p, x, cache, pos, start=None):
     out = attn_ops.masked_attention(
         q.transpose(0, 2, 1, 3), kop.transpose(0, 2, 1, 3),
         vop.transpose(0, 2, 1, 3), valid=valid[:, None, :],
-        k_scale=_scale_op(ks), v_scale=_scale_op(vs))
+        scale=cfg.attn_scale, k_scale=_scale_op(ks), v_scale=_scale_op(vs))
     return _finish(cfg, p, out), new
 
 
@@ -262,7 +265,7 @@ def verify_step(cfg: ModelConfig, p, x, cache, pos, start=None):
         kop, vop = kop.astype(dt), vop.astype(dt)
     out = attn_ops.masked_attention(
         q.transpose(0, 2, 1, 3), kop.transpose(0, 2, 1, 3),
-        vop.transpose(0, 2, 1, 3), valid=valid,
+        vop.transpose(0, 2, 1, 3), valid=valid, scale=cfg.attn_scale,
         k_scale=_scale_op(ks), v_scale=_scale_op(vs),
         chunk=kop.shape[1])
     return _finish(cfg, p, out), new
@@ -339,5 +342,6 @@ def prefill_step(cfg: ModelConfig, p, x, cache, start=None, pos0: int = 0,
     out = attn_ops.masked_attention(
         q.transpose(0, 2, 1, 3), kop.transpose(0, 2, 1, 3),
         vop.transpose(0, 2, 1, 3), start=start_local, q_offset=ctx,
-        window=new.window, k_scale=_scale_op(ks), v_scale=_scale_op(vs))
+        window=new.window, scale=cfg.attn_scale, k_scale=_scale_op(ks),
+        v_scale=_scale_op(vs))
     return _finish(cfg, p, out), (new if write else cache)

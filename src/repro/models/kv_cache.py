@@ -154,10 +154,11 @@ class CacheSlots:
     """
 
     def prefill_view(self, slot):
-        """A fresh single-slot cache for admission prefill.  Row-based
+        """A single-slot cache for admission prefill.  Row-based
         backends return zeroed state; PagedCache returns a live view of
         the shared pool restricted to ``slot``'s block-table row, so the
-        admission prefill writes pages through with no copy at all."""
+        admission prefill writes pages through with no copy at all;
+        SSMCache returns the slot's own recurrent state."""
         del slot
         return jax.tree.map(
             lambda c: jnp.zeros(c.shape[:1] + (1,) + c.shape[2:], c.dtype),
@@ -567,6 +568,13 @@ class SSMCache(CacheSlots):
 
     conv: jax.Array
     ssd: jax.Array
+
+    def prefill_view(self, slot):
+        """The slot's live state: a prefill chunk that resumes a prompt
+        carries it on.  (A prompt's first chunk starts from zero:
+        ``transformer._layer_prefill`` resets it at position 0.)"""
+        return jax.tree.map(
+            lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1), self)
 
 
 def _rows(pool, table):

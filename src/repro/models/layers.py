@@ -127,7 +127,10 @@ def embed_init(key, cfg: ModelConfig):
 
 
 def embed_apply(cfg: ModelConfig, p, tokens):
-    return p["embedding"].astype(cdtype(cfg))[tokens]
+    x = p["embedding"].astype(cdtype(cfg))[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
 
 
 def lm_head_apply(cfg: ModelConfig, p_head, p_embed, x):
@@ -139,6 +142,8 @@ def lm_head_apply(cfg: ModelConfig, p_head, p_embed, x):
     logits = jax.lax.dot_general(
         x.astype(dt), kernel.astype(dt), (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logit_softcap:
         c = cfg.logit_softcap
         logits = c * jnp.tanh(logits / c)
@@ -199,6 +204,8 @@ def fused_cross_entropy(cfg: ModelConfig, p_head, p_embed, x, labels,
     def chunk_loss(carry, inp):
         xi, li = inp
         logits = xi.astype(jnp.float32) @ kernel.astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if cfg.logit_softcap:
             c = cfg.logit_softcap
             logits = c * jnp.tanh(logits / c)

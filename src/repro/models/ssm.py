@@ -118,13 +118,21 @@ def init_cache(cfg: ModelConfig, batch: int, dtype) -> SSMCache:
         ssd=jnp.zeros((batch, nheads, s.head_dim, s.state_dim), jnp.float32))
 
 
-def decode_step(cfg: ModelConfig, p, x, cache, pos, token_mask=None):
+def decode_step(cfg: ModelConfig, p, x, cache, pos, group, token_mask=None):
     """One-token decode.  x: [B, 1, D] -> (y [B, 1, D], new cache).
 
-    ``token_mask`` ([B] bool, optional): False entries are pad tokens —
-    their conv history contribution is zeroed and the SSD state is left
-    untouched, so left-padded prompts produce the same state a padding-
-    free sequence would (the SSM is position-free).
+    ``cache.ssd`` is the model's whole stack of layer groups
+    ``[L, B, H, P, N]``, of which group ``group`` (int32 scalar) steps,
+    and comes back whole: the state is updated where it lies, never
+    sliced out and written back.  ``cache.conv`` is this layer's own.
+
+    ``token_mask`` ([B] bool, optional): False entries are not decoded
+    — their conv window and SSD state are left untouched.  Left pads
+    of a ragged prompt take this path (they precede every real token,
+    so the window they leave is the zero window a padding-free sequence
+    starts from), as do the serving slots a decode tick does not
+    advance: one parked mid-admission, whose next prefill chunk resumes
+    its state, and idle slots.
     """
     del pos  # SSM state is position-free
     s = cfg.ssm
@@ -143,6 +151,8 @@ def decode_step(cfg: ModelConfig, p, x, cache, pos, token_mask=None):
                           p["conv"].astype(jnp.float32))
     conv_out = jax.nn.silu(conv_out + p["conv_bias"].astype(jnp.float32)).astype(dtype)
     new_conv = hist[:, 1:]
+    if token_mask is not None:  # pad or frozen slot: the window stays
+        new_conv = jnp.where(token_mask[:, None, None], new_conv, cache.conv)
     xin, bmat, cmat = jnp.split(conv_out, [d_inner, d_inner + s.ngroups * s.state_dim], -1)
 
     xh = xin.reshape(b, nheads, s.head_dim).astype(jnp.float32)
@@ -152,10 +162,10 @@ def decode_step(cfg: ModelConfig, p, x, cache, pos, token_mask=None):
                               + p["dt_bias"].astype(jnp.float32))  # [B,H]
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
 
-    new_ssd, y = ssd_decode_step_ref(cache.ssd, xh, dt_soft, a, bm, cm)
-    if token_mask is not None:  # pad step: state carries through unchanged
-        new_ssd = jnp.where(token_mask[:, None, None, None], new_ssd,
-                            cache.ssd)
+    # the state update: the Pallas kernel on TPU (in place; slots that
+    # ``token_mask`` leaves out keep their state), the oracle elsewhere
+    new_ssd, y = ssd_ops.ssd_decode_step(cache.ssd, xh, dt_soft, a, bm, cm,
+                                         group, mask=token_mask)
     y = y + xh * p["d_skip"].astype(jnp.float32)[None, :, None]
     y_flat = y.reshape(b, 1, d_inner).astype(dtype)
     out = _gated_out(cfg, p, y_flat, z[:, None])
@@ -172,8 +182,9 @@ def prefill_step(cfg: ModelConfig, p, x, cache, mask=None):
     which makes the final (conv, ssd) cache bit-identical to stepping the
     prompt token by token.  ``mask`` ([B, S] bool, True = real token)
     handles left-padded ragged batches exactly like ``token_mask`` in
-    decode: pad columns contribute zeros to the conv window and leave the
-    SSD state untouched.
+    decode, and tail pads (a serving chunk padded to its window): pad
+    columns contribute zeros to the conv window and leave the SSD state
+    untouched, and the conv window returned ends at the last real token.
     """
     out, new, _ = _scan_core(cfg, p, x, cache, mask, collect=False)
     return out, new
@@ -212,7 +223,15 @@ def _scan_core(cfg: ModelConfig, p, x, cache, mask, collect: bool):
     if mask is not None:
         xbc = xbc * mask[..., None].astype(xbc.dtype)
     hist = jnp.concatenate([cache.conv.astype(xbc.dtype), xbc], 1)
-    new_conv = hist[:, slen:]                                # last W-1 columns
+    if mask is None:
+        new_conv = hist[:, slen:]                            # last W-1 columns
+    else:
+        # the W-1 columns ending at each row's last real token: left
+        # pads come before it, tail pads (a chunk padded to its window)
+        # after it, and neither may enter the window
+        end = slen - jnp.argmax(mask[:, ::-1], axis=1)
+        new_conv = jax.vmap(lambda h, e: jax.lax.dynamic_slice_in_dim(
+            h, e, s.conv_width - 1, 0))(hist, end)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
 
     # The conv contraction, split and SSD update run per timestep inside

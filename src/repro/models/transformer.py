@@ -23,9 +23,18 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention, layers as L, moe, ssm
+from repro.models.kv_cache import SSMCache
 
 
 # --- per-layer init/apply dispatch -------------------------------------------
+
+def _res(cfg: ModelConfig, x, y):
+    """Residual add of a layer branch, scaled by the muP
+    ``residual_multiplier`` where the model has one."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
 
 def _layer_init(key, cfg: ModelConfig, spec):
     mixer, ffn = spec
@@ -60,43 +69,43 @@ def _layer_apply(cfg: ModelConfig, spec, p, x, dist=None):
     if mixer == "attn":
         with jax.named_scope("attn"):
             h = L.norm_apply(cfg, p["mixer_norm"], x)
-            x = x + attention.apply(cfg, p["mixer"], h, dist=dist)
+            x = _res(cfg, x, attention.apply(cfg, p["mixer"], h, dist=dist))
     elif mixer == "ssm":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
-        x = x + ssm.apply(cfg, p["mixer"], h)
+        x = _res(cfg, x, ssm.apply(cfg, p["mixer"], h))
     if ffn == "dense":
         with jax.named_scope("mlp"):
             h = L.norm_apply(cfg, p["ffn_norm"], x)
-            x = x + L.mlp_apply(cfg, p["ffn"], h)
+            x = _res(cfg, x, L.mlp_apply(cfg, p["ffn"], h))
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, aux = _moe_apply(cfg, p["ffn"], h, dist)
-        x = x + y
+        x = _res(cfg, x, y)
     return x, aux
 
 
-def _layer_decode(cfg: ModelConfig, spec, p, x, cache, pos, dist=None,
-                  start=None, token_mask=None):
+def _layer_decode(cfg: ModelConfig, spec, p, x, cache, pos, group,
+                  dist=None, start=None, token_mask=None):
     mixer, ffn = spec
     if mixer == "attn":
         with jax.named_scope("attn"):
             h = L.norm_apply(cfg, p["mixer_norm"], x)
             y, cache = attention.decode_step(cfg, p["mixer"], h, cache, pos,
                                              start=start)
-            x = x + y
+            x = _res(cfg, x, y)
     elif mixer == "ssm":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
-        y, cache = ssm.decode_step(cfg, p["mixer"], h, cache, pos,
+        y, cache = ssm.decode_step(cfg, p["mixer"], h, cache, pos, group,
                                    token_mask=token_mask)
-        x = x + y
+        x = _res(cfg, x, y)
     if ffn == "dense":
         with jax.named_scope("mlp"):
             h = L.norm_apply(cfg, p["ffn_norm"], x)
-            x = x + L.mlp_apply(cfg, p["ffn"], h)
+            x = _res(cfg, x, L.mlp_apply(cfg, p["ffn"], h))
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, _ = _moe_apply(cfg, p["ffn"], h, dist)
-        x = x + y
+        x = _res(cfg, x, y)
     return x, cache
 
 
@@ -111,19 +120,19 @@ def _layer_verify(cfg: ModelConfig, spec, p, x, cache, pos, dist=None,
             h = L.norm_apply(cfg, p["mixer_norm"], x)
             y, cache = attention.verify_step(cfg, p["mixer"], h, cache, pos,
                                              start=start)
-            x = x + y
+            x = _res(cfg, x, y)
     elif mixer == "ssm":
         h = L.norm_apply(cfg, p["mixer_norm"], x)
         y, cache, states = ssm.verify_step(cfg, p["mixer"], h, cache)
-        x = x + y
+        x = _res(cfg, x, y)
     if ffn == "dense":
         with jax.named_scope("mlp"):
             h = L.norm_apply(cfg, p["ffn_norm"], x)
-            x = x + L.mlp_apply(cfg, p["ffn"], h)
+            x = _res(cfg, x, L.mlp_apply(cfg, p["ffn"], h))
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, _ = _moe_apply(cfg, p["ffn"], h, dist)
-        x = x + y
+        x = _res(cfg, x, y)
     return x, cache, states
 
 
@@ -141,23 +150,27 @@ def _layer_prefill(cfg: ModelConfig, spec, p, x, cache, start=None,
             y, cache = attention.prefill_step(cfg, p["mixer"], h, cache,
                                               start=start, pos0=pos0,
                                               write=write)
-            x = x + y
+            x = _res(cfg, x, y)
     elif mixer == "ssm":
         if not write:
             raise ValueError(
                 "peek prefill is attention-only: an SSM layer must write "
                 "its recurrent state through")
+        if pos0 == 0:
+            # a prompt's first chunk: the recurrence starts from zero,
+            # whatever state the slot's view still holds
+            cache = jax.tree.map(jnp.zeros_like, cache)
         h = L.norm_apply(cfg, p["mixer_norm"], x)
         y, cache = ssm.prefill_step(cfg, p["mixer"], h, cache, mask=pad_mask)
-        x = x + y
+        x = _res(cfg, x, y)
     if ffn == "dense":
         with jax.named_scope("mlp"):
             h = L.norm_apply(cfg, p["ffn_norm"], x)
-            x = x + L.mlp_apply(cfg, p["ffn"], h)
+            x = _res(cfg, x, L.mlp_apply(cfg, p["ffn"], h))
     elif ffn == "moe":
         h = L.norm_apply(cfg, p["ffn_norm"], x)
         y, _ = _moe_apply(cfg, p["ffn"], h, dist)
-        x = x + y
+        x = _res(cfg, x, y)
     return x, cache
 
 
@@ -232,7 +245,9 @@ class Model:
         from each sequence's first real token.  ``start`` ([B] int32)
         overrides the pad count derived from ``pad_mask`` — required for
         chunks past the first, where the mask slice no longer sees the
-        row's left pads.
+        row's left pads.  With ``start`` given, ``pad_mask`` may also
+        mark TAIL pads (a serving chunk padded to its window): SSM
+        layers freeze on them, and no real query attends them.
 
         ``peek=True`` (write_cache path, attention-only) runs the chunk
         read-only: logits are exactly what a writing prefill would
@@ -412,22 +427,30 @@ class Model:
         # The cache rides the scan CARRY with per-group dynamic slice
         # updates — never through xs/ys, which would stage a full copy of
         # the multi-GB cache every token (§Perf: 2x cache traffic saved;
-        # XLA aliases the carry update in place under donation).
+        # XLA aliases the carry update in place under donation).  An SSM
+        # layer's SSD state is not sliced at all: the layer updates its
+        # group's rows of the stack where they lie (a slice and its
+        # write-back would hold a group's state twice).
         def group_body(carry, scan_in):
             x, full_cache = carry
             gparams, g = scan_in
-            gcache = jax.tree.map(
-                lambda c: jax.lax.dynamic_index_in_dim(c, g, 0, keepdims=False),
-                full_cache)
+            index = lambda c: jax.lax.dynamic_index_in_dim(c, g, 0,
+                                                           keepdims=False)
+            put = lambda full, new: jax.lax.dynamic_update_index_in_dim(
+                full, new.astype(full.dtype), g, 0)
+            gcache = tuple(
+                SSMCache(conv=index(c.conv), ssd=c.ssd)
+                if isinstance(c, SSMCache) else jax.tree.map(index, c)
+                for c in full_cache)
             new_caches = []
             for i, spec in enumerate(cfg.group):
                 x, c = _layer_decode(cfg, spec, gparams[i], x, gcache[i], pos,
-                                     self.dist, start, token_mask)
+                                     g, self.dist, start, token_mask)
                 new_caches.append(c)
-            full_cache = jax.tree.map(
-                lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                    full, new.astype(full.dtype), g, 0),
-                full_cache, tuple(new_caches))
+            full_cache = tuple(
+                SSMCache(conv=put(f.conv, n.conv), ssd=n.ssd)
+                if isinstance(f, SSMCache) else jax.tree.map(put, f, n)
+                for f, n in zip(full_cache, new_caches))
             return (x, full_cache), None
 
         (x, new_layer_caches), _ = jax.lax.scan(

@@ -3,12 +3,16 @@
 ``quantize_params`` walks a float param tree and replaces every matmul
 kernel (minus skip patterns) with a quantized record:
 
-    {"planes_packed": int8 [2, I, O], "scale": f32 [1, O]}   # EN-T
+    {"planes_packed": int8 [2, I, O'], "scale": f32 [1, O]}  # EN-T
     {"q": int8 [I, O], "scale": f32 [1, O]}                  # plain int8
 
 (per-out-channel scales).  An EN-T record carries the planes only: they
 decode exactly to the int8 weight (``dequantize_weight``), so a second
-int8 copy would be bytes nothing reads.
+int8 copy would be bytes nothing reads.  Its planes are lane-tiled: a
+width O past one 128-lane tile that is no multiple of it (Mamba-2's
+in_proj, 8,512 columns) gets zero columns up to the next multiple O',
+so every block of the kernel's column tiling is lane-aligned; the
+scale keeps the true width, which the matmul's result is cut back to.
 
 The packed planes are produced ONCE here by the hoisted edge encoder
 (repro.core.multiplier.ent_packed_planes) — the paper's computation reuse
@@ -44,6 +48,8 @@ from repro.kernels.int8_matmul import ops as int8_ops
 __all__ = ["quantize_weight", "quantize_params", "quantize_acts",
            "qdense_apply", "dequantize_weight"]
 
+LANES = 128     # the TPU's lane tile: EN-T plane columns are padded to it
+
 
 def quantize_weight(w, *, ent_encode: bool = True, per_channel: bool = True):
     """Symmetric int8 quantization of a [I, O] kernel: packed EN-T planes
@@ -56,6 +62,9 @@ def quantize_weight(w, *, ent_encode: bool = True, per_channel: bool = True):
     scale = jnp.maximum(amax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(w32 / scale), -127, 127).astype(jnp.int8)
     if ent_encode:
+        o = q.shape[-1]
+        if o > LANES and o % LANES:
+            q = jnp.pad(q, ((0, 0), (0, -o % LANES)))
         return {"planes_packed": ent_packed_planes(q),
                 "scale": scale.astype(jnp.float32)}
     return {"q": q, "scale": scale.astype(jnp.float32)}
@@ -65,6 +74,7 @@ def dequantize_weight(rec):
     """Float weight of a record (stacked records keep their lead axes)."""
     if "planes_packed" in rec:
         w = packed_to_weight(jnp.moveaxis(rec["planes_packed"], -3, 0))
+        w = w[..., :rec["scale"].shape[-1]]       # lane-tile padding
     elif "planes" in rec:
         w = planes_to_weight(jnp.moveaxis(rec["planes"], -3, 0))
     else:

@@ -100,7 +100,8 @@ class LatencyHistogram:
 class Span(NamedTuple):
     """One finished span: times are ``time.monotonic`` seconds; ``parent``
     is the id of the innermost span open on the recording thread (-1:
-    none); ``uid`` the request (-1: none); ``n`` an integer payload."""
+    none); ``uid`` the request (-1: none); ``n`` an integer payload;
+    ``carry`` 1 on a prefill chunk that resumes a recurrent state."""
     name: str
     t0: float
     t1: float
@@ -108,22 +109,25 @@ class Span(NamedTuple):
     parent: int
     uid: int
     n: int
+    carry: int = 0
 
 
 SUMMARY_S = 60.0    # the span summary of /metrics covers this many seconds
 _SPAN_DTYPE = np.dtype([("name", np.int32), ("t0", np.float64),
                         ("t1", np.float64), ("id", np.int64),
                         ("parent", np.int64), ("uid", np.int64),
-                        ("n", np.int64)])
+                        ("n", np.int64), ("carry", np.int8)])
 
 
 class _Open:
     """A span in progress (``SpanLog.span``).  ``uid`` and ``n`` may be
     set inside the block, once they are known."""
-    __slots__ = ("log", "name", "uid", "n", "id", "parent", "t0", "_ann")
+    __slots__ = ("log", "name", "uid", "n", "carry", "id", "parent", "t0",
+                 "_ann")
 
-    def __init__(self, log, name, uid, n):
+    def __init__(self, log, name, uid, n, carry):
         self.log, self.name, self.uid, self.n = log, name, uid, n
+        self.carry = carry
 
     def __enter__(self):
         stack = self.log._stack()
@@ -140,16 +144,16 @@ class _Open:
         self._ann.__exit__(*exc)
         self.log._stack().pop()
         self.log._write(self.name, self.t0, t1, self.id, self.parent,
-                        self.uid, self.n)
+                        self.uid, self.n, self.carry)
 
 
 class _Off:
     """The span of a log that records nothing."""
-    __slots__ = ("uid", "n")
+    __slots__ = ("uid", "n", "carry")
     id = -1
 
     def __init__(self):
-        self.uid, self.n = -1, 0
+        self.uid, self.n, self.carry = -1, 0, 0
 
     def __enter__(self):
         return self
@@ -185,21 +189,21 @@ class SpanLog:
         self._lock = threading.Lock()
         self._local = threading.local()
 
-    def span(self, name: str, uid: int = -1, n: int = 0):
+    def span(self, name: str, uid: int = -1, n: int = 0, carry: int = 0):
         """Context manager timing its block as span ``name``; also a
         ``jax.profiler.TraceAnnotation`` of that name."""
         if not self.capacity:
             return _Off()
-        return _Open(self, name, uid, n)
+        return _Open(self, name, uid, n, carry)
 
     def record(self, name: str, t0: float, t1: float, uid: int = -1,
-               n: int = 0) -> None:
+               n: int = 0, carry: int = 0) -> None:
         """Add a finished span that started at ``t0`` (monotonic seconds)."""
         if not self.capacity:
             return
         stack = self._stack()
         self._write(name, t0, t1, next(self._ids),
-                    stack[-1] if stack else -1, uid, n)
+                    stack[-1] if stack else -1, uid, n, carry)
 
     def _stack(self) -> list:
         try:
@@ -208,7 +212,7 @@ class SpanLog:
             self._local.stack = []
             return self._local.stack
 
-    def _write(self, name, t0, t1, sid, parent, uid, n) -> None:
+    def _write(self, name, t0, t1, sid, parent, uid, n, carry) -> None:
         with self._lock:
             code = self._codes.get(name)
             if code is None:
@@ -217,7 +221,7 @@ class SpanLog:
             at = self._written % self.capacity
             if self._written >= self.capacity:
                 self.lost_t1 = max(self.lost_t1, float(self._buf[at]["t1"]))
-            self._buf[at] = (code, t0, t1, sid, parent, uid, n)
+            self._buf[at] = (code, t0, t1, sid, parent, uid, n, carry)
             self._written += 1
 
     def _rows(self, name, lo, hi) -> np.ndarray:

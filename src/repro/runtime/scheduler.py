@@ -25,8 +25,8 @@ structural changes, and emits bit-identical streams while doing it:
    never in prefix-shared bytes.  A processed entry whose slot was
    freed or re-admitted in the meantime is discarded by uid guard.
 
-2. **Split prefill/decode streams.**  On the default paged +
-   prefix-cache engine, admission no longer runs as one fused
+2. **Split prefill/decode streams.**  On a paged engine (with a prefix
+   cache or without), admission no longer runs as one fused
    dispatch + host sync.  The prompt's unshared suffix prefills in
    grid-aligned chunks (windows of ``prefill_chunk`` tokens at
    absolute multiples of it, so jit compile keys stay bounded:
@@ -37,7 +37,9 @@ structural changes, and emits bit-identical streams while doing it:
    parked: the decode tick's pos-override pins its device position to
    the chunk frontier, whose write lands in the slot's own
    exclusively-held (or unmapped -> null) page and is overwritten by
-   the next chunk before any pos-bounded read can see it.  The first
+   the next chunk before any pos-bounded read can see it, and the tick
+   leaves its recurrent state (SSM layers) untouched, as it does every
+   idle slot's: the next chunk resumes that state.  The first
    sampled token stays ON DEVICE (fed to the next tick via the token
    feed) and its host emission is deferred to entry processing, so
    admission never syncs.  Chunk boundaries don't change prefill
@@ -45,8 +47,8 @@ structural changes, and emits bit-identical streams while doing it:
    the oracle softmax sees the same columns), so streams match the
    synchronous engine bit for bit — asserted in tests.
 
-   Dense/ring backends, engines without a prefix cache, and
-   speculative engines admit atomically through ``_admit_one``
+   Dense/ring backends and speculative engines admit atomically
+   through ``_admit_one``
    (speculative engines tick through ``engine.step()`` — the verify
    burst IS the decode stream); they still get admission control and
    metrics, and non-spec engines still get pipelined decode.
@@ -244,9 +246,12 @@ class PipelinedScheduler:
         # every reservation by the pipeline depth (the speculative
         # engine's spec_k slack, generalized) BEFORE any admission
         engine._reserve_slack = self.depth
-        self._chunked = (engine.cache_kind == "paged"
-                         and engine._prefix is not None
-                         and not engine._spec)
+        self._chunked = engine.cache_kind == "paged" and not engine._spec
+        engine._exact_prompts = self._chunked
+        # recurrent (SSM) layers: a chunk after a prompt's first resumes
+        # the slot's state, and the final chunk's tail pad must not
+        # advance it
+        self._ssm = bool(engine.model.cfg.ssm_positions)
         self._lock = threading.RLock()
         self._heap: list[_QEntry] = []
         self._seq = 0
@@ -288,16 +293,22 @@ class PipelinedScheduler:
         # XLA updates the pool in place and a decode tick or prefill
         # chunk costs only its compute.
 
+        # ``pmask`` marks the slots the tick does not decode (parked
+        # mid-admission, or idle): their position is pinned to ``ppos``
+        # and their recurrent state, if any, left as it is
+
         def _greedy_tick(params, cache, toks, pmask, ppos):
             cache = dict(cache)
             cache["pos"] = jnp.where(pmask, ppos, cache["pos"])
-            logits, cache = model.decode_step(params, cache, tokens=toks)
+            logits, cache = model.decode_step(params, cache, tokens=toks,
+                                              token_mask=~pmask)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
         def _sampled_tick(params, cache, toks, pmask, ppos, temps, keys):
             cache = dict(cache)
             cache["pos"] = jnp.where(pmask, ppos, cache["pos"])
-            logits, cache = model.decode_step(params, cache, tokens=toks)
+            logits, cache = model.decode_step(params, cache, tokens=toks,
+                                              token_mask=~pmask)
             tok, keys = sampler(logits, keys, temps)
             return tok, keys, cache
 
@@ -329,9 +340,14 @@ class PipelinedScheduler:
             view = tuple(c.prefill_view(slot) if hasattr(c, "prefill_view")
                          else c for c in layers)
             c = {"layers": view, "pos": jnp.full((), pos0, jnp.int32)}
+            tail = {}
+            if self._ssm:   # the tail pad must not advance the recurrence
+                tail = {"pad_mask": (jnp.arange(toks.shape[1])[None, :]
+                                     <= last_index),
+                        "start": jnp.zeros((1,), jnp.int32)}
             out = model.apply(params, tokens=toks, cache=c,
                               write_cache=True, last_only=True, pos0=pos0,
-                              last_index=last_index)
+                              last_index=last_index, **tail)
             merged = tuple(f.admit(o, slot) if hasattr(f, "admit") else f
                            for f, o in zip(layers, out["cache"]["layers"]))
             return out["logits"][:, 0], merged
@@ -467,7 +483,9 @@ class PipelinedScheduler:
             # past the host mirror: make that whole span write-safe first
             eng._map_tick_pages(len(self._pipeline))
             toks = self._feed()
-            pmask = host_copy(self._park_mask)
+            idle = np.ones((eng.slots,), bool)
+            idle[list(eng._active)] = False
+            pmask = host_copy(self._park_mask | idle)
             ppos = host_copy(self._park_pos)
             fault_point("sampler")
             if eng._temp.any() or eng._truncates:
@@ -583,18 +601,24 @@ class PipelinedScheduler:
 
     def _start_admission(self, slot: int, req: Request) -> bool:
         """Begin split-stream admission: map the prompt's pages (shared
-        prefix + fresh suffix) and either finish immediately (fully
-        cached prompt — one peek dispatch) or park the slot and hand the
-        suffix to the chunk stream."""
+        prefix + fresh suffix, or without a prefix cache the whole
+        prompt's) and either finish immediately (fully cached prompt —
+        one peek dispatch) or park the slot and hand the rest to the
+        chunk stream."""
         eng = self.engine
+        n = len(req.tokens)
         with self.spans.span("sched.admit", uid=req.uid):
             fault_point("prefill.dispatch", uid=req.uid)
-            pos0 = eng._map_prefix(slot, req)
+            if eng._prefix is not None:
+                pos0 = eng._map_prefix(slot, req)
+            else:
+                pos0 = 0 if eng._alloc_pages(
+                    slot, -(-n // eng.page_size),
+                    eng._pages_needed(n, req.max_new_tokens)) else None
             if pos0 is None:
                 return False
             eng._free.remove(slot)
             self.metrics.admitted(req.uid)
-            n = len(req.tokens)
             if pos0 >= n:
                 # fully cached: a read-only peek of the last token's logits
                 view = eng._view(eng.cache["layers"], slot)
@@ -620,7 +644,8 @@ class PipelinedScheduler:
         cap = eng._pps * eng.page_size
         hi = min((lo // self.chunk + 1) * self.chunk, cap)
         real_hi = min(hi, pf.n)
-        with self.spans.span("sched.chunk", uid=req.uid, n=real_hi - lo):
+        with self.spans.span("sched.chunk", uid=req.uid, n=real_hi - lo,
+                             carry=int(self._ssm and lo > 0)):
             fault_point("prefill.dispatch", uid=req.uid)
             toks = jnp.asarray(
                 [req.tokens[lo:real_hi]
@@ -644,9 +669,10 @@ class PipelinedScheduler:
         sync: the token stays on device (fed to the next decode tick),
         and its emission rides the pipeline as an admit record."""
         eng, n = self.engine, len(req.tokens)
-        eng._prefix.insert(
-            req.tokens,
-            [int(p) for p in eng._table[slot, :n // eng.page_size]])
+        if eng._prefix is not None:
+            eng._prefix.insert(
+                req.tokens,
+                [int(p) for p in eng._table[slot, :n // eng.page_size]])
         eng.cache["pos"] = eng.cache["pos"].at[slot].set(n)
         eng.cache["start"] = eng.cache["start"].at[slot].set(0)
         eng._pos[slot] = n
@@ -860,8 +886,9 @@ class PipelinedScheduler:
         Shrinking below current occupancy PARKS the youngest active
         streams (pages stay resident; ``resume_parked`` continues them
         bit-identically when capacity returns).  Engines that cannot
-        park (row backends, speculative) shrink by attrition: no new
-        admissions until occupancy fits."""
+        park (row backends, speculative, recurrent layers: the state
+        stays in the slot) shrink by attrition: no new admissions until
+        occupancy fits."""
         with self._lock:
             self._capacity = max(0, min(n, self.engine.slots))
             self._enforce_capacity()
@@ -873,7 +900,7 @@ class PipelinedScheduler:
 
     def _enforce_capacity(self) -> None:
         eng = self.engine
-        if eng.cache_kind != "paged" or eng._spec:
+        if eng.cache_kind != "paged" or eng._spec or self._ssm:
             return                         # attrition-only shrink
         # process in-flight ticks first so parking sees settled state
         while self._pipeline:
@@ -930,6 +957,8 @@ class PipelinedScheduler:
                      "failed": len(self.errors)}
             if eng.page_stats is not None:
                 extra["pages"] = eng.page_stats
+            if self._ssm:
+                extra["ssm_state_bytes"] = eng.ssm_state_bytes
             if eng.prefix_stats is not None:
                 extra["prefix_cache"] = eng.prefix_stats
             return self.metrics.snapshot(

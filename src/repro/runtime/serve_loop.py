@@ -587,6 +587,9 @@ class ServeEngine:
         # positions past prompt+max_new before the host learns a request
         # finished (PipelinedScheduler sets this to its pipeline depth)
         self._reserve_slack = 0
+        # a scheduler that admits in unpadded chunks sets this: prompts
+        # then take their exact length, not a left-padded bucket
+        self._exact_prompts = False
 
         # .. speculative decoding ..
         self._spec = draft_model is not None
@@ -679,14 +682,16 @@ class ServeEngine:
         tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
         if not tokens:
             raise ValueError("cannot serve an empty prompt")
-        # prefix-cached admission keeps absolute positions (tail pads
-        # never occupy one), so the exact length is the capacity bound
-        sp = (len(tokens) if self._prefix is not None
-              else _bucket(len(tokens), self.prefill_bucket))
+        # prefix-cached and chunked admission keep absolute positions
+        # (tail pads never occupy one), so the exact length is the
+        # capacity bound
+        exact = self._prefix is not None or self._exact_prompts
+        sp = len(tokens) if exact else _bucket(len(tokens),
+                                               self.prefill_bucket)
         if sp + max_new_tokens > self.max_len:
             raise ValueError(
                 f"prompt ({len(tokens)} tokens"
-                f"{'' if self._prefix is not None else ', bucketed'}) + "
+                f"{'' if exact else ', bucketed'}) + "
                 f"max_new_tokens ({max_new_tokens}) exceeds engine max_len "
                 f"{self.max_len}")
         if self.cache_kind == "paged":
@@ -733,8 +738,7 @@ class ServeEngine:
             self._table[slot] = 0
             with self.spans.span("kv.release",
                                  uid=-1 if st is None else st.req.uid):
-                self.cache["layers"] = self._release(
-                    self.cache["layers"], slot)
+                self._on_pools(self._release, slot)
 
     def cancel(self, uid: int) -> bool:
         """Abort ``uid`` wherever it is — queued (dropped) or active
@@ -795,6 +799,14 @@ class ServeEngine:
         if self._prefix is not None:
             stats["cached"] = self._prefix.resident
         return stats
+
+    @property
+    def ssm_state_bytes(self) -> int:
+        """Bytes of recurrent (SSM) state the engine holds on the device:
+        every slot's conv window and SSD state, whether in use or not."""
+        return sum(a.nbytes for c in self.cache["layers"]
+                   if isinstance(c, kv_cache.SSMCache)
+                   for a in jax.tree.leaves(c))
 
     @property
     def prefix_stats(self) -> dict | None:
@@ -861,9 +873,8 @@ class ServeEngine:
             self._alloc.release(pid)
         if src:
             self._cow_copies += len(src)
-            self.cache["layers"] = self._copy_pages(
-                self.cache["layers"],
-                jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+            self._on_pools(self._copy_pages, jnp.asarray(src, jnp.int32),
+                           jnp.asarray(dst, jnp.int32))
         return bool(src)
 
     def _alloc_pages(self, slot: int, need: int, reserve: int) -> bool:
@@ -885,8 +896,18 @@ class ServeEngine:
         """Hand the host block table to every paged layer: one dispatch
         over the whole pool (span ``kv.set_tables``)."""
         with self.spans.span("kv.set_tables"):
-            self.cache["layers"] = self._set_tables(
-                self.cache["layers"], host_copy(self._table))
+            self._on_pools(self._set_tables, host_copy(self._table))
+
+    def _on_pools(self, step, *args) -> None:
+        """Run a jitted page-table step (``_set_tables``, ``_release``,
+        ``_copy_pages``) over the paged layers alone.  These jits do not
+        donate, so a layer they passed through unchanged, such as an SSM
+        layer's recurrent state, would come back as a copy of itself."""
+        layers = self.cache["layers"]
+        new = step(tuple(c if isinstance(c, kv_cache.PagedCache) else None
+                         for c in layers), *args)
+        self.cache["layers"] = tuple(c if n is None else n
+                                     for c, n in zip(layers, new))
 
     def _map_prefix(self, slot: int, req: Request) -> int | None:
         """Prefix-cached page mapping for ``req``: walk the radix cache,
@@ -1261,6 +1282,11 @@ class ServeEngine:
             raise ValueError(
                 "parking requires the paged backend: dense/ring slots "
                 "reuse the parked stream's KV rows for the next occupant")
+        if self.model.cfg.ssm_positions:
+            raise ValueError(
+                "parking models with SSM layers is unsupported: their "
+                "recurrent state lives in the slot, which the next "
+                "occupant reuses")
         if self._spec:
             raise ValueError(
                 "parking speculative engines is unsupported: the "

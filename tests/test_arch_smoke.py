@@ -84,7 +84,8 @@ class TestDecodePrefillConsistency:
     """Step-by-step decode must match the full forward (per family)."""
 
     @pytest.mark.parametrize("arch", ["qwen2.5-3b", "mamba2-370m",
-                                      "mixtral-8x7b", "jamba-1.5-large"])
+                                      "mixtral-8x7b", "jamba-1.5-large",
+                                      "granite-4.0-h-micro"])
     def test_consistency(self, models, arch):
         cfg, m, params = _get(models, arch)
         key = jax.random.PRNGKey(7)
@@ -118,6 +119,7 @@ class TestParamCounts:
             ("musicgen-medium", 1.2e9, 1.8e9),
             ("llava-next-34b", 32e9, 36e9),
             ("jamba-1.5-large", 360e9, 420e9),
+            ("granite-4.0-h-micro", 3.1e9, 3.3e9),   # ~3.19B
         ],
     )
     def test_param_count_range(self, arch, lo, hi):
@@ -138,4 +140,5 @@ class TestParamCounts:
         """DESIGN.md §Arch-applicability: who runs long_500k."""
         runnable = {a for a in ARCH_IDS if get_config(a).is_sub_quadratic}
         assert runnable == {"mixtral-8x7b", "starcoder2-15b",
-                            "jamba-1.5-large", "mamba2-370m"}
+                            "jamba-1.5-large", "mamba2-370m",
+                            "granite-4.0-h-micro"}

@@ -18,7 +18,8 @@ from repro.kernels.flash_attention.ref import (attention_ref,
                                                masked_attention_ref)
 from repro.kernels.int8_matmul.int8_matmul import int8_matmul
 from repro.kernels.int8_matmul.ref import int8_matmul_ref
-from repro.kernels.ssd_scan.ref import ssd_scan_ref
+from repro.kernels.ssd_scan import ops as ssd_ops
+from repro.kernels.ssd_scan.ref import ssd_decode_step_ref, ssd_scan_ref
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 
 RNG = np.random.default_rng(42)
@@ -288,3 +289,56 @@ class TestSSDScan:
         x, dt, a, bm, cm = self._data(1, 128, 2, 16, 1, 16)
         got = ssd_scan(jnp.zeros_like(x), dt, a, bm, cm, chunk=64, interpret=True)
         assert np.all(np.asarray(got) == 0)
+
+
+class TestSSDDecodeStep:
+    """The Mamba-2 decode state update (Pallas, interpret mode) against
+    ``ssd_decode_step_ref``: stepped slots to f32 rounding (the C
+    contraction sums in another order), masked slots and the other layer
+    groups of the stack bit for bit."""
+
+    def _data(self, b, h, p, n, g, layers=1):
+        f = lambda *s: jnp.asarray(RNG.normal(size=s).astype(np.float32))
+        state = f(layers, b, h, p, n)
+        dt = jnp.asarray(RNG.uniform(0.001, 0.1, size=(b, h)).astype(np.float32))
+        a = jnp.asarray(-RNG.uniform(1.0, 16.0, size=(h,)).astype(np.float32))
+        return state, f(b, h, p), dt, a, f(b, g, n), f(b, g, n)
+
+    @pytest.mark.parametrize("b,h,p,n,g", [
+        (1, 8, 16, 16, 1), (3, 8, 16, 16, 1), (4, 4, 64, 128, 1),
+        (2, 16, 64, 128, 2), (5, 64, 16, 32, 4)])
+    def test_matches_ref(self, b, h, p, n, g):
+        state, x, dt, a, bm, cm = self._data(b, h, p, n, g)
+        want_h, want_y = ssd_decode_step_ref(state[0], x, dt, a, bm, cm)
+        got_h, got_y = ssd_ops.ssd_decode_step(state, x, dt, a, bm, cm,
+                                               jnp.int32(0),
+                                               use_kernel="interpret")
+        np.testing.assert_allclose(np.asarray(got_h)[0], np.asarray(want_h),
+                                   atol=1e-5, rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                                   atol=1e-4, rtol=1e-5)
+
+    @pytest.mark.parametrize("b,h,p,n,g", [(4, 8, 16, 16, 1),
+                                           (3, 16, 64, 128, 2)])
+    def test_masked_slots_and_other_groups_unchanged(self, b, h, p, n, g):
+        state, x, dt, a, bm, cm = self._data(b, h, p, n, g, layers=3)
+        mask = jnp.arange(b) % 2 == 0
+        keep = np.asarray(state)
+        got, y = ssd_ops.ssd_decode_step(state, x, dt, a, bm, cm,
+                                         jnp.int32(1), mask=mask,
+                                         use_kernel="interpret")
+        got, y, m = np.asarray(got), np.asarray(y), np.asarray(mask)
+        np.testing.assert_array_equal(got[[0, 2]], keep[[0, 2]])
+        np.testing.assert_array_equal(got[1][~m], keep[1][~m])
+        assert not np.any(y[~m])
+        want_h, want_y = ssd_decode_step_ref(keep[1], x, dt, a, bm, cm)
+        np.testing.assert_allclose(got[1][m], np.asarray(want_h)[m],
+                                   atol=1e-5, rtol=1e-6)
+        np.testing.assert_allclose(y[m], np.asarray(want_y)[m],
+                                   atol=1e-4, rtol=1e-5)
+        # the oracle path keeps the same slots and groups
+        ref_h, _ = ssd_ops.ssd_decode_step(state, x, dt, a, bm, cm,
+                                           jnp.int32(1), mask=mask,
+                                           use_kernel="ref")
+        np.testing.assert_array_equal(np.asarray(ref_h)[[0, 2]], keep[[0, 2]])
+        np.testing.assert_array_equal(np.asarray(ref_h)[1][~m], keep[1][~m])

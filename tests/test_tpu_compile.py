@@ -53,6 +53,7 @@ def _compile(fn, one_chip, kernel, *shapes):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
     assert kernel in text, f"kernel {kernel!r} not in the program"
+    return text
 
 
 @pytest.mark.parametrize("m", [3, 8, 512])
@@ -109,3 +110,101 @@ def test_flash_masked(one_chip, sq, skv):
              ((1, HQ, sq, HD), jnp.bfloat16),
              ((1, HKV, skv, HD), jnp.bfloat16),
              ((1, HKV, skv, HD), jnp.bfloat16), ((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("m", [8, 64])
+def test_ent_fused_matmul_lane_padded_columns(one_chip, m):
+    """A projection whose width is no multiple of the 128-lane tile
+    (Granite-4.0-H's Mamba-2 in_proj, 2048 x 8512) runs the fused kernel
+    on planes padded to 8576 columns at encode, and the result keeps
+    the true width."""
+    from repro.quant.quantize import qdense_apply, quantize_weight
+    rec = jax.eval_shape(quantize_weight,
+                         jax.ShapeDtypeStruct((D, 8512), jnp.bfloat16))
+    assert rec["planes_packed"].shape == (2, D, 8576)
+    assert rec["scale"].shape == (1, 8512)
+
+    def fn(x, planes, scale):
+        y = qdense_apply({"planes_packed": planes, "scale": scale}, x,
+                         use_kernel="pallas")
+        assert y.shape == (m, 8512)
+        return y
+
+    _compile(fn, one_chip, "ent_matmul_packed_fused",
+             ((m, D), jnp.bfloat16), ((2, D, 8576), jnp.int8),
+             ((1, 8512), jnp.float32))
+
+
+# (slots, heads, head dim, state, groups): Granite-4.0-H-Micro's mixers
+# at the serving cell's 64 slots, and Jamba's 8 groups of 32 heads
+SSD = {"granite_64": (64, 64, 64, 128, 1), "jamba_8": (8, 256, 64, 128, 8)}
+
+
+@pytest.mark.parametrize("shape", sorted(SSD))
+def test_ssd_decode_step(one_chip, shape):
+    """Mamba-2 decode state update: one group of the stacked
+    [L, B, H, P, N] fp32 state updated in place, the group index and a
+    slot mask in scalar memory."""
+    from repro.kernels.ssd_scan import ops as ssd_ops
+    b, h, p, n, g = SSD[shape]
+
+    def fn(state, x, dt, a, bm, cm, mask, group):
+        return ssd_ops.ssd_decode_step(state, x, dt, a, bm, cm, group,
+                                       mask=mask, use_kernel="pallas")
+
+    text = _compile(fn, one_chip, "ssd_decode_step",
+                    ((4, b, h, p, n), jnp.float32), ((b, h, p), jnp.float32),
+                    ((b, h), jnp.float32), ((h,), jnp.float32),
+                    ((b, g, n), jnp.float32), ((b, g, n), jnp.float32),
+                    ((b,), jnp.bool_), ((), jnp.int32))
+    # the new state is the state operand's buffer (output 1 <- operand 7)
+    assert "output_to_operand_aliasing={{1}: (7, {})}" in text
+
+
+def test_granite_decode_tick_holds_no_copy_of_the_ssd_state(one_chip,
+                                                             monkeypatch):
+    """Granite-4.0-H's decode tick at the published widths (two layer
+    groups, 8 slots, EN-T planes, donated cache): each mixer's state is
+    stepped where it lies in the stacked [G, B, H, P, N] fp32 state by
+    ``ssd_decode_step``; no copy, slice or write-back of a group's
+    state, or of the stack, is left in the compiled program."""
+    import dataclasses
+    import re
+
+    from repro.configs.base import QuantConfig
+    from repro.configs.granite_4_0_h_micro import CONFIG
+    from repro.models.transformer import build_model
+    from repro.quant.quantize import quantize_params
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = build_model(dataclasses.replace(CONFIG, num_layers=20))
+    slots, max_len, page = SLOTS, MAX_LEN, PAGE
+    params = jax.eval_shape(lambda k: quantize_params(
+        model.init(k), QuantConfig(enabled=True)), jax.random.PRNGKey(0))
+
+    def cache_fn():
+        c = model.init_cache(slots, max_len, kind="paged", page_size=page,
+                             pages=slots * max_len // page, mapped=False)
+        c["pos"] = jnp.zeros((slots,), jnp.int32)
+        c["start"] = jnp.zeros((slots,), jnp.int32)
+        return c
+
+    def tick(params, cache, toks, pmask):
+        logits, cache = model.decode_step(params, cache, tokens=toks,
+                                          token_mask=~pmask)
+        return jnp.argmax(logits, axis=-1), cache
+
+    place = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), t)
+    args = place((params, jax.eval_shape(cache_fn),
+                  jax.ShapeDtypeStruct((slots,), jnp.int32),
+                  jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    text = jax.jit(tick, donate_argnums=(1,)).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') > 0
+    assert "ssd_decode_step" in text
+    state = f"{slots},64,64,128]"          # [.., B, H, P, N] f32
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= \(?f32\[(\d+,)?" + re.escape(state)
+                          + r"\S* (copy|copy-start|fusion|dynamic-slice|"
+                          r"dynamic-update-slice)\(", line)]
+    assert not moved, moved
