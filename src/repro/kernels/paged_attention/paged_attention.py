@@ -43,8 +43,13 @@ Two ways of bringing the pages in, one per pool shape:
   block operand the pipeline fetches (:func:`_schedule`).
 
 Layout: pools are ``[P, Hkv, page, D]``, so one page is one contiguous
-slab.  GQA: the q heads of kv head ``h`` are the contiguous block
-``h*G .. (h+1)*G - 1``; q arrives as ``[B, Hkv, G, D]``.
+slab.  A decode tick hands the model's stack of layer pools folded flat,
+``[G*P, Hkv, page, D]``, with ``base = g * P``, the first page of layer
+``g``: table ids are layer-local, and every page is read at ``base +
+id`` (the DMA path's source, the pipelined path's fetch list), so no
+layer's pool is sliced out of the stack.  GQA: the q heads of kv head
+``h`` are the contiguous block ``h*G .. (h+1)*G - 1``; q arrives as
+``[B, Hkv, G, D]``.
 """
 
 from __future__ import annotations
@@ -140,9 +145,10 @@ def _finish(o_ref, l_ref, acc_ref):
     o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _dma_kernel(table_ref, pos_ref, start_ref, ext_ref, q_ref, k_hbm, v_hbm,
-                o_ref, k_buf, v_buf, sem, cur_ref, m_ref, l_ref, acc_ref, *,
-                page: int, ppb: int, npages: int, nslots: int, scale: float):
+def _dma_kernel(table_ref, pos_ref, start_ref, ext_ref, base_ref, q_ref,
+                k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur_ref, m_ref, l_ref,
+                acc_ref, *, page: int, ppb: int, npages: int, nslots: int,
+                scale: float):
     b = pl.program_id(0)
     tokens = ppb * page
 
@@ -156,7 +162,8 @@ def _dma_kernel(table_ref, pos_ref, start_ref, ext_ref, q_ref, k_hbm, v_hbm,
             def _():
                 for src, dst in ((k_hbm, k_buf), (v_hbm, v_buf)):
                     c = pltpu.make_async_copy(
-                        src.at[pid], dst.at[buf, :, rows], sem.at[buf])
+                        src.at[base_ref[0] + pid], dst.at[buf, :, rows],
+                        sem.at[buf])
                     c.wait() if wait else c.start()
 
     _init_state(m_ref, l_ref, acc_ref)
@@ -285,6 +292,7 @@ def paged_attention_kernel(
     start: jax.Array,        # [B] int32: first attendable position
     k_scales: jax.Array | None = None,   # [P, Hkv, page, 1] per-row scales
     v_scales: jax.Array | None = None,
+    base: jax.Array | None = None,       # int32 scalar: the layer's first page
     *,
     page_size: int,
     scale: float | None = None,
@@ -307,6 +315,8 @@ def paged_attention_kernel(
     start = start.astype(jnp.int32)
     ext = live_extent(table, pos, start, page_size=page_size,
                       pages_per_block=ppb)
+    base = jnp.reshape(jnp.asarray(0 if base is None else base, jnp.int32),
+                       (1,))
     qg = q.reshape(b, hkv, group, d)
     state = [pltpu.VMEM((hkv, group, 1), jnp.float32),
              pltpu.VMEM((hkv, group, 1), jnp.float32),
@@ -321,7 +331,7 @@ def paged_attention_kernel(
             _dma_kernel, page=page_size, ppb=ppb, npages=npages, nslots=b,
             scale=scale)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(b,),
             in_specs=[q_spec, hbm, hbm],
             out_specs=q_spec,
@@ -332,10 +342,11 @@ def paged_attention_kernel(
                 pltpu.SMEM((1,), jnp.int32),    # buffer of the next block
             ] + state,
         )
-        prefetch = (table, pos, start, ext)
+        prefetch = (table, pos, start, ext, base)
         operands = (qg, k_pages, v_pages)
     else:
         steps, fetch, total = _schedule(ext, table, pages_per_block=ppb)
+        fetch = fetch + base
 
         def q_idx(i, table_ref, pos_ref, start_ref, ext_ref, steps_ref,
                   fetch_ref):

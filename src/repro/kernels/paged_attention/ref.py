@@ -148,3 +148,12 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, pos, start=None,
                      preferred_element_type=jnp.float32)
     out = acc / jnp.maximum(l, 1e-30)
     return out.reshape(b, hq, sq, d)
+
+
+def paged_write_ref(pools, rows, page, off, layer):
+    """jnp twin of the in-place row write: row ``b`` of each ``rows``
+    entry ([B, H, X]) lands in row ``off[b]`` of page ``page[b]`` of
+    layer ``layer`` of its stacked ``[G, P, H, page, X]`` pool — the
+    scatter ``PagedCache.write_token`` makes on one layer's pool."""
+    return tuple(c.at[layer, page, :, off].set(r.astype(c.dtype))
+                 for c, r in zip(pools, rows))

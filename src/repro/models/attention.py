@@ -210,7 +210,8 @@ def decode_step(cfg: ModelConfig, p, x, cache, pos, start=None):
         out = paged_ops.paged_attention(
             q.transpose(0, 2, 1, 3), view.k, view.v, view.block_table,
             pos_b, start_b, page_size=view.page_size,
-            k_scales=view.k_s, v_scales=view.v_s, scale=cfg.attn_scale)
+            k_scales=view.k_s, v_scales=view.v_s, scale=cfg.attn_scale,
+            layer=view.layer)
         return _finish(cfg, p, out), new
 
     # attention against the whole cache view through the shared masked

@@ -59,6 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import ops as paged_ops
+
 DEFAULT_PAGE_SIZE = 16
 
 
@@ -75,6 +77,7 @@ class PagedView(NamedTuple):
     v_s: jax.Array | None
     block_table: jax.Array        # [B, pages_per_slot] int32
     page_size: int
+    layer: jax.Array | None = None   # pools stacked [G, ..]: the layer read
 
 
 def _register(meta=()):
@@ -224,21 +227,26 @@ class KVCache(CacheSlots):
         """Logical kv view length (slots per sequence)."""
         return self.k.shape[-3]
 
+    def _stored(self, k, v):
+        """k/v rows in storage form: (k, v, k_s, v_s), quantized when
+        the cache is int8 (scales None otherwise)."""
+        if self.k_s is not None:
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            return kq, vq, ks, vs
+        return k.astype(self.k.dtype), v.astype(self.v.dtype), None, None
+
     def _write(self, upd, k, v):
         """Apply placement ``upd(leaf, new) -> leaf`` to every leaf,
         quantizing k/v en route when the cache is int8.  Returns the new
         cache plus the freshly written values in storage form (the
         operand views prefill contracts against, bit-identical to
         reading the written slots back without the round-trip)."""
-        if self.k_s is not None:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            new = replace(self, k=upd(self.k, kq), v=upd(self.v, vq),
-                          k_s=upd(self.k_s, ks), v_s=upd(self.v_s, vs))
-            return new, kq, vq, ks, vs
-        kc, vc = k.astype(self.k.dtype), v.astype(self.v.dtype)
-        return (replace(self, k=upd(self.k, kc), v=upd(self.v, vc)),
-                kc, vc, None, None)
+        kc, vc, ks, vs = self._stored(k, v)
+        new = replace(self, k=upd(self.k, kc), v=upd(self.v, vc))
+        if ks is not None:
+            new = replace(new, k_s=upd(self.k_s, ks), v_s=upd(self.v_s, vs))
+        return new, kc, vc, ks, vs
 
     # .. speculative decoding ..
     #
@@ -421,6 +429,15 @@ class PagedCache(KVCache):
     ever materializing it); chunked-prefill ``context`` still gathers
     the pages covering the prefix.  Writes scatter into the owning
     page.  Slot admission and release move page *indices*, never rows.
+
+    ``layer`` set (an int32 scalar) marks a decode tick's view of one
+    layer of the model's stack: the pools stay the whole stack
+    ``[G, P, H, page, *]`` and only the block table is the layer's.
+    ``write_token`` then writes the layer's rows where they lie
+    (``paged_ops.paged_write``, in place on TPU) and ``token_view``
+    hands the stack and the layer to the kernel, so the tick never
+    slices a pool out of the stack or writes one back
+    (``Model.decode_step``).  Only those two are defined on such a view.
     """
 
     k: jax.Array
@@ -429,6 +446,7 @@ class PagedCache(KVCache):
     k_s: jax.Array | None = None
     v_s: jax.Array | None = None
     page_size: int = DEFAULT_PAGE_SIZE
+    layer: jax.Array | None = None
 
     @property
     def width(self) -> int:
@@ -444,9 +462,16 @@ class PagedCache(KVCache):
         pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
         pp, off = pos_b // self.page_size, pos_b % self.page_size
         pid = jnp.take_along_axis(self.block_table, pp[:, None], axis=1)[:, 0]
-        new, *_ = self._write(
-            lambda c, n: c.at[pid, :, off].set(n[:, 0]), k, v)
-        return new
+        if self.layer is None:
+            new, *_ = self._write(
+                lambda c, n: c.at[pid, :, off].set(n[:, 0]), k, v)
+            return new
+        names = ("k", "v", "k_s", "v_s")
+        rows = [r for r in self._stored(k, v) if r is not None]
+        pools = paged_ops.paged_write(
+            [getattr(self, n) for n in names[:len(rows)]],
+            [r[:, 0] for r in rows], pid, off, self.layer)
+        return replace(self, **dict(zip(names, pools)))
 
     def write_tokens(self, k, v, pos):
         b, s = k.shape[:2]
@@ -483,7 +508,7 @@ class PagedCache(KVCache):
         ``valid``."""
         del pos_b, start_b   # masked in-kernel
         return PagedView(self.k, self.v, self.k_s, self.v_s,
-                         self.block_table, self.page_size)
+                         self.block_table, self.page_size, self.layer)
 
     def gather_view(self, pos_b, start_b):
         """The pre-kernel read: pages gathered into a position-ordered

@@ -17,13 +17,14 @@ assignment); labels still index the token vocab.
 from __future__ import annotations
 
 import functools
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention, layers as L, moe, ssm
-from repro.models.kv_cache import SSMCache
+from repro.models.kv_cache import PagedCache, SSMCache
 
 
 # --- per-layer init/apply dispatch -------------------------------------------
@@ -424,13 +425,17 @@ class Model:
         else:
             x = embeds.astype(L.cdtype(cfg))
 
-        # The cache rides the scan CARRY with per-group dynamic slice
-        # updates — never through xs/ys, which would stage a full copy of
-        # the multi-GB cache every token (§Perf: 2x cache traffic saved;
-        # XLA aliases the carry update in place under donation).  An SSM
-        # layer's SSD state is not sliced at all: the layer updates its
-        # group's rows of the stack where they lie (a slice and its
-        # write-back would hold a group's state twice).
+        # The cache rides the scan CARRY — never through xs/ys, which
+        # would stage a full copy of the multi-GB cache every token.  The
+        # large leaves are not sliced out of the carry at all: a slice
+        # and its write-back each cost a pass over the leaf (XLA does
+        # not alias them in place, donation or not).  An SSM layer steps
+        # its group's SSD state where it lies in the stack, and a paged
+        # layer writes and reads its pages where they lie in the stacked
+        # pools (``PagedCache.layer``); both kernels alias their stack
+        # in place.  The other leaves (conv windows, block tables, the
+        # dense and ring backends' rows) keep per-group slices and
+        # updates.
         def group_body(carry, scan_in):
             x, full_cache = carry
             gparams, g = scan_in
@@ -438,19 +443,31 @@ class Model:
                                                            keepdims=False)
             put = lambda full, new: jax.lax.dynamic_update_index_in_dim(
                 full, new.astype(full.dtype), g, 0)
-            gcache = tuple(
-                SSMCache(conv=index(c.conv), ssd=c.ssd)
-                if isinstance(c, SSMCache) else jax.tree.map(index, c)
-                for c in full_cache)
+
+            def layer_view(c):
+                if isinstance(c, SSMCache):
+                    return SSMCache(conv=index(c.conv), ssd=c.ssd)
+                if isinstance(c, PagedCache):
+                    return replace(c, block_table=index(c.block_table),
+                                   layer=g)
+                return jax.tree.map(index, c)
+
+            def write_back(full, new):
+                if isinstance(full, SSMCache):
+                    return SSMCache(conv=put(full.conv, new.conv), ssd=new.ssd)
+                if isinstance(full, PagedCache):   # a tick maps no page
+                    return replace(full, k=new.k, v=new.v, k_s=new.k_s,
+                                   v_s=new.v_s)
+                return jax.tree.map(put, full, new)
+
+            gcache = tuple(layer_view(c) for c in full_cache)
             new_caches = []
             for i, spec in enumerate(cfg.group):
                 x, c = _layer_decode(cfg, spec, gparams[i], x, gcache[i], pos,
                                      g, self.dist, start, token_mask)
                 new_caches.append(c)
-            full_cache = tuple(
-                SSMCache(conv=put(f.conv, n.conv), ssd=n.ssd)
-                if isinstance(f, SSMCache) else jax.tree.map(put, f, n)
-                for f, n in zip(full_cache, new_caches))
+            full_cache = tuple(write_back(f, n)
+                               for f, n in zip(full_cache, new_caches))
             return (x, full_cache), None
 
         (x, new_layer_caches), _ = jax.lax.scan(

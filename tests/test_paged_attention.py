@@ -19,6 +19,8 @@ Pins this PR's acceptance contract:
   dense backend.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -377,3 +379,70 @@ class TestDecodePathInPlace:
             ld, cd = model.decode_step(params, cd, tokens=toks[:, t])
             lp, cp = model.decode_step(params, cp, tokens=toks[:, t])
             np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
+
+
+# (head dim, int8 KV, layer): the DMA path, the pipelined path, both with
+# int8 scale pools, and the first layer of the stack
+STACKED_CASES = {"hd128_layer1": (128, False, 1), "hd64_layer2": (64, False, 2),
+                 "int8_hd128_layer2": (128, True, 2),
+                 "int8_hd64_layer1": (64, True, 1),
+                 "hd128_layer0": (128, False, 0)}
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+@pytest.mark.parametrize("case", sorted(STACKED_CASES))
+def test_stacked_pool_write_and_read_match_the_layer_path(case, mode):
+    """A decode tick's write and read of layer g in the stacked
+    [G, P, H, page, D] pools == the same write and read on layer g's
+    pool sliced out of the stack, bit for bit: the oracle
+    (``PagedCache.write_token`` with ``layer`` set) and the Pallas
+    kernels (interpret).  Slot 0 sits on the null page; every other
+    layer of the stack is left as it was."""
+    hd, quantized, layer = STACKED_CASES[case]
+    g, b, h, hq, page, pps = 3, 4, 2, 4, 8, 4
+    rng = np.random.default_rng(11)
+    npages = 1 + b * pps
+    shape = (g, npages, h, page, hd)
+    if quantized:
+        pools = [jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                 for _ in range(2)]
+        pools += [jnp.asarray(rng.uniform(0.01, 0.1, shape[:-1] + (1,)),
+                              jnp.bfloat16) for _ in range(2)]
+    else:
+        pools = [jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                 for _ in range(2)]
+    table = np.zeros((b, pps), np.int32)
+    table[1:] = 1 + rng.permutation(b * pps)[:(b - 1) * pps].reshape(b - 1,
+                                                                      pps)
+    table = jnp.asarray(table)
+    pos = jnp.asarray([5, 3, 17, 30], jnp.int32)
+    start = jnp.asarray([0, 0, 2, 0], jnp.int32)
+    k, v = (jnp.asarray(rng.normal(size=(b, 1, h, hd)), jnp.bfloat16)
+            for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(b, hq, 1, hd)), jnp.bfloat16)
+    names = ("k", "v", "k_s", "v_s")
+    kw = dict(zip(names, pools), block_table=table, page_size=page)
+
+    one = kv_cache.PagedCache(**{**kw, **{n: c[layer] for n, c in
+                                          zip(names, pools)}})
+    want = one.write_token(k, v, pos, per_seq=True)
+    stacked = kv_cache.PagedCache(**kw, layer=jnp.int32(layer))
+    if mode == "ref":
+        got = stacked.write_token(k, v, pos, per_seq=True)
+    else:
+        pid = jnp.take_along_axis(table, (pos // page)[:, None], 1)[:, 0]
+        rows = [r[:, 0] for r in one._stored(k, v) if r is not None]
+        new = paged_ops.paged_write(pools, rows, pid, pos % page,
+                                    jnp.int32(layer), use_kernel="interpret")
+        got = dataclasses.replace(stacked, **dict(zip(names, new)))
+    for n, c in zip(names, pools):
+        new = np.asarray(getattr(got, n))
+        np.testing.assert_array_equal(new[layer], np.asarray(getattr(want, n)))
+        rest = [i for i in range(g) if i != layer]
+        np.testing.assert_array_equal(new[rest], np.asarray(c)[rest])
+
+    read = lambda c, **kw: paged_ops.paged_attention(
+        q, c.k, c.v, table, pos, start, page_size=page, k_scales=c.k_s,
+        v_scales=c.v_s, use_kernel=mode, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(read(got, layer=jnp.int32(layer))), np.asarray(read(want)))

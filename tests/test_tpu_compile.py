@@ -13,6 +13,8 @@ process that describes it holds the TPU library until it exits, so only
 the worker that runs this file may touch it.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -97,6 +99,31 @@ def test_paged_attention(one_chip, kv_dtype, shape):
     _compile(fn, one_chip, "paged_attention", *shapes)
 
 
+@pytest.mark.parametrize("shape", sorted(PAGED))
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
+def test_paged_write(one_chip, kv_dtype, shape):
+    """A decode tick's K/V row write into layer 1 of a two-layer stack of
+    pools, bf16 KV and int8 KV with its scale pools, each new pool the
+    buffer of the pool it updates."""
+    slots, max_len, _, hkv, hd = PAGED[shape]
+    pool = (2, slots * (max_len // PAGE) + 1, hkv, PAGE, hd)
+    pools = [(pool, kv_dtype)] * 2
+    rows = [((slots, hkv, hd), kv_dtype)] * 2
+    if kv_dtype == jnp.int8:
+        pools += [(pool[:-1] + (1,), jnp.bfloat16)] * 2
+        rows += [((slots, hkv, 1), jnp.bfloat16)] * 2
+    n = len(pools)
+
+    def fn(*args):
+        return paged_ops.paged_write(args[:n], args[n:2 * n], *args[2 * n:],
+                                     use_kernel="pallas")
+
+    text = _compile(fn, one_chip, "paged_write", *pools, *rows,
+                    ((slots,), jnp.int32), ((slots,), jnp.int32),
+                    ((), jnp.int32))
+    assert "output_to_operand_aliasing" in text
+
+
 @pytest.mark.parametrize("sq,skv", [(512, 512), (256, 512)])
 def test_flash_masked(one_chip, sq, skv):
     """Ragged masked flash prefill: a whole 512-token prompt, and a
@@ -161,30 +188,25 @@ def test_ssd_decode_step(one_chip, shape):
     assert "output_to_operand_aliasing={{1}: (7, {})}" in text
 
 
-def test_granite_decode_tick_holds_no_copy_of_the_ssd_state(one_chip,
-                                                             monkeypatch):
-    """Granite-4.0-H's decode tick at the published widths (two layer
-    groups, 8 slots, EN-T planes, donated cache): each mixer's state is
-    stepped where it lies in the stacked [G, B, H, P, N] fp32 state by
-    ``ssd_decode_step``; no copy, slice or write-back of a group's
-    state, or of the stack, is left in the compiled program."""
+def _tick_hlo(one_chip, monkeypatch, config, layers, slots, max_len, pages):
+    """The compiled decode tick of ``config`` cut to ``layers`` layers, at
+    its published widths with EN-T planes, ``slots`` slots of ``max_len``
+    tokens on a paged pool of ``pages`` pages and a donated cache, as the
+    scheduler serves it."""
     import dataclasses
-    import re
 
     from repro.configs.base import QuantConfig
-    from repro.configs.granite_4_0_h_micro import CONFIG
     from repro.models.transformer import build_model
     from repro.quant.quantize import quantize_params
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model = build_model(dataclasses.replace(CONFIG, num_layers=20))
-    slots, max_len, page = SLOTS, MAX_LEN, PAGE
+    model = build_model(dataclasses.replace(config, num_layers=layers))
     params = jax.eval_shape(lambda k: quantize_params(
         model.init(k), QuantConfig(enabled=True)), jax.random.PRNGKey(0))
 
     def cache_fn():
-        c = model.init_cache(slots, max_len, kind="paged", page_size=page,
-                             pages=slots * max_len // page, mapped=False)
+        c = model.init_cache(slots, max_len, kind="paged", page_size=PAGE,
+                             pages=pages, mapped=False)
         c["pos"] = jnp.zeros((slots,), jnp.int32)
         c["start"] = jnp.zeros((slots,), jnp.int32)
         return c
@@ -201,10 +223,79 @@ def test_granite_decode_tick_holds_no_copy_of_the_ssd_state(one_chip,
                   jax.ShapeDtypeStruct((slots,), jnp.bool_)))
     text = jax.jit(tick, donate_argnums=(1,)).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') > 0
+    return text
+
+
+# ops that make a new array of their result's shape
+MOVERS = r"(copy|copy-start|fusion|dynamic-slice|dynamic-update-slice|scatter)"
+
+
+def _moved(text, dtype, shape):
+    """[(computation, line)] of each op of ``text`` whose result (or a
+    tuple element of it) is a ``dtype`` array of ``shape``, a trailing
+    shape, given with a leading group axis or without one."""
+    pat = re.compile(r"= \(?" + dtype + r"\[(\d+,)?" + re.escape(shape)
+                     + r"\S* " + MOVERS + r"\(")
+    out, comp = [], None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+        if pat.search(line):
+            out.append((comp, line.strip()[:160]))
+    return out
+
+
+def _kv_write_aliases_its_pools(text, layers_with_attn):
+    """Each paged layer's K/V rows are written by ``paged_write``, whose
+    outputs are its pool operands' buffers."""
+    writes = [line for line in text.splitlines()
+              if re.match(r"\s*%paged_write\S* = .* custom-call\(", line)]
+    assert len(writes) == layers_with_attn, writes
+    for line in writes:
+        assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" \
+            in line, line[:400]
+
+
+def test_qwen_decode_tick_holds_no_copy_of_the_kv_pool(one_chip, monkeypatch):
+    """Qwen2.5-3B's decode tick at the published widths (four layers, the
+    serving cell's 64 slots on 8,192 pages, EN-T planes, donated cache):
+    each layer writes its token's K/V rows where they lie in the stacked
+    [G, P, Hkv, page, D] pools (``paged_write``, aliased) and
+    ``paged_attention`` reads them there; no op makes a copy, slice,
+    write-back or scatter of a layer's pool or of the stack."""
+    from repro.configs.qwen2_5_3b import CONFIG
+    text = _tick_hlo(one_chip, monkeypatch, CONFIG, 4, 64, 2048, 8192)
+    assert "paged_attention" in text
+    moved = _moved(text, "bf16", f"{8192 + 1},{HKV},{PAGE},{HD}]")
+    moved += _moved(text, "bf16", f"{4 * (8192 + 1)},{HKV},{PAGE},{HD}]")
+    assert not moved, moved
+    _kv_write_aliases_its_pools(text, 1)
+
+
+def test_granite_decode_tick_holds_no_copy_of_the_ssd_state(one_chip,
+                                                             monkeypatch):
+    """Granite-4.0-H's decode tick at the published widths (two layer
+    groups, 8 slots, EN-T planes, donated cache): each mixer's state is
+    stepped where it lies in the stacked [G, B, H, P, N] fp32 state by
+    ``ssd_decode_step``; no copy, slice or write-back of a group's
+    state, or of the stack, is left in the compiled program.  The
+    attention layers' K/V pools ([G, P, 8, 16, 64]) are written and read
+    where they lie too: the loop over the groups holds no op that makes
+    a pool.  What is left is the program's edge: the device's default
+    layout for a bf16 array with a 64-wide minor dim puts the page axis
+    on the lanes, where the kernels take pages row-major, so each stacked
+    pool is laid out once on the way in and once on the way out."""
+    from repro.configs.granite_4_0_h_micro import CONFIG
+    slots, pages = SLOTS, SLOTS * MAX_LEN // PAGE
+    text = _tick_hlo(one_chip, monkeypatch, CONFIG, 20, slots, MAX_LEN,
+                     pages)
     assert "ssd_decode_step" in text
     state = f"{slots},64,64,128]"          # [.., B, H, P, N] f32
-    moved = [line.strip()[:160] for line in text.splitlines()
-             if re.search(r"= \(?f32\[(\d+,)?" + re.escape(state)
-                          + r"\S* (copy|copy-start|fusion|dynamic-slice|"
-                          r"dynamic-update-slice)\(", line)]
+    moved = _moved(text, "f32", state)
     assert not moved, moved
+    kv = _moved(text, "bf16", f"{pages + 1},8,16,64]")
+    kv += _moved(text, "bf16", f"{2 * (pages + 1)},8,16,64]")
+    entry = [comp for comp, _ in kv if comp.startswith("%main")]
+    assert len(entry) == len(kv) == 4, kv      # K and V, in and out
+    assert all(" copy(" in line for _, line in kv), kv
+    _kv_write_aliases_its_pools(text, 1)
