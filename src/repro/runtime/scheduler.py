@@ -5,7 +5,8 @@ every tick the host syncs the sampled tokens (``np.asarray``) before it
 may dispatch the next, so the device idles during host bookkeeping, and
 a long prompt's admission prefill stalls every in-flight decoder for
 its full duration.  :class:`PipelinedScheduler` drives the SAME engine
-— same jitted steps, same page allocator, same sampler keys — with two
+— the engine's own admission steps and device programs, page allocator
+and sampler keys; the scheduler decides only WHEN each runs — with two
 structural changes, and emits bit-identical streams while doing it:
 
 1. **Pipelined decode.**  Tick N+1 is dispatched before tick N's
@@ -18,7 +19,7 @@ structural changes, and emits bit-identical streams while doing it:
    Because the engine can't know a slot finished until its token is
    processed, a dispatch-ahead tick may write one position past the
    host mirror — admission therefore reserves ``pipeline_depth`` extra
-   positions per request (``ServeEngine._reserve_slack``), mirroring
+   positions per request (``ServeEngine.reserve_ahead``), mirroring
    how speculative ticks reserve ``spec_k``, and every dispatch runs
    ``_map_tick_pages(in_flight)`` so the write can only land in a page
    this slot holds exclusively (or the compute-skipped null page) —
@@ -26,11 +27,11 @@ structural changes, and emits bit-identical streams while doing it:
    freed or re-admitted in the meantime is discarded by uid guard.
 
 2. **Split prefill/decode streams.**  On a paged engine (with a prefix
-   cache or without), admission no longer runs as one fused
-   dispatch + host sync.  The prompt's unshared suffix prefills in
-   grid-aligned chunks (windows of ``prefill_chunk`` tokens at
-   absolute multiples of it, so jit compile keys stay bounded:
-   ``pos0`` is static in ``Model.apply``), ONE chunk dispatched per
+   cache or without), the engine's admission steps run one per tick,
+   not back to back as in ``ServeEngine.step``.  The prompt's unshared
+   suffix prefills in grid-aligned chunks (windows of ``prefill_chunk``
+   tokens at absolute multiples of it, so jit compile keys stay
+   bounded: ``pos0`` is static in ``Model.apply``), ONE chunk dispatched per
    tick between decode dispatches — a 10k-token prompt admits as many
    small dispatches interleaved with everyone else's decode ticks
    instead of one monolithic stall.  While a slot is mid-prefill it is
@@ -47,11 +48,11 @@ structural changes, and emits bit-identical streams while doing it:
    the oracle softmax sees the same columns), so streams match the
    synchronous engine bit for bit — asserted in tests.
 
-   Dense/ring backends and speculative engines admit atomically
-   through ``_admit_one``
-   (speculative engines tick through ``engine.step()`` — the verify
-   burst IS the decode stream); they still get admission control and
-   metrics, and non-spec engines still get pipelined decode.
+   Dense/ring backends and speculative engines admit in one call
+   (``ServeEngine.admit``), and speculative engines tick through
+   ``engine.step()`` — the verify burst IS the decode stream; they
+   still get admission control and metrics, and non-spec engines
+   still get pipelined decode.
 
 On top sit the serving policies the synchronous loop never had:
 **admission control** (``max_queue`` bound — submit past it is shed
@@ -123,8 +124,7 @@ import numpy as np
 
 from repro.runtime.faults import fault_point
 from repro.runtime.metrics import ServingMetrics
-from repro.runtime.serve_loop import (Request, ServeEngine, _SlotState,
-                                      host_copy)
+from repro.runtime.serve_loop import Request, ServeEngine, host_copy
 
 QUEUED, PREFILL, ACTIVE = "queued", "prefill", "active"
 DONE, SHED, CANCELLED = "done", "shed", "cancelled"
@@ -200,7 +200,7 @@ class PipelinedScheduler:
     max_queue: queued-request bound; ``submit`` past it returns None
         (shed) instead of queueing — overload sheds, it never stalls.
     prefill_chunk: chunk-grid width for split-stream admission
-        (default: the engine's ``prefill_chunk``, else 32).
+        (default: the engine's ``chunk``).
     metrics: a ``ServingMetrics`` to record into (default: fresh one).
     max_retries: per-request retry budget after a recovered fault; past
         it the request is quarantined (status FAILED, ``errors[uid]``).
@@ -238,19 +238,16 @@ class PipelinedScheduler:
                     or degrade is not None)
         self.depth = (0 if engine._spec or self._ft else pipeline_depth)
         self.max_queue = max_queue
-        self.chunk = max(1, prefill_chunk or engine.prefill_chunk or 32)
+        self.chunk = max(1, prefill_chunk or engine.chunk)
         self.metrics = metrics if metrics is not None else ServingMetrics()
         self.spans = engine.spans = self.metrics.spans
         self._clock = clock
         # dispatch-ahead ticks write past the host pos mirror: widen
-        # every reservation by the pipeline depth (the speculative
-        # engine's spec_k slack, generalized) BEFORE any admission
-        engine._reserve_slack = self.depth
+        # every reservation by the pipeline depth BEFORE any admission
+        engine.reserve_ahead(self.depth)
         self._chunked = engine.cache_kind == "paged" and not engine._spec
-        engine._exact_prompts = self._chunked
         # recurrent (SSM) layers: a chunk after a prompt's first resumes
-        # the slot's state, and the final chunk's tail pad must not
-        # advance it
+        # the slot's state
         self._ssm = bool(engine.model.cfg.ssm_positions)
         self._lock = threading.RLock()
         self._heap: list[_QEntry] = []
@@ -283,79 +280,12 @@ class PipelinedScheduler:
         self._capacity = engine.slots
         self._draining = False
 
-        model, sampler = engine.model, engine._sampler
-
-        # Every jit below DONATES the cache it threads: the KV pool is
-        # tens of MB, and a functional scatter without donation copies
-        # all of it on every dispatch.  The scheduler's lineage is
-        # strictly linear (each tick's cache feeds exactly the next
-        # dispatch, nothing on the host retains the old buffers), so
-        # XLA updates the pool in place and a decode tick or prefill
-        # chunk costs only its compute.
-
-        # ``pmask`` marks the slots the tick does not decode (parked
-        # mid-admission, or idle): their position is pinned to ``ppos``
-        # and their recurrent state, if any, left as it is
-
-        def _greedy_tick(params, cache, toks, pmask, ppos):
-            cache = dict(cache)
-            cache["pos"] = jnp.where(pmask, ppos, cache["pos"])
-            logits, cache = model.decode_step(params, cache, tokens=toks,
-                                              token_mask=~pmask)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-        def _sampled_tick(params, cache, toks, pmask, ppos, temps, keys):
-            cache = dict(cache)
-            cache["pos"] = jnp.where(pmask, ppos, cache["pos"])
-            logits, cache = model.decode_step(params, cache, tokens=toks,
-                                              token_mask=~pmask)
-            tok, keys = sampler(logits, keys, temps)
-            return tok, keys, cache
-
-        self._greedy_tick = jax.jit(_greedy_tick, donate_argnums=(1,))
-        self._sampled_tick = jax.jit(_sampled_tick, donate_argnums=(1,))
-
-        # The chunk jits fuse view-gather -> apply -> pool merge into one
-        # dispatch over the FULL layer tuple (slot traced, pos0 static):
-        # splitting them would force the donated pool out through three
-        # jit boundaries and re-copy it at each one.
-
-        def _chunk_mid(params, toks, layers, slot, pos0):
-            # interior prefill chunk: cache write-through only — no
-            # final norm / vocab projection for chunks that don't
-            # contain the last real token
-            view = tuple(c.prefill_view(slot) if hasattr(c, "prefill_view")
-                         else c for c in layers)
-            c = {"layers": view, "pos": jnp.full((), pos0, jnp.int32)}
-            out = model.apply(params, tokens=toks, cache=c,
-                              write_cache=True, need_logits=False,
-                              pos0=pos0)
-            return tuple(f.admit(o, slot) if hasattr(f, "admit") else f
-                         for f, o in zip(layers, out["cache"]["layers"]))
-
-        def _chunk_last(params, toks, layers, slot, pos0, last_index):
-            # final chunk: tail-padded to the grid window, the last REAL
-            # token's logits gathered at a traced index so the compile
-            # key is (window shape, pos0) — not the raw prompt length
-            view = tuple(c.prefill_view(slot) if hasattr(c, "prefill_view")
-                         else c for c in layers)
-            c = {"layers": view, "pos": jnp.full((), pos0, jnp.int32)}
-            tail = {}
-            if self._ssm:   # the tail pad must not advance the recurrence
-                tail = {"pad_mask": (jnp.arange(toks.shape[1])[None, :]
-                                     <= last_index),
-                        "start": jnp.zeros((1,), jnp.int32)}
-            out = model.apply(params, tokens=toks, cache=c,
-                              write_cache=True, last_only=True, pos0=pos0,
-                              last_index=last_index, **tail)
-            merged = tuple(f.admit(o, slot) if hasattr(f, "admit") else f
-                           for f, o in zip(layers, out["cache"]["layers"]))
-            return out["logits"][:, 0], merged
-
-        self._chunk_mid = jax.jit(_chunk_mid, static_argnums=(4,),
-                                  donate_argnums=(2,))
-        self._chunk_last = jax.jit(_chunk_last, static_argnums=(4,),
-                                   donate_argnums=(2,))
+        # the engine's programs; every decode tick dispatches through
+        # these two names (a wrapper may swap them on the instance)
+        self._greedy_tick = engine._greedy_tick
+        self._sampled_tick = engine._sampled_tick
+        self._chunk_mid = engine._chunk_mid
+        self._chunk_last = engine._chunk_last
 
     # .. intake ..
     def submit(self, tokens, *, max_new_tokens: int = 32,
@@ -374,12 +304,11 @@ class PipelinedScheduler:
             if self._queued >= self.max_queue:
                 self.metrics.shed("queue_full")
                 return None
-            # engine.submit runs the capacity validation (prompt length
-            # vs max_len, worst-case pages vs pool) and mints the uid;
-            # the request then moves to the scheduler's own queue
-            uid = self.engine.submit(tokens, max_new_tokens=max_new_tokens,
-                                     temperature=temperature)
-            req = self.engine._queue.pop()
+            # checked against the engine's capacity, queued here
+            req = self.engine.new_request(
+                tokens, max_new_tokens=max_new_tokens,
+                temperature=temperature)
+            uid = req.uid
             self._seq += 1
             heapq.heappush(self._heap, _QEntry(
                 priority, self._seq, req,
@@ -483,18 +412,10 @@ class PipelinedScheduler:
             # past the host mirror: make that whole span write-safe first
             eng._map_tick_pages(len(self._pipeline))
             toks = self._feed()
-            idle = np.ones((eng.slots,), bool)
-            idle[list(eng._active)] = False
-            pmask = host_copy(self._park_mask | idle)
+            pmask = host_copy(self._park_mask | eng.idle_mask())
             ppos = host_copy(self._park_pos)
-            fault_point("sampler")
-            if eng._temp.any() or eng._truncates:
-                tok, eng._keys, eng.cache = self._sampled_tick(
-                    eng.params, eng.cache, toks, pmask, ppos,
-                    host_copy(eng._temp), eng._keys)
-            else:
-                tok, eng.cache = self._greedy_tick(
-                    eng.params, eng.cache, toks, pmask, ppos)
+            tok = eng.decode_tick(self._greedy_tick, self._sampled_tick,
+                                  toks, pmask, ppos)
             self._tok_dev = tok
             return _Entry(tok, [(s, st.req.uid) for s, st in
                                 eng._active.items()], span=sp.id)
@@ -516,8 +437,7 @@ class PipelinedScheduler:
                 if st is None or st.req.uid != uid:
                     continue    # finished/cancelled after this dispatch:
                                 # the in-flight token is discarded
-                eng._pos[slot] += 1
-                eng._emit(slot, int(toks[slot]))
+                eng.emit_decoded(slot, int(toks[slot]))
                 sp.n += 1
         for slot, uid, tdev in entry.admits:
             st = eng._active.get(slot)
@@ -526,7 +446,7 @@ class PipelinedScheduler:
             with self.spans.span("sched.sync", n=-1):
                 tok = int(np.asarray(tdev)[0])
             with self.spans.span("sched.emit", n=1):
-                eng._emit(slot, tok)
+                eng.emit(slot, tok)
 
     # .. prefill stream ..
     def _pop_ready(self, now: float) -> Request | None:
@@ -582,12 +502,11 @@ class PipelinedScheduler:
             else:
                 t_admit = self.metrics.clock()
                 with self.spans.span("sched.admit", uid=req.uid):
-                    ok = eng._admit_one(slot, req)
+                    ok = eng.admit(slot, req)
                 if ok:
-                    eng._free.remove(slot)
-                    self._status[req.uid] = ACTIVE
                     self.metrics.admitted(req.uid, now=t_admit)
                     if slot in eng._active:   # not done at token one
+                        self._status[req.uid] = ACTIVE
                         self._tok_dev = self._feed().at[slot].set(
                             jnp.int32(int(eng._next_tok[slot])))
             if not ok:
@@ -600,31 +519,18 @@ class PipelinedScheduler:
                 return
 
     def _start_admission(self, slot: int, req: Request) -> bool:
-        """Begin split-stream admission: map the prompt's pages (shared
-        prefix + fresh suffix, or without a prefix cache the whole
-        prompt's) and either finish immediately (fully cached prompt —
-        one peek dispatch) or park the slot and hand the rest to the
-        chunk stream."""
-        eng = self.engine
-        n = len(req.tokens)
+        """Begin split-stream admission and either finish immediately
+        (fully cached prompt — one peek dispatch) or park the slot and
+        hand the rest to the chunk stream."""
+        eng, n = self.engine, len(req.tokens)
         with self.spans.span("sched.admit", uid=req.uid):
-            fault_point("prefill.dispatch", uid=req.uid)
-            if eng._prefix is not None:
-                pos0 = eng._map_prefix(slot, req)
-            else:
-                pos0 = 0 if eng._alloc_pages(
-                    slot, -(-n // eng.page_size),
-                    eng._pages_needed(n, req.max_new_tokens)) else None
+            pos0 = eng.begin_admission(slot, req)
             if pos0 is None:
                 return False
-            eng._free.remove(slot)
             self.metrics.admitted(req.uid)
             if pos0 >= n:
-                # fully cached: a read-only peek of the last token's logits
-                view = eng._view(eng.cache["layers"], slot)
-                toks = jnp.asarray([[req.tokens[-1]]], jnp.int32)
-                logits = eng._peek(eng.params, toks, view, n - 1)
-                self._complete_admission(slot, req, logits)
+                self._complete_admission(slot, req,
+                                         eng.peek_admission(slot, req))
             else:
                 self._prefill = _Prefill(slot, req, pos0, n)
                 self._status[req.uid] = PREFILL
@@ -635,31 +541,19 @@ class PipelinedScheduler:
     def _advance_chunk(self) -> None:
         """Dispatch ONE grid-aligned prefill chunk for the admission in
         flight; the final chunk completes it.  Windows end at absolute
-        multiples of ``self.chunk`` (capped at the block table's reach),
-        so jit compiles once per (window shape, window start) — shared
-        across prompts and match depths on the grid."""
+        multiples of ``self.chunk`` (``ServeEngine.chunk_end``)."""
         pf = self._prefill
         assert pf is not None
         eng, req, slot, lo = self.engine, pf.req, pf.slot, pf.lo
-        cap = eng._pps * eng.page_size
-        hi = min((lo // self.chunk + 1) * self.chunk, cap)
+        hi = eng.chunk_end(lo, self.chunk)
         real_hi = min(hi, pf.n)
         with self.spans.span("sched.chunk", uid=req.uid, n=real_hi - lo,
                              carry=int(self._ssm and lo > 0)):
-            fault_point("prefill.dispatch", uid=req.uid)
-            toks = jnp.asarray(
-                [req.tokens[lo:real_hi]
-                 + [eng.pad_id] * (hi - real_hi)], jnp.int32)
-            slot_t = jnp.int32(slot)
-            if real_hi < pf.n:
-                eng.cache["layers"] = self._chunk_mid(
-                    eng.params, toks, eng.cache["layers"], slot_t, lo)
+            logits = eng.admit_chunk(slot, req, lo, hi)
+            if logits is None:
                 pf.lo = hi
                 self._park_pos[slot] = hi     # frontier moved
             else:
-                logits, eng.cache["layers"] = self._chunk_last(
-                    eng.params, toks, eng.cache["layers"], slot_t, lo,
-                    jnp.asarray(pf.n - 1 - lo, jnp.int32))
                 self._prefill = None
                 self._park_mask[slot] = False
                 self._complete_admission(slot, req, logits)
@@ -668,23 +562,7 @@ class PipelinedScheduler:
         """Activate the slot and sample the first token WITHOUT a host
         sync: the token stays on device (fed to the next decode tick),
         and its emission rides the pipeline as an admit record."""
-        eng, n = self.engine, len(req.tokens)
-        if eng._prefix is not None:
-            eng._prefix.insert(
-                req.tokens,
-                [int(p) for p in eng._table[slot, :n // eng.page_size]])
-        eng.cache["pos"] = eng.cache["pos"].at[slot].set(n)
-        eng.cache["start"] = eng.cache["start"].at[slot].set(0)
-        eng._pos[slot] = n
-        eng._active[slot] = _SlotState(req)
-        eng._temp[slot] = req.temperature
-        eng._keys = eng._keys.at[slot].set(
-            jax.random.fold_in(eng._seed_key, req.uid))
-        fault_point("sampler", uid=req.uid)
-        tok, krow = eng._sampler(
-            logits, eng._keys[slot:slot + 1],
-            jnp.full((1,), req.temperature, jnp.float32))
-        eng._keys = eng._keys.at[slot].set(krow[0])
+        tok = self.engine.complete_admission(slot, req, logits)
         self._tok_dev = self._feed().at[slot].set(tok[0])
         self._status[req.uid] = ACTIVE
         record = (slot, req.uid, tok)

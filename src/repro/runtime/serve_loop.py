@@ -4,6 +4,9 @@ Three layers, bottom-up:
 
 - ``make_prefill_step`` / ``make_serve_step``: the single jitted
   functions the decode_* and long_* dry-run cells lower;
+  ``make_serve_programs``: the engine's masked decode ticks and
+  admission chunk programs, which ``ServeEngine`` and
+  ``repro.runtime.scheduler.PipelinedScheduler`` both dispatch;
 - ``generate``: the end-to-end loop used by examples and tests.  The
   prompt is prefilled through the masked flash-attention cache
   write-through path (one ``model.prefill`` call; prompts longer than
@@ -16,9 +19,10 @@ Three layers, bottom-up:
   the other slots keep decoding; finished slots are refilled from the
   queue.  With the default PAGED backend, admission allocates
   fixed-size pages from a shared pool and prefills straight through a
-  block-table view — page indices move, cache rows never do — and a
-  finished request's pages return to the pool; the decode tick then
-  reads those pages IN PLACE: ``decode_step`` hands the pool + block
+  block-table view, in windows on a fixed grid — page indices move,
+  cache rows never do — and a finished request's pages return to the
+  pool; the decode tick then reads those pages IN PLACE:
+  ``decode_step`` hands the pool + block
   table to the paged-attention kernel (``repro.kernels.paged_attention``
   — scalar-prefetched table, per-page int8 scales dequantized
   in-kernel, null pages compute-skipped), so a tick never materializes
@@ -35,9 +39,8 @@ Three layers, bottom-up:
   per-slot positions over a window-sized ring, prompts longer than the
   window included).
   Sampling runs ON DEVICE (``repro.runtime.sampling``): each decode
-  tick is one batched decode dispatch plus one batched sample dispatch,
-  and only [B] int32 tokens cross back to the host — never the [B, V]
-  logits.
+  tick is one dispatch that decodes and samples every slot, and only
+  [B] int32 tokens cross back to the host — never the [B, V] logits.
 
 With EN-T quantized params every projection in every one of these paths
 runs the FUSED packed-plane matmul (repro.quant.qdense_apply): per-row
@@ -88,6 +91,79 @@ def make_serve_step(model: Model, *, donate_cache: bool | None = None):
         return model.decode_step(params, cache, tokens=tokens)
 
     return jax.jit(serve_step, donate_argnums=(1,) if donate_cache else ())
+
+
+def make_serve_programs(model: Model, sampler):
+    """The engine's device programs ``(greedy_tick, sampled_tick,
+    chunk_mid, chunk_last)``: a decode tick over every slot (argmax, or
+    ``sampler``), and one window of one slot's prompt prefilled at the
+    static absolute position ``pos0`` (the final window also returns the
+    last real token's logits).  ``pmask`` marks the slots a tick does not
+    decode (idle, or parked mid-admission): their position is pinned to
+    ``ppos`` and their recurrent state, if any, left as it is.
+
+    Every program DONATES the cache it threads: a serving loop's lineage
+    is strictly linear, so XLA updates the pool in place instead of
+    copying tens of MB on every dispatch.
+    """
+    ssm = bool(model.cfg.ssm_positions)
+
+    def _greedy_tick(params, cache, toks, pmask, ppos):
+        cache = dict(cache)
+        cache["pos"] = jnp.where(pmask, ppos, cache["pos"])
+        logits, cache = model.decode_step(params, cache, tokens=toks,
+                                          token_mask=~pmask)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+    def _sampled_tick(params, cache, toks, pmask, ppos, temps, keys):
+        cache = dict(cache)
+        cache["pos"] = jnp.where(pmask, ppos, cache["pos"])
+        logits, cache = model.decode_step(params, cache, tokens=toks,
+                                          token_mask=~pmask)
+        tok, keys = sampler(logits, keys, temps)
+        return tok, keys, cache
+
+    # The chunk programs fuse view-gather -> apply -> pool merge into one
+    # dispatch over the FULL layer tuple (slot traced, pos0 static):
+    # splitting them would force the donated pool out through three jit
+    # boundaries and re-copy it at each one.
+
+    def _chunk_mid(params, toks, layers, slot, pos0):
+        # interior prefill chunk: cache write-through only — no
+        # final norm / vocab projection for chunks that don't
+        # contain the last real token
+        view = tuple(c.prefill_view(slot) if hasattr(c, "prefill_view")
+                     else c for c in layers)
+        c = {"layers": view, "pos": jnp.full((), pos0, jnp.int32)}
+        out = model.apply(params, tokens=toks, cache=c,
+                          write_cache=True, need_logits=False,
+                          pos0=pos0)
+        return tuple(f.admit(o, slot) if hasattr(f, "admit") else f
+                     for f, o in zip(layers, out["cache"]["layers"]))
+
+    def _chunk_last(params, toks, layers, slot, pos0, last_index):
+        # final chunk: tail-padded to the grid window, the last REAL
+        # token's logits gathered at a traced index so the compile
+        # key is (window shape, pos0) — not the raw prompt length
+        view = tuple(c.prefill_view(slot) if hasattr(c, "prefill_view")
+                     else c for c in layers)
+        c = {"layers": view, "pos": jnp.full((), pos0, jnp.int32)}
+        tail = {}
+        if ssm:   # the tail pad must not advance the recurrence
+            tail = {"pad_mask": (jnp.arange(toks.shape[1])[None, :]
+                                 <= last_index),
+                    "start": jnp.zeros((1,), jnp.int32)}
+        out = model.apply(params, tokens=toks, cache=c,
+                          write_cache=True, last_only=True, pos0=pos0,
+                          last_index=last_index, **tail)
+        merged = tuple(f.admit(o, slot) if hasattr(f, "admit") else f
+                       for f, o in zip(layers, out["cache"]["layers"]))
+        return out["logits"][:, 0], merged
+
+    return (jax.jit(_greedy_tick, donate_argnums=(1,)),
+            jax.jit(_sampled_tick, donate_argnums=(1,)),
+            jax.jit(_chunk_mid, static_argnums=(4,), donate_argnums=(2,)),
+            jax.jit(_chunk_last, static_argnums=(4,), donate_argnums=(2,)))
 
 
 def _pad_mask_from_lens(prompt_lens, b: int, s0: int):
@@ -240,10 +316,6 @@ def host_copy(mirror: np.ndarray) -> jax.Array:
     return jnp.asarray(mirror.copy())
 
 
-def argmax_tokens(logits):
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
 def _bucket(n: int, lo: int) -> int:
     """Round a prompt length up to a power of two (>= lo) so prefill jits
     once per bucket instead of once per length."""
@@ -330,19 +402,23 @@ class ServeEngine:
     The engine keeps one [slots, max_len] decode cache with PER-SLOT
     positions and pad offsets (``cache["pos"]``/``cache["start"]`` are [B]
     vectors).  Each ``step()`` tick first admits queued requests into free
-    slots — the newcomer's prompt is prefilled through the batched cache
-    write-through path (bucketed to a power-of-two length, left-padded +
-    masked, chunked at ``prefill_chunk`` when set) directly into a
-    single-slot ``prefill_view`` of the batch cache, then merged back
-    with the backend's ``admit`` — then runs ONE batched decode step
-    plus ONE batched on-device sample step for every slot: per-slot
-    temperatures ride in a [slots] vector, each slot draws from its own
-    PRNG key (folded from the engine seed and the request uid, so
-    replays are slot-placement independent), and only the [slots]
-    sampled tokens are transferred back.  A slot is freed on EOS or
-    ``max_new_tokens`` and immediately becomes refillable, so long and
-    short requests share the batch without barriers (continuous
-    batching).
+    slots (``admit``), then runs ONE masked decode tick that decodes and
+    samples every active slot on device (``make_serve_programs``; idle
+    slots are masked out): per-slot temperatures ride in a [slots]
+    vector, each slot draws from its own PRNG key (folded from the
+    engine seed and the request uid, so replays are slot-placement
+    independent), and only the [slots] sampled tokens are transferred
+    back.  A slot is freed on EOS or ``max_new_tokens`` and immediately
+    becomes refillable, so long and short requests share the batch
+    without barriers (continuous batching).
+
+    ADMISSION.  A paged engine admits a prompt at its exact length and
+    absolute positions: ``begin_admission`` maps its pages,
+    ``admit_chunk`` prefills one window of the ``chunk`` grid, and
+    ``complete_admission`` activates the slot and samples the first
+    token on device.  ``admit`` runs them back to back; a
+    ``PipelinedScheduler`` runs one window per tick.  Dense and ring
+    backends admit in one dispatch (``_admit_one``).
 
     ``cache_kind`` picks the KV backend; the default is PAGED for
     full-attention models and RING for sliding-window models:
@@ -377,15 +453,11 @@ class ServeEngine:
     of its KV.  A FULLY cached prompt admits with a read-only peek of
     its last token's logits (``Model.apply(peek=True)``): zero fresh
     pages, zero copies — the thundering-herd case costs one forward of
-    one token.  To keep shared pages byte-identical across holders,
-    prefix-cached admission prefills at start 0 with absolute positions
-    (RoPE rotations line up for every request; the ragged parity tests
-    pin unpadded == padded emissions, so streams stay bit-identical to
-    the bucketed path); the suffix is TAIL-padded to a power-of-two
-    bucket with the real length traced, so the admission jit compiles
-    once per (suffix bucket, match depth) pair — match depths are
-    page-quantized and shared-prefix workloads reuse a handful — not
-    once per raw suffix length.  Admission counts pages it is about to
+    one token.  Paged admission keeps absolute positions, so RoPE
+    rotations line up for every request and shared pages stay
+    byte-identical across holders; match depths are page-quantized, so
+    a suffix window starts on one of a handful of grid points and
+    shares their compiles.  Admission counts pages it is about to
     pin OUT of the availability check (a matched page the cache alone
     holds stops being evictable the moment it is shared), so pool
     pressure stalls admission instead of breaking a live reservation.
@@ -435,7 +507,8 @@ class ServeEngine:
     acceptance.  Sliding-window (ring) targets and drafters are
     rejected: a burst write evicts window rows rollback cannot restore.
     The drafter must share the target's tokenizer (vocab); its KV runs
-    a dense cache prefilled alongside the target's at admission.
+    a dense cache prefilled alongside the target's at admission (the
+    whole prompt in one dispatch, after a paged target's chunks).
     """
 
     def __init__(self, model: Model, params, *, slots: int = 4,
@@ -458,6 +531,9 @@ class ServeEngine:
         self.eos_id, self.pad_id = eos_id, pad_id
         self.prefill_bucket = prefill_bucket
         self.prefill_chunk = prefill_chunk
+        # the paged admission grid: windows end at multiples of it (a
+        # PipelinedScheduler takes it as its default)
+        self.chunk = max(1, prefill_chunk or 32)
         self.on_token = on_token
         if cache_kind == "paged":
             self.page_size = page_size or kv_cache.DEFAULT_PAGE_SIZE
@@ -502,7 +578,6 @@ class ServeEngine:
         cache["pos"] = jnp.zeros((slots,), jnp.int32)
         cache["start"] = jnp.zeros((slots,), jnp.int32)
         self.cache = cache
-        self._decode = make_serve_step(model)
 
         # where the engine's spans go (a scheduler hands it its own log)
         self.spans = SPANS
@@ -517,40 +592,9 @@ class ServeEngine:
             return model.prefill(params, c, tokens=toks, pad_mask=mask,
                                  chunk=prefill_chunk)
 
-        # jit's own shape-keyed cache compiles once per length bucket
+        # row-backend admission: jit's own shape-keyed cache compiles
+        # once per length bucket
         self._prefill = jax.jit(_prefill_into)
-
-        def _suffix_prefill(params, toks, layers, pos0, nreal):
-            # prefix-shared admission: resume the prompt at its first
-            # unshared position on top of the mapped shared pages.  The
-            # suffix arrives TAIL-padded to a power-of-two bucket with
-            # the real length ``nreal`` traced, so jit compiles once per
-            # (bucket, match depth) pair instead of once per raw suffix
-            # length.  Pad rows write garbage KV at rows >= the prompt
-            # end — rows decode overwrites before any pos-bounded read
-            # can see them (rows past the mapped pages scatter into the
-            # compute-skipped null page) — and the last REAL token's
-            # logits are gathered at a traced index, chunk by chunk.
-            c = {"layers": layers, "pos": jnp.full((), pos0, jnp.int32)}
-            sp = toks.shape[1]
-            step = min(prefill_chunk or sp, sp)
-            last = nreal - 1
-            logits = None
-            for lo in range(0, sp, step):
-                hi = min(lo + step, sp)
-                out = model.apply(
-                    params, tokens=jax.lax.slice_in_dim(toks, lo, hi, axis=1),
-                    cache=c, write_cache=True, last_only=True,
-                    pos0=pos0 + lo,
-                    last_index=jnp.clip(last - lo, 0, hi - lo - 1))
-                c = out["cache"]
-                chunk_logits = out["logits"][:, 0]
-                sel = (last >= lo) & (last < hi)
-                logits = chunk_logits if logits is None else jnp.where(
-                    sel, chunk_logits, logits)
-            return logits, c
-
-        self._prefill_suffix = jax.jit(_suffix_prefill, static_argnums=(3,))
 
         def _peek_last(params, toks, layers, pos0):
             # fully prefix-cached prompt: every KV row already lives in
@@ -567,7 +611,8 @@ class ServeEngine:
         self._copy_pages = jax.jit(kv_copy_pages)
         self._sampler = sampling.make_sampler(top_k, top_p, pad_id)
         self._truncates = top_k is not None or top_p is not None
-        self._argmax = jax.jit(argmax_tokens)
+        (self._greedy_tick, self._sampled_tick, self._chunk_mid,
+         self._chunk_last) = make_serve_programs(model, self._sampler)
         self._seed_key = jax.random.PRNGKey(seed)
         self._keys = sampling.init_keys(self._seed_key, slots)
         self._temp = np.zeros((slots,), np.float32)
@@ -582,14 +627,8 @@ class ServeEngine:
         self._next_tok = np.full((slots,), pad_id, np.int32)
         self._results: dict[int, list[int]] = {}
         self._next_uid = 0
-        # extra per-request page reservation demanded by a pipelined
-        # scheduler: a dispatch-ahead tick can map/write up to this many
-        # positions past prompt+max_new before the host learns a request
-        # finished (PipelinedScheduler sets this to its pipeline depth)
+        # extra per-request page reservation (``reserve_ahead``)
         self._reserve_slack = 0
-        # a scheduler that admits in unpadded chunks sets this: prompts
-        # then take their exact length, not a left-padded bucket
-        self._exact_prompts = False
 
         # .. speculative decoding ..
         self._spec = draft_model is not None
@@ -679,13 +718,21 @@ class ServeEngine:
     # .. request intake ..
     def submit(self, tokens, *, max_new_tokens: int = 32,
                temperature: float = 0.0) -> int:
+        req = self.new_request(tokens, max_new_tokens=max_new_tokens,
+                               temperature=temperature)
+        self._queue.append(req)
+        return req.uid
+
+    def new_request(self, tokens, *, max_new_tokens: int = 32,
+                    temperature: float = 0.0) -> Request:
+        """Check a prompt against the engine's capacity and mint its uid:
+        the :class:`Request`, for ``submit`` (or a scheduler) to queue."""
         tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
         if not tokens:
             raise ValueError("cannot serve an empty prompt")
-        # prefix-cached and chunked admission keep absolute positions
-        # (tail pads never occupy one), so the exact length is the
-        # capacity bound
-        exact = self._prefix is not None or self._exact_prompts
+        # paged admission keeps absolute positions (tail pads never
+        # occupy one), so the exact length is the capacity bound
+        exact = self.cache_kind == "paged"
         sp = len(tokens) if exact else _bucket(len(tokens),
                                                self.prefill_bucket)
         if sp + max_new_tokens > self.max_len:
@@ -702,8 +749,12 @@ class ServeEngine:
                     f"only has {self._npages}; raise pages= or page_size=")
         uid = self._next_uid
         self._next_uid += 1
-        self._queue.append(Request(uid, tokens, max_new_tokens, temperature))
-        return uid
+        return Request(uid, tokens, max_new_tokens, temperature)
+
+    def reserve_ahead(self, positions: int) -> None:
+        """Widen every later page reservation by ``positions``: each tick
+        a driver dispatches ahead of the host may write one past it."""
+        self._reserve_slack = positions
 
     # .. internals ..
     def _release_slot(self, slot: int) -> None:
@@ -711,7 +762,7 @@ class ServeEngine:
         zero its pos/start/temp mirrors, and drop every page reference
         it holds (exclusive pages free immediately; prefix-shared pages
         just lose one holder — the cache's pin keeps them resident).
-        Records NOTHING: ``_emit`` stores the result first on normal
+        Records NOTHING: ``emit`` stores the result first on normal
         completion, while ``cancel`` calls this directly so an aborted
         request leaves no trace but its freed capacity.  Tolerates a
         slot that is mid-admission (reserved pages but no ``_active``
@@ -759,7 +810,7 @@ class ServeEngine:
                 return True
         return False
 
-    def _emit(self, slot: int, tok: int) -> bool:
+    def emit(self, slot: int, tok: int) -> bool:
         """Record one sampled token; returns True if the request finished."""
         st = self._active[slot]
         st.emitted.append(tok)
@@ -774,6 +825,18 @@ class ServeEngine:
         else:
             self._next_tok[slot] = tok
         return done
+
+    def emit_decoded(self, slot: int, tok: int) -> bool:
+        """``emit`` a decode tick's token, past the position the tick
+        wrote; returns True if the request finished."""
+        self._pos[slot] += 1
+        return self.emit(slot, tok)
+
+    def idle_mask(self) -> np.ndarray:
+        """[slots] bool: the slots no request is decoding in."""
+        idle = np.ones((self.slots,), bool)
+        idle[list(self._active)] = False
+        return idle
 
     def _pages_needed(self, prompt_len: int, max_new: int) -> int:
         """Worst-case pages one request can touch: positions
@@ -877,21 +940,6 @@ class ServeEngine:
                            jnp.asarray(dst, jnp.int32))
         return bool(src)
 
-    def _alloc_pages(self, slot: int, need: int, reserve: int) -> bool:
-        """Reserve ``reserve`` pages for the request's lifetime and map
-        the first ``need`` (the prompt) onto ``slot``'s block-table
-        prefix; False when the pool can't cover the reservation
-        (admission waits for an EOS)."""
-        if self._pages_available() < reserve:
-            return False
-        self._slot_reserved[slot] = reserve
-        pids = self._take_pages(need)
-        self._slot_pages[slot] = pids
-        self._table[slot] = 0
-        self._table[slot, :need] = pids
-        self._push_tables()
-        return True
-
     def _push_tables(self) -> None:
         """Hand the host block table to every paged layer: one dispatch
         over the whole pool (span ``kv.set_tables``)."""
@@ -909,17 +957,17 @@ class ServeEngine:
         self.cache["layers"] = tuple(c if n is None else n
                                      for c, n in zip(layers, new))
 
-    def _map_prefix(self, slot: int, req: Request) -> int | None:
-        """Prefix-cached page mapping for ``req``: walk the radix cache,
-        map the shared prefix pages into ``slot``'s block table
-        (refcount + 1 each) and allocate fresh pages for the unshared
-        rest of the prompt.  Returns the resume position ``pos0``
-        (first position the prefill must compute; ``pos0 == len(
-        tokens)`` means fully cached — admission then only peeks the
-        last token's logits, writing nothing), or None when the pool
-        can't cover the reservation (admission stalls)."""
+    def _map_pages(self, slot: int, req: Request) -> int | None:
+        """Reserve ``req``'s worst case and map its prompt onto ``slot``'s
+        block table: with a prefix cache, the longest cached prefix's
+        shared pages (refcount + 1 each), then fresh pages for the rest
+        of the prompt.  Returns the resume position ``pos0`` (the first
+        position the prefill must compute; ``len(req.tokens)`` when the
+        whole prompt is cached), or None when the pool can't cover the
+        reservation (admission stalls)."""
         n, ps = len(req.tokens), self.page_size
-        matched, spids = self._prefix.match(req.tokens)
+        matched, spids = (self._prefix.match(req.tokens)
+                          if self._prefix is not None else (0, []))
         reserve = (self._pages_needed(n, req.max_new_tokens)
                    - len(spids))
         # the matched pages are about to be pinned for the slot's
@@ -945,87 +993,66 @@ class ServeEngine:
         self._push_tables()
         return matched
 
-    def _admit_one(self, slot: int, req: Request) -> bool:
-        """Admit ``req`` into ``slot``; False when the paged pool can't
-        cover its worst case yet (the caller stops admitting until an
-        EOS returns pages)."""
+    # .. admission ..
+    def begin_admission(self, slot: int, req: Request) -> int | None:
+        """Map ``req``'s prompt pages onto free ``slot`` and claim it.
+        Returns the position the prefill resumes at (``len(req.tokens)``:
+        all cached, see ``peek_admission``), or None when the pool can't
+        cover the request's worst case yet (admission waits for an EOS)."""
+        fault_point("prefill.dispatch", uid=req.uid)
+        pos0 = self._map_pages(slot, req)
+        if pos0 is not None:
+            self._free.remove(slot)
+        return pos0
+
+    def peek_admission(self, slot: int, req: Request):
+        """A fully cached prompt's last-token logits: a read-only forward,
+        so admission takes zero fresh pages and copies nothing."""
+        view = self._view(self.cache["layers"], slot)
+        toks = jnp.asarray([[req.tokens[-1]]], jnp.int32)
+        return self._peek(self.params, toks, view, len(req.tokens) - 1)
+
+    def chunk_end(self, lo: int, chunk: int) -> int:
+        """End of the window from ``lo`` on the ``chunk`` grid (capped at
+        the block table's reach): compiles are keyed on window starts."""
+        return min((lo // chunk + 1) * chunk, self._pps * self.page_size)
+
+    def admit_chunk(self, slot: int, req: Request, lo: int, hi: int):
+        """Prefill positions [lo, hi) of ``req``'s prompt into ``slot``,
+        tail-padded past its end (rows decode overwrites before any read).
+        Returns the last token's logits [1, V] if the window holds it."""
         fault_point("prefill.dispatch", uid=req.uid)
         n = len(req.tokens)
+        real_hi = min(hi, n)
+        toks = jnp.asarray(
+            [req.tokens[lo:real_hi] + [self.pad_id] * (hi - real_hi)],
+            jnp.int32)
+        slot_t = jnp.int32(slot)
+        if real_hi < n:
+            self.cache["layers"] = self._chunk_mid(
+                self.params, toks, self.cache["layers"], slot_t, lo)
+            return None
+        logits, self.cache["layers"] = self._chunk_last(
+            self.params, toks, self.cache["layers"], slot_t, lo,
+            jnp.asarray(n - 1 - lo, jnp.int32))
+        return logits
+
+    def complete_admission(self, slot: int, req: Request, logits,
+                           pos: int | None = None, start: int = 0):
+        """Activate ``slot`` once ``req``'s prompt is prefilled: cache its
+        full pages for sharing, set the position (default: the prompt
+        length) and pad offset, fold the request's key and sample from
+        ``logits``.  Returns the first token [1] on the device."""
+        if pos is None:
+            pos = len(req.tokens)
         if self._prefix is not None:
-            pos0 = self._map_prefix(slot, req)
-            if pos0 is None:
-                return False
-            view = self._view(self.cache["layers"], slot)
-            if pos0 >= n:
-                # fully cached: read-only last-token forward — the
-                # shared pages already hold every KV row, so admission
-                # takes zero fresh pages and copies nothing (the first
-                # decode write lands past the prompt, outside the
-                # shared full pages)
-                toks = jnp.asarray([[req.tokens[-1]]], jnp.int32)
-                logits = self._peek(self.params, toks, view, n - 1)
-            else:
-                # suffix-only prefill, unpadded at start 0: positions
-                # (and RoPE rotations) line up across every request
-                # sharing the prefix, so the pages are byte-shareable.
-                # The suffix is TAIL-padded to a power-of-two bucket
-                # (real length traced) so compiles are keyed on
-                # (bucket, match depth), not every raw suffix length;
-                # pad rows land past the prompt where pos-bounded reads
-                # never look before decode overwrites them.
-                real = n - pos0
-                spb = min(_bucket(real, self.prefill_bucket),
-                          self._pps * self.page_size - pos0)
-                toks = jnp.asarray(
-                    [req.tokens[pos0:] + [self.pad_id] * (spb - real)],
-                    jnp.int32)
-                logits, c1 = self._prefill_suffix(
-                    self.params, toks, view, pos0,
-                    jnp.asarray(real, jnp.int32))
-                self.cache["layers"] = self._admit_slot(
-                    self.cache["layers"], c1["layers"], slot)
             # register the full-page prompt blocks for future sharing
             # (already-cached blocks keep their canonical pages)
             self._prefix.insert(
-                req.tokens,
-                [int(p) for p in self._table[slot, :n // self.page_size]])
-            # the drafter shadow-prefills the WHOLE prompt tail-padded
-            # to a bucket (compiles per bucket, not per length); an SSM
-            # drafter's conv window/SSD state must end on the real last
-            # token, so it stays unpadded
-            spd = (n if self._spec and self._d_has_ssm
-                   else min(_bucket(n, self.prefill_bucket), self.max_len))
-            dtoks, dmask, pos, start = (
-                jnp.asarray([req.tokens + [self.pad_id] * (spd - n)],
-                            jnp.int32), None, n, 0)
-        else:
-            sp = _bucket(n, self.prefill_bucket)
-            if self.cache_kind == "paged" and not self._alloc_pages(
-                    slot, -(-sp // self.page_size),
-                    self._pages_needed(sp, req.max_new_tokens)):
-                return False
-            toks = jnp.asarray([[self.pad_id] * (sp - n) + req.tokens],
-                               jnp.int32)
-            mask, _ = _pad_mask_from_lens([n], 1, sp)
-            # prefill straight into a single-slot view of the batch cache
-            # (zeroed rows for dense/ring; the live page pool for paged,
-            # where admission therefore copies no rows at all), then
-            # merge back through the backend's ``admit``
-            view = self._view(self.cache["layers"], slot)
-            logits, c1 = self._prefill(self.params, toks, mask, view)
-            self.cache["layers"] = self._admit_slot(
-                self.cache["layers"], c1["layers"], slot)
-            dtoks, dmask, pos, start = toks, mask, sp, sp - n
+                req.tokens, [int(p) for p in self._table[
+                    slot, :len(req.tokens) // self.page_size]])
         self.cache["pos"] = self.cache["pos"].at[slot].set(pos)
         self.cache["start"] = self.cache["start"].at[slot].set(start)
-        if self._spec:   # the drafter shadows the (full) prompt prefill
-            dview = self._view(self._dcache["layers"], slot)
-            _, d1 = self._dprefill(self.draft_params, dtoks, dmask, dview)
-            self._dcache["layers"] = self._admit_slot(
-                self._dcache["layers"], d1["layers"], slot)
-            self._dcache["pos"] = self._dcache["pos"].at[slot].set(pos)
-            self._dcache["start"] = (
-                self._dcache["start"].at[slot].set(start))
         self._pos[slot] = pos
         self._start[slot] = start
         self._active[slot] = _SlotState(req)
@@ -1039,17 +1066,76 @@ class ServeEngine:
             logits, self._keys[slot:slot + 1],
             jnp.full((1,), req.temperature, jnp.float32))
         self._keys = self._keys.at[slot].set(krow[0])
-        self._emit(slot, int(tok[0]))
+        return tok
+
+    def admit(self, slot: int, req: Request) -> bool:
+        """Admit ``req`` into free ``slot`` and emit its first token;
+        False when the pool can't cover its worst case yet."""
+        n = len(req.tokens)
+        if self.cache_kind != "paged":
+            logits, pos, start = self._admit_one(slot, req)
+        else:
+            lo = self.begin_admission(slot, req)
+            if lo is None:
+                return False
+            if lo >= n:
+                logits = self.peek_admission(slot, req)
+            else:
+                logits = None
+                while logits is None:
+                    hi = self.chunk_end(lo, self.chunk)
+                    logits = self.admit_chunk(slot, req, lo, hi)
+                    lo = hi
+            if self._spec:
+                # the drafter prefills the WHOLE prompt at start 0,
+                # tail-padded to a bucket (compiles per bucket, not per
+                # length); an SSM drafter's conv window/SSD state must
+                # end on the real last token, so it stays unpadded
+                spd = (n if self._d_has_ssm else
+                       min(_bucket(n, self.prefill_bucket), self.max_len))
+                self._prefill_drafter(
+                    slot, jnp.asarray(
+                        [req.tokens + [self.pad_id] * (spd - n)],
+                        jnp.int32), None, n, 0)
+            pos, start = n, 0
+        tok = self.complete_admission(slot, req, logits, pos, start)
+        self.emit(slot, int(tok[0]))
         return True
+
+    def _admit_one(self, slot: int, req: Request):
+        """Dense/ring admission prefill: ``req``'s prompt, left-padded to
+        a power-of-two bucket, into a zeroed single-slot view of free
+        ``slot`` (claimed), merged back through the backend's ``admit``.
+        Returns (logits, position, pad offset)."""
+        fault_point("prefill.dispatch", uid=req.uid)
+        n = len(req.tokens)
+        sp = _bucket(n, self.prefill_bucket)
+        toks = jnp.asarray([[self.pad_id] * (sp - n) + req.tokens],
+                           jnp.int32)
+        mask, _ = _pad_mask_from_lens([n], 1, sp)
+        view = self._view(self.cache["layers"], slot)
+        logits, c1 = self._prefill(self.params, toks, mask, view)
+        self.cache["layers"] = self._admit_slot(
+            self.cache["layers"], c1["layers"], slot)
+        self._free.remove(slot)
+        if self._spec:
+            self._prefill_drafter(slot, toks, mask, sp, sp - n)
+        return logits, sp, sp - n
+
+    def _prefill_drafter(self, slot, toks, mask, pos, start) -> None:
+        """The drafter shadows the target's admission in one dispatch."""
+        dview = self._view(self._dcache["layers"], slot)
+        _, d1 = self._dprefill(self.draft_params, toks, mask, dview)
+        self._dcache["layers"] = self._admit_slot(
+            self._dcache["layers"], d1["layers"], slot)
+        self._dcache["pos"] = self._dcache["pos"].at[slot].set(pos)
+        self._dcache["start"] = self._dcache["start"].at[slot].set(start)
 
     def _admit(self):
         while self._queue and self._free:
-            req = self._queue[0]
-            slot = self._free[-1]
-            if not self._admit_one(slot, req):
+            if not self.admit(self._free[-1], self._queue[0]):
                 break          # pool dry: requests wait for a slot's EOS
             self._queue.popleft()
-            self._free.remove(slot)
 
     def _map_tick_pages(self, span: int = 0) -> None:
         """Make positions ``[pos, pos+span]`` write-safe for every active
@@ -1084,10 +1170,25 @@ class ServeEngine:
             self._push_tables()
 
     # .. driving ..
+    def decode_tick(self, greedy_tick, sampled_tick, toks, pmask, ppos):
+        """Dispatch one decode tick through ``greedy_tick`` (no slot
+        samples) or ``sampled_tick`` — the engine's programs or a driver's
+        wrapping — and keep the cache and keys it returns.  Returns the
+        [slots] tokens on the device."""
+        fault_point("sampler")
+        if self._temp.any() or self._truncates:
+            tok, self._keys, self.cache = sampled_tick(
+                self.params, self.cache, toks, pmask, ppos,
+                host_copy(self._temp), self._keys)
+        else:
+            tok, self.cache = greedy_tick(self.params, self.cache, toks,
+                                          pmask, ppos)
+        return tok
+
     def step(self) -> bool:
-        """Admit newcomers, then one batched decode tick + one batched
-        on-device sample for every active slot (only the [slots] sampled
-        tokens come back to the host).  With a drafter the tick is
+        """Admit newcomers, then one decode tick that decodes and samples
+        every active slot on device (only the [slots] sampled tokens
+        come back to the host).  With a drafter the tick is
         draft-K -> verify-1-dispatch -> accept/rollback instead (see the
         class docstring).  Returns True while there is (or will be) work
         left."""
@@ -1098,23 +1199,19 @@ class ServeEngine:
             return self._spec_tick()
         fault_point("decode.dispatch")
         self._map_tick_pages()
-        logits, self.cache = self._decode(
-            self.params, self.cache, host_copy(self._next_tok))
-        self._pos += 1     # decode_step advances every slot's pos
+        # idle slots stay pinned at position 0, where _release_slot
+        # left them
+        toks = self.decode_tick(
+            self._greedy_tick, self._sampled_tick, host_copy(self._next_tok),
+            host_copy(self.idle_mask()), jnp.zeros((self.slots,), jnp.int32))
+        toks = np.asarray(toks)          # the ONE device->host transfer
+        for slot in list(self._active):
+            self.emit_decoded(slot, int(toks[slot]))
         if self._spec:
             # spec temporarily degraded to plain decode: keep the
             # drafter's pos in step so re-enabling resumes cleanly (its
             # missing KV rows only cost acceptance, never correctness)
             self._dcache["pos"] = jnp.asarray(self._pos.astype(np.int32))
-        fault_point("sampler")
-        if self._temp.any() or self._truncates:
-            toks, self._keys = self._sampler(
-                logits, self._keys, host_copy(self._temp))
-        else:              # all-greedy tick: skip the [B, V] Gumbel draw
-            toks = self._argmax(logits)
-        toks = np.asarray(toks)          # the ONE device->host transfer
-        for slot in list(self._active):
-            self._emit(slot, int(toks[slot]))
         return bool(self._active or self._queue)
 
     def _spec_tick(self) -> bool:
@@ -1168,13 +1265,12 @@ class ServeEngine:
             # advancing the pos mirror per token so EOS / max_new /
             # max_len stop exactly where plain decode would
             for j in range(a + 1):
-                self._pos[slot] += 1
                 self.spec_stats["emitted"] += 1
-                if self._emit(slot, int(toks_h[slot, j])):
+                if self.emit_decoded(slot, int(toks_h[slot, j])):
                     break
         # attention rollback IS this pos push: rejected rows sit beyond
         # every slot's committed depth, masked until overwritten (freed
-        # slots were zeroed by _emit and the mirror agrees)
+        # slots were zeroed by emit and the mirror agrees)
         posv = jnp.asarray(self._pos.astype(np.int32))
         self.cache["pos"] = posv
         self._dcache["pos"] = posv

@@ -361,7 +361,7 @@ class TestServeEnginePaged:
         eng = ServeEngine(model, params, slots=2, max_len=64, page_size=16,
                           pages=3)   # 2 slots want 8 pages fully provisioned
         uids = [eng.submit(list(range(1, 18)), max_new_tokens=4)
-                for _ in range(3)]   # 17 tokens -> 32-bucket -> 2 pages
+                for _ in range(3)]   # 17 tokens -> 2 pages
         res = eng.run()
         assert set(res) == set(uids)
         assert all(len(res[u]) == 4 for u in uids)
@@ -382,7 +382,7 @@ class TestServeEnginePaged:
         eng = ServeEngine(model, params, slots=2, max_len=64, page_size=8,
                           pages=5)
         uids = [eng.submit(list(range(1, 13)), max_new_tokens=20)
-                for _ in range(2)]   # 16-bucket + 20 new -> 5 pages each
+                for _ in range(2)]   # 12 + 20 new -> 4 pages each
         res = eng.run()
         assert all(len(res[u]) == 20 for u in uids)
         stats = eng.page_stats
@@ -406,7 +406,7 @@ class TestServeEnginePaged:
         must map fresh pages on the fly."""
         cfg, model, params = tiny
         eng = ServeEngine(model, params, slots=1, max_len=64, page_size=4)
-        uid = eng.submit([5, 3, 1], max_new_tokens=10)   # 4-bucket: 1 page
+        uid = eng.submit([5, 3, 1], max_new_tokens=10)   # 1 page
         res = eng.run()
         assert len(res[uid]) == 10
         ref = generate(model, params, jnp.asarray([[5, 3, 1]], jnp.int32),

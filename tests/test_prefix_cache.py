@@ -156,21 +156,26 @@ class TestPrefixAccounting:
         eng.check_leaks()
 
     def test_ragged_suffixes_share_compiles(self, tiny):
-        """Admission compiles are keyed on (suffix bucket, match depth),
-        not raw suffix length: ragged warm suffixes reuse ONE compile
-        of the tail-padded suffix prefill."""
+        """Admission compiles are keyed on the chunk grid (window shape,
+        window start), not raw suffix length: ragged warm suffixes
+        reuse ONE compile of the final chunk program, at the window
+        their match depth starts."""
         cfg, model, params = tiny
         eng = ServeEngine(model, params, slots=2, max_len=32, page_size=4,
-                          prefix_cache=True)
+                          prefix_cache=True, prefill_chunk=8)
         eng.submit(SYS, max_new_tokens=2)
-        eng.run()                               # cold compile (depth 0)
-        for i, sfx in enumerate([1, 2, 3, 5, 7]):   # ragged, one bucket
+        eng.run()                               # cold compile (pos0=0)
+        sizes = []
+        for i, sfx in enumerate([1, 2, 3, 5, 7]):   # ragged, one window
             eng.submit(SYS + [40 + 10 * i + j for j in range(sfx)],
                        max_new_tokens=2)
-        eng.run()
-        # one entry for the cold prompt (pos0=0), one shared by every
-        # warm ragged suffix (pos0=8, bucket 8)
-        assert eng._prefill_suffix._cache_size() == 2
+            eng.run()
+            sizes.append(eng._chunk_last._cache_size())
+        # one entry for the cold prompt's window [0, 8), one shared by
+        # every warm ragged suffix's window [8, 16), from the first on
+        assert sizes == [2] * 5
+        assert eng._chunk_mid._cache_size() == 0
+        assert eng.prefix_stats["hits"] == 5
 
     def test_leak_check_at_every_tick(self, tiny):
         cfg, model, params = tiny

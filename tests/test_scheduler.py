@@ -110,6 +110,20 @@ class TestBitIdentity:
         assert sched.run() == ref
         assert eng.spec_stats["accepted"] > 0
 
+    def test_atomic_admission_done_at_first_token(self, tiny):
+        """A request an atomic admission finishes at its first token is
+        DONE and hands its slot to the next one."""
+        cfg, model, params = tiny
+        eng = ServeEngine(model, params, slots=1, max_len=32, seed=5,
+                          cache_kind="dense")
+        sched = PipelinedScheduler(eng)
+        uids = [sched.submit(p, max_new_tokens=1) for p in ([1, 2, 3], [4])]
+        for _ in range(8):                        # bounded: no spin
+            if not sched.tick():
+                break
+        assert [sched.status(u) for u in uids] == [DONE, DONE]
+        assert sorted(sched.results) == uids
+
     def test_tight_pool_reserve_slack(self, tiny):
         """Dispatch-ahead ticks overshoot reservations by pipeline_depth
         positions: on a page pool too small to hold every request at
@@ -503,3 +517,27 @@ def test_benchmark_recording_hooks_still_bind(tiny):
     sched.tick()
     assert {"lo", "n"} <= set(vars(sched._prefill))
     sched.run()
+
+
+def test_engine_and_scheduler_dispatch_the_same_programs(tiny):
+    """A paged engine has one set of device programs: the scheduler
+    dispatches the engine's own tick and chunk programs, and the
+    synchronous ``ServeEngine.run`` admits through the same chunk
+    programs, window by window on the grid, and decodes through the
+    same masked tick."""
+    cfg, model, params = tiny
+    eng = ServeEngine(model, params, slots=2, max_len=32, seed=5)
+    sched = PipelinedScheduler(eng, prefill_chunk=4)
+    for name in ("_greedy_tick", "_sampled_tick", "_chunk_mid",
+                 "_chunk_last"):
+        assert getattr(sched, name) is getattr(eng, name), name
+
+    eng = ServeEngine(model, params, slots=2, max_len=32, seed=5,
+                      prefill_chunk=4)
+    eng.submit(list(range(1, 11)), max_new_tokens=3)
+    assert len(eng.run()[0]) == 3
+    # windows [0, 4) and [4, 8) write through, [8, 12) ends the prompt
+    assert eng._chunk_mid._cache_size() == 2
+    assert eng._chunk_last._cache_size() == 1
+    assert eng._greedy_tick._cache_size() == 1
+    assert eng._sampled_tick._cache_size() == 0

@@ -239,6 +239,25 @@ class TestServeEngine:
         res = eng.run()
         assert all(len(res[u]) == 3 for u in uids)
 
+    @pytest.mark.parametrize("kind", ["paged", "dense"])
+    def test_request_done_at_its_first_token_frees_its_slot(self, tiny,
+                                                            kind):
+        """A request that ends at its first token (``max_new_tokens=1``,
+        emitted by its admission) hands its slot back: one slot serves a
+        queue of them."""
+        cfg, model, params = tiny
+        eng = ServeEngine(model, params, slots=1, max_len=32,
+                          cache_kind=kind)
+        uids = [eng.submit(p, max_new_tokens=1)
+                for p in ([1, 2, 3], [4, 5], [6])]
+        for _ in range(8):                        # bounded: no spin
+            if not eng.step():
+                break
+        assert sorted(eng._results) == uids
+        assert all(len(eng._results[u]) == 1 for u in uids)
+        assert eng._free == [0]
+        eng.check_leaks()
+
     def test_engine_eos_stops_request(self, tiny):
         cfg, model, params = tiny
         prompt = [3, 1, 4, 1, 5]

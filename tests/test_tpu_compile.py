@@ -191,13 +191,15 @@ def test_ssd_decode_step(one_chip, shape):
 def _tick_hlo(one_chip, monkeypatch, config, layers, slots, max_len, pages):
     """The compiled decode tick of ``config`` cut to ``layers`` layers, at
     its published widths with EN-T planes, ``slots`` slots of ``max_len``
-    tokens on a paged pool of ``pages`` pages and a donated cache, as the
-    scheduler serves it."""
+    tokens on a paged pool of ``pages`` pages and a donated cache: the
+    engine's greedy tick (``make_serve_programs``), the program served."""
     import dataclasses
 
     from repro.configs.base import QuantConfig
     from repro.models.transformer import build_model
     from repro.quant.quantize import quantize_params
+    from repro.runtime.sampling import make_sampler
+    from repro.runtime.serve_loop import make_serve_programs
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = build_model(dataclasses.replace(config, num_layers=layers))
@@ -211,17 +213,14 @@ def _tick_hlo(one_chip, monkeypatch, config, layers, slots, max_len, pages):
         c["start"] = jnp.zeros((slots,), jnp.int32)
         return c
 
-    def tick(params, cache, toks, pmask):
-        logits, cache = model.decode_step(params, cache, tokens=toks,
-                                          token_mask=~pmask)
-        return jnp.argmax(logits, axis=-1), cache
-
+    tick = make_serve_programs(model, make_sampler())[0]
     place = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), t)
     args = place((params, jax.eval_shape(cache_fn),
                   jax.ShapeDtypeStruct((slots,), jnp.int32),
-                  jax.ShapeDtypeStruct((slots,), jnp.bool_)))
-    text = jax.jit(tick, donate_argnums=(1,)).lower(*args).compile().as_text()
+                  jax.ShapeDtypeStruct((slots,), jnp.bool_),
+                  jax.ShapeDtypeStruct((slots,), jnp.int32)))
+    text = tick.lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') > 0
     return text
 
